@@ -1,41 +1,11 @@
-"""``shard_map`` across jax versions.
-
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the
-top-level ``jax.shard_map`` (and renamed the replication-check kwarg
-``check_rep`` → ``check_vma``).  The tier-1 container pins a jax build
-that only has the experimental path, while newer images only document
-the top-level one.  All in-repo call sites import from here and speak
-the *new* API (``check_vma``); the shim maps the kwarg down when the
-experimental implementation is the one available.
-"""
+"""The one spelling of ``shard_map`` in-repo call sites use: jax's
+top-level ``jax.shard_map`` with the ``check_vma`` keyword (the
+installed jax is 0.9.0; ``jax.experimental.shard_map`` and its
+``check_rep`` are its predecessor's)."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level, kwarg check_vma
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental, kwarg check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None, **kwargs):
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name):
-    """Static size of a bound mesh axis inside a traced region.
-    ``lax.axis_size`` only exists on newer jax; ``psum(1, axis)``
-    constant-folds to a Python int under tracing on every version."""
-    from jax import lax
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:
-        return lax.psum(1, axis_name)
-
+from jax import shard_map
+from jax.lax import axis_size
 
 __all__ = ["shard_map", "axis_size"]

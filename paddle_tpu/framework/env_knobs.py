@@ -18,9 +18,9 @@ the lint framework loads it straight from this file, and import-time
 consumers (``observability/__init__.py``) must not pay for anything.
 
 Call-site parsing stays at the call site on purpose: knobs like
-``PADDLE_TPU_DP_COMPRESS`` ("8"/"int8"/"exact16"...) or
-``PADDLE_TPU_COMPILE_CACHE`` (flag-or-path) have bespoke grammars and
-bespoke error messages that belong next to the feature.  What the
+``PADDLE_TPU_DP_COMPRESS`` ("8"/"int8"/"exact16"...) have bespoke
+grammars and bespoke error messages that belong next to the feature.
+What the
 registry centralizes is the *name*, the *documented default*, and the
 *doc line* — the three things that rot when scattered.
 """
@@ -66,7 +66,7 @@ _k("PADDLE_TPU_FLASH_NO_PACKED", "off", "bool",
    "Disable the packed (batch*heads-collapsed) flash kernel variant.")
 _k("PADDLE_TPU_FUSED_LMCE", "off", "bool",
    "Bench A/B gate: fold the LM head into the streaming-CE kernel "
-   "(read by bench.py / scripts/tpu_ab.py).")
+   "(read by bench.py).")
 _k("PADDLE_TPU_LMCE_BN", "256", "int",
    "Fused LM-head CE row-block size.")
 _k("PADDLE_TPU_LMCE_BV", "512", "int",
@@ -90,9 +90,10 @@ _k("PADDLE_TPU_METRICS_PORT", "0 (disarmed)", "int",
    "base+1+r.")
 
 # -- compile cache / dispatch engine (framework/) ---------------------------
-_k("PADDLE_TPU_COMPILE_CACHE", "off", "str",
-   "Persistent XLA compile cache: 1 = default cache dir, a path = "
-   "that dir, 0/empty = off.")
+_k("PADDLE_TPU_COMPILE_CACHE", "off", "bool",
+   "Persistent XLA compile cache on/off. Its place is "
+   "JAX_COMPILATION_CACHE_DIR where set, else "
+   "<checkout>/.jax_compile_cache.")
 _k("PADDLE_TPU_FOLD_OVERHEAD_TARGET", "0.05", "float",
    "Auto-fold tuner: target host-overhead fraction per dispatch "
    "group.")

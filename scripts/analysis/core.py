@@ -30,7 +30,7 @@ PKG_REL = "paddle_tpu"
 
 # Modules outside paddle_tpu/ that wire env knobs (bench A/B harness);
 # README.md rides along as text for the staleness check.
-EXTRA_MODULES = ("bench.py", os.path.join("scripts", "tpu_ab.py"))
+EXTRA_MODULES = ("bench.py",)
 TEXT_FILES = ("README.md",)
 
 # same-line suppression: ``code  # lint: allow(pass-name): reason``

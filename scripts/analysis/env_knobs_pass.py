@@ -19,8 +19,8 @@ Rules:
    KeyError); a *computed* name defeats the census and is rejected.
 3. **No dead registry entries.**  Every registered knob's name must
    appear in production wiring — ``paddle_tpu/`` or the bench A/B
-   harness (``bench.py``, ``scripts/tpu_ab.py``) — as a string
-   literal.  An entry nothing mentions is documentation rot.
+   harness (``bench.py``) — as a string literal.  An entry nothing
+   mentions is documentation rot.
 4. **README freshness.**  The block between the
    ``<!-- env-knobs:begin -->`` / ``<!-- env-knobs:end -->`` markers
    must equal ``env_knobs.render_table()`` output (regenerate with

@@ -1,0 +1,188 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached (on-chip-measurement guide §2, third rehearsal).
+
+Interpret mode and the CPU mesh partition and lower anything; the
+chip's compiler does not.  Each case lowers one kernel (or the sharded
+public call) at GPT-2-small widths — b8 · s1024 · 12 heads · d64,
+bf16 — for a described ``v5e:2x2`` and compiles it with libtpu.
+Nothing runs, so this says nothing about values or times: it says the
+chip's compiler accepts the program.  The two ``xfail(strict=True)``
+cases carry the compiler's own message; the PR that repairs either
+kernel flips its case.
+"""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import paddle_tpu  # noqa: F401 — turns on x64, which the kernels must survive
+from paddle_tpu.distributed import collective
+from paddle_tpu.ops import pallas_ops, pallas_lmce
+
+B, S, H, D = 8, 1024, 12, 64
+VOCAB, HIDDEN = 50304, 768
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip: the next run would
+    warn and compile again.  Keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _one_chip_spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return spec
+
+
+def _bh_fwd(topo, monkeypatch):
+    x = _one_chip_spec(topo)((B * H, S, D))
+    return (lambda q, k, v: pallas_ops._pallas_flash_bh(
+        q, k, v, causal=True)), (x, x, x)
+
+
+def _bh_bwd(topo, monkeypatch):
+    spec = _one_chip_spec(topo)
+    x = spec((B * H, S, D))
+    lse = spec((B * H, S, pallas_ops._LANES), jnp.float32)
+    return (lambda q, k, v, o, l, do: pallas_ops._pallas_flash_bwd(
+        q, k, v, o, l, do, causal=True)), (x, x, x, x, lse, x)
+
+
+def _packed_fwd(topo, monkeypatch):
+    x = _one_chip_spec(topo)((B, S, H * D))
+    return (lambda q, k, v: pallas_ops._pallas_flash_packed(
+        q, k, v, H, D, causal=True)), (x, x, x)
+
+
+def _packed_bwd(topo, monkeypatch):
+    spec = _one_chip_spec(topo)
+    x = spec((B, S, H * D))
+    lse = spec((B, S, H * D), jnp.float32)
+    return (lambda q, k, v, o, l, do: pallas_ops._pallas_flash_packed_bwd(
+        q, k, v, o, l, do, H, D, causal=True)), (x, x, x, x, lse, x)
+
+
+def _packed_varlen(topo, monkeypatch):
+    """Packed forward and backward with segment ids (packed documents):
+    the two segment-id layouts take their own index maps."""
+    spec = _one_chip_spec(topo)
+    x = spec((B, S, H * D))
+    seg = spec((B, S), jnp.int32)
+
+    def fwd_bwd(q, k, v, do, seg_):
+        out, lse = pallas_ops._pallas_flash_packed(
+            q, k, v, H, D, seg_, seg_, causal=True)
+        return pallas_ops._pallas_flash_packed_bwd(
+            q, k, v, out, lse, do, H, D, seg_, seg_, causal=True)
+
+    return fwd_bwd, (x, x, x, x, seg)
+
+
+def _sharded_public_op(topo, monkeypatch):
+    """The public op, forward and backward, under the implicit-SPMD
+    step's shardings on a dp2 x mp2 mesh: GSPMD cannot partition a
+    Mosaic kernel, so the op must hand each device its own
+    [4, 1024, 6, 64] block (``pallas_ops._per_device``)."""
+    # jax.default_backend() is the CPU here; the kernel branch is what
+    # this case compiles, so the test answers the platform question
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    mesh = collective.build_mesh({"dp": 2, "mp": 2}, devices=topo.devices)
+    collective.set_mesh(mesh)   # conftest's _reset_state clears it
+    x = jax.ShapeDtypeStruct(
+        (B, S, H, D), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+    def loss(q, k, v):
+        out = pallas_ops.flash_attention.raw(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
+
+
+def _lmce_fwd(topo, monkeypatch):
+    spec = _one_chip_spec(topo)
+    return pallas_lmce._call_fwd, (
+        spec((B * S, HIDDEN)), spec((VOCAB, HIDDEN)),
+        spec((B * S,), jnp.int32))
+
+
+def _paged_decode(topo, monkeypatch):
+    from paddle_tpu.inference.serving.paged_attention_kernel import \
+        paged_ragged_attention
+    spec = _one_chip_spec(topo)
+    pool = spec((512, 16, H, D))
+    return (lambda pk, pv, table, lens, q: paged_ragged_attention(
+        pk, pv, table, lens, q, interpret=False)), (
+        pool, pool, spec((B, 64), jnp.int32), spec((B,), jnp.int32),
+        spec((B, H, D)))
+
+
+class CompilerRefused(Exception):
+    """The chip's compiler refused the kernel with the recorded message."""
+
+
+def _refused(build, case_id, pattern, why):
+    """A case held as xfail(strict) only while the compiler's own
+    message matches ``pattern``: a compile that passes, or fails
+    otherwise, fails the case."""
+    return pytest.param(build, 1, pattern, id=case_id,
+                        marks=pytest.mark.xfail(
+                            strict=True, raises=CompilerRefused,
+                            reason=f"{pattern}: {why}"))
+
+
+@pytest.mark.parametrize("build, n_calls, refused", [
+    pytest.param(_bh_fwd, 1, None, id="flash_bh_fwd"),
+    pytest.param(_bh_bwd, 2, None, id="flash_bh_bwd"),
+    pytest.param(_packed_fwd, 1, None, id="flash_packed_fwd"),
+    pytest.param(_packed_bwd, 2, None, id="flash_packed_bwd"),
+    pytest.param(_packed_varlen, 3, None, id="flash_packed_varlen"),
+    pytest.param(_sharded_public_op, 3, None,
+                 id="flash_attention_dp2_mp2"),
+    _refused(_lmce_fwd, "lmce_fwd",
+             r"failed to legalize operation 'tpu\.truncf'",
+             "(f64) -> f32 — the package-wide jax_enable_x64 reaches "
+             "the kernel's scalars (ROADMAP D5)"),
+    _refused(_paged_decode, "paged_ragged_attention",
+             r"Unable to parse attribute:\s+error: "
+             r"\"#tpu\.dot_dimension_numbers",
+             "the kernel's 3-D einsums; it also has no BlockSpecs "
+             "(ROADMAP Reach: serving bring-up on the chip)"),
+])
+def test_compiles_for_v5e(topo, monkeypatch, build, n_calls, refused):
+    fn, args = build(topo, monkeypatch)
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    except Exception as e:
+        if refused and re.search(refused, str(e)):
+            raise CompilerRefused(refused) from e
+        raise
+    assert text.count("tpu_custom_call") >= n_calls

@@ -305,9 +305,7 @@ def _no_fallback(monkeypatch):
         raise AssertionError(
             "packed kernel fell back to _flash_reference")
     monkeypatch.setattr(pallas_ops, "_flash_reference", boom)
-    pallas_ops._PALLAS_HEALTH.pop("packed_ok", None)
     yield
-    pallas_ops._PALLAS_HEALTH.pop("packed_ok", None)
 
 
 def test_pallas_packed_kernels_match_composed(_interpret_mode,
@@ -433,3 +431,41 @@ def test_headpack_ineligible_falls_back(_interpret_mode, monkeypatch):
         ref = pallas_ops._flash_reference(qbh, kbh, vbh, True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
+
+
+def test_flash_attention_runs_per_device_under_a_mesh(_interpret_mode,
+                                                      _no_fallback):
+    """Mosaic kernels cannot be partitioned by GSPMD, so under a mesh
+    of several devices the public op runs the kernel per device inside
+    a shard_map (batch on the data axes, heads on 'mp').  Values and
+    gradients on a dp2 x mp2 mesh match the one-device kernel."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed import collective
+    rng = np.random.RandomState(16)
+    b, s, h, d = 2, 128, 4, 64      # per device: [1, 128, 2, 64], packed
+    q, k, v = (jnp.asarray(x) for x in _rand_qkv(rng, b=b, s=s, h=h, d=d))
+
+    def loss(q_, k_, v_):
+        out = pallas_ops.flash_attention.raw(q_, k_, v_, causal=True)
+        return (out * out).sum(), out
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+    g_one, out_one = grad(q, k, v)
+
+    mesh = collective.build_mesh({"dp": 2, "mp": 2})
+    collective.set_mesh(mesh)
+    sh = NamedSharding(mesh, P("dp", None, "mp", None))
+    qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2),
+                               has_aux=True)).lower(qs, ks, vs)
+    assert "sdy.manual_computation" in lowered.as_text()  # the shard_map
+    g_mesh, out_mesh = lowered.compile()(qs, ks, vs)
+    assert out_mesh.sharding.spec == sh.spec
+    np.testing.assert_allclose(np.asarray(out_mesh), np.asarray(out_one),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(out_mesh), _oracle(*(np.asarray(x) for x in (q, k, v)),
+                                      causal=True), rtol=2e-4, atol=2e-5)
+    for gm, go in zip(g_mesh, g_one):
+        np.testing.assert_allclose(np.asarray(gm), np.asarray(go),
+                                   rtol=1e-5, atol=1e-6)

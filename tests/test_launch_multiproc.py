@@ -198,3 +198,32 @@ def test_launch_two_process_training_step(tmp_path):
     assert lines[0] and lines[1], logs
     # identical program + identical global batch → identical losses
     assert lines[0][0].split()[1:] == lines[1][0].split()[1:]
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_imports_initialise_no_backend():
+    """One process for each chip: a parent that has touched a jax
+    backend holds the chip and its children cannot have it.  The
+    launcher's parent imports the package, the launch controller and
+    serving; none of those may initialise a backend."""
+    code = ("import paddle_tpu, paddle_tpu.distributed.launch.controller, "
+            "paddle_tpu.inference.serving\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+
+
+def test_chip_smoke_refuses_without_a_chip():
+    """chip_smoke.py on a machine where jax finds no TPU: non-zero
+    exit and no result line — it never falls back to the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
+                       env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode != 0, r.stdout + r.stderr
+    assert '"ok": true' not in r.stdout + r.stderr
+    assert "no TPU" in r.stderr

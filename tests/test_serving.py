@@ -460,19 +460,24 @@ def test_hapi_prepare_serving_export(tiny_net):
 _CACHE_PROBE = """
 import os, paddle_tpu, jax, jax.numpy as jnp
 from paddle_tpu.framework import compile_cache
-assert compile_cache.active_cache_dir() == os.environ["PADDLE_TPU_COMPILE_CACHE"], \
+want = os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert compile_cache.active_cache_dir() == want, \
     compile_cache.active_cache_dir()
+# the variable alone places the cache: no code configured a directory
+assert jax.config.jax_compilation_cache_dir == want
 f = jax.jit(lambda x: (x @ x.T).sum() * 3)
 print(float(f(jnp.ones((32, 32)))))
 """
 
 
 def test_compilation_cache_reused_across_processes(tmp_path):
-    """Second process re-serves compiles from the on-disk cache: the
-    first run writes entries, the second adds NONE (all keys hit)."""
+    """``JAX_COMPILATION_CACHE_DIR`` set: that directory is the cache
+    and nothing else is configured.  A second process re-serves
+    compiles from it: the first run writes entries, the second adds
+    NONE (all keys hit)."""
     cache = str(tmp_path / "xla_cache")
-    env = dict(os.environ, PADDLE_TPU_COMPILE_CACHE=cache,
-               JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache,
+               PADDLE_TPU_COMPILE_CACHE="1", JAX_PLATFORMS="cpu")
     for expect_growth in (True, False):
         before = set(os.listdir(cache)) if os.path.isdir(cache) \
             else set()
@@ -487,10 +492,21 @@ def test_compilation_cache_reused_across_processes(tmp_path):
             assert after == before            # pure cache hits
 
 
-def test_compilation_cache_off_by_default():
+def test_compilation_cache_off_by_default(monkeypatch):
+    """The knob is on/off only; unset (or a path, the form it no
+    longer has) enables nothing."""
     from paddle_tpu.framework import compile_cache
-    if not os.environ.get(compile_cache.ENV_VAR, "").strip():
-        assert compile_cache.active_cache_dir() is None
+
+    def boom(*a, **k):
+        raise AssertionError("the cache was enabled with the knob off")
+    monkeypatch.setattr(compile_cache, "enable_compilation_cache", boom)
+    for val in (None, "", "0", "/some/dir"):
+        if val is None:
+            monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        else:
+            monkeypatch.setenv(compile_cache.ENV_VAR, val)
+        assert compile_cache.enable_from_env() == \
+            compile_cache.active_cache_dir()
 
 
 def test_done_poll_interval_auto_tunes(tiny_net):
