@@ -485,17 +485,19 @@ class DistributedRunner:
             size = int(mesh.shape.get("sharding", 1))
             if stage >= 1 and size > 1:
                 grads = runner._constrain_zero_grads(grads, stage, size)
-            new_params, new_state = opt.apply_gradients_tree(
-                params, grads, opt_state, lr,
-                decay_coeffs=runner._decay_coeffs,
-                lr_scales=runner._lr_scales,
-                l1_coeffs=runner._l1_coeffs)
-            # pin updated params back to their canonical shardings so the
-            # ZeRO-1 weight-update all-gather happens here, not lazily
-            new_params = {
-                n: jax.lax.with_sharding_constraint(
-                    v, NamedSharding(mesh, runner._pspecs.get(n, P())))
-                for n, v in new_params.items()}
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt.apply_gradients_tree(
+                    params, grads, opt_state, lr,
+                    decay_coeffs=runner._decay_coeffs,
+                    lr_scales=runner._lr_scales,
+                    l1_coeffs=runner._l1_coeffs)
+                # pin updated params back to their canonical shardings
+                # so the ZeRO-1 weight-update all-gather happens here,
+                # not lazily
+                new_params = {
+                    n: jax.lax.with_sharding_constraint(
+                        v, NamedSharding(mesh, runner._pspecs.get(n, P())))
+                    for n, v in new_params.items()}
             return (loss_val, mstats, out_vals, new_params, new_state,
                     new_buf)
 
@@ -629,11 +631,12 @@ class DistributedRunner:
             if not shard_update:
                 grads = {n: reduce_full(g, qkey, i) / W
                          for i, (n, g) in enumerate(grads.items())}
-                new_params, new_state = opt.apply_gradients_tree(
-                    params, grads, opt_state, lr,
-                    decay_coeffs=runner._decay_coeffs,
-                    lr_scales=runner._lr_scales,
-                    l1_coeffs=runner._l1_coeffs)
+                with jax.named_scope("optimizer"):
+                    new_params, new_state = opt.apply_gradients_tree(
+                        params, grads, opt_state, lr,
+                        decay_coeffs=runner._decay_coeffs,
+                        lr_scales=runner._lr_scales,
+                        l1_coeffs=runner._l1_coeffs)
             else:
                 g_sh, p_sh = {}, {}
                 for i, (n, g) in enumerate(grads.items()):
@@ -653,20 +656,21 @@ class DistributedRunner:
                     span_len = params[n].shape[d] // W
                     p_sh[n] = jax.lax.dynamic_slice_in_dim(
                         params[n], r * span_len, span_len, axis=d)
-                if clip_fn is not None:
-                    g_sh = clip_fn(g_sh)
-                new_p_sh, new_state = opt.apply_gradients_tree(
-                    p_sh, g_sh, opt_state, lr,
-                    decay_coeffs=runner._decay_coeffs,
-                    lr_scales=runner._lr_scales,
-                    l1_coeffs=runner._l1_coeffs,
-                    apply_clip=clip_fn is None)
-                new_params = {
-                    n: (v if shard_dims.get(n) is None else
-                        jax.lax.all_gather(v, "dp",
-                                           axis=shard_dims[n],
-                                           tiled=True))
-                    for n, v in new_p_sh.items()}
+                with jax.named_scope("optimizer"):
+                    if clip_fn is not None:
+                        g_sh = clip_fn(g_sh)
+                    new_p_sh, new_state = opt.apply_gradients_tree(
+                        p_sh, g_sh, opt_state, lr,
+                        decay_coeffs=runner._decay_coeffs,
+                        lr_scales=runner._lr_scales,
+                        l1_coeffs=runner._l1_coeffs,
+                        apply_clip=clip_fn is None)
+                    new_params = {
+                        n: (v if shard_dims.get(n) is None else
+                            jax.lax.all_gather(v, "dp",
+                                               axis=shard_dims[n],
+                                               tiled=True))
+                        for n, v in new_p_sh.items()}
             loss_val = jax.lax.pmean(loss_val, "dp")
             mstats = jax.tree_util.tree_map(
                 lambda s: jax.lax.psum(s, "dp"), mstats)
@@ -695,10 +699,7 @@ class DistributedRunner:
         """dp-comm observability (host floats only, no device sync):
         modeled per-device dp wire bytes per dispatch on the registry
         (`dp_allreduce_bytes_total`) plus the achieved compression
-        ratio gauge; under tracing, instant annotation spans mark the
-        dispatch's reduce-scatter/all-gather (or all-reduce) site with
-        the byte/mode payload so /trace and /fleet/trace see
-        compression working."""
+        ratio gauge."""
         info = self._dp_comm_info
         if not info:
             return
@@ -712,21 +713,6 @@ class DistributedRunner:
             "dp_compress_ratio",
             "uncompressed-allreduce bytes / actual dp gradient-path "
             "bytes (1.0 = no compression)").set(info["ratio"])
-        if self._dp_explicit and _obs_trace.enabled():
-            now = time.monotonic()
-            if self._dp_shard_update:
-                _obs_trace.add_span(
-                    "mesh.dp.reduce_scatter", now, now,
-                    args={"bytes": info["bytes_per_step"] * n_steps,
-                          "bits": self._dp_compress_bits or 32})
-                _obs_trace.add_span(
-                    "mesh.dp.all_gather", now, now,
-                    args={"bits": 32})
-            else:
-                _obs_trace.add_span(
-                    "mesh.dp.all_reduce", now, now,
-                    args={"bytes": info["bytes_per_step"] * n_steps,
-                          "bits": self._dp_compress_bits or 32})
 
     def _constrain_zero_grads(self, grads, stage: int, size: int):
         """Explicit sharding pins on the ZeRO grad boundary.

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .. import ops
@@ -82,6 +83,7 @@ class GPTEmbeddings(nn.Layer):
                 0.0, config.initializer_range)))
         self.dropout = nn.Dropout(config.hidden_dropout_prob)
 
+    @jax.named_scope("embed")
     def forward(self, input_ids, position_ids=None):
         if position_ids is None:
             seq = input_ids.shape[1]
@@ -112,6 +114,7 @@ class GPTAttention(nn.Layer):
             config.hidden_size, config.hidden_size, weight_attr=init,
             input_is_parallel=True)
 
+    @jax.named_scope("attn")
     def forward(self, x):
         b, s, h = x.shape
         qkv = self.qkv_proj(x)
@@ -160,6 +163,7 @@ class GPTMLP(nn.Layer):
                                      config.hidden_size, weight_attr=init,
                                      input_is_parallel=True)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.fc2(ops.gelu(self.fc1(x), approximate=True))
 
@@ -230,7 +234,9 @@ class GPTForCausalLM(nn.Layer):
         hidden = self.gpt(input_ids, position_ids)
         if self.skip_lm_head:
             return hidden
-        logits = ops.matmul(hidden, self.lm_weight(), transpose_y=True)
+        with jax.named_scope("head"):
+            logits = ops.matmul(hidden, self.lm_weight(),
+                                transpose_y=True)
         return logits
 
 
@@ -249,6 +255,7 @@ class GPTPretrainingCriterion(nn.Layer):
         self.loss_fn = ParallelCrossEntropy()
         self._lm_weight_fn = lm_weight_fn
 
+    @jax.named_scope("loss")
     def forward(self, logits, labels, loss_mask=None):
         # logits [b, s, V] (or hidden [b, s, D] in fused mode);
         # labels [b, s] — shift-by-one is the caller's responsibility
@@ -307,7 +314,8 @@ class _NormLogitsPipe(nn.Layer):
 
     def forward(self, x):
         x = self.final_norm(x)
-        return ops.matmul(x, self.lm_weight, transpose_y=True)
+        with jax.named_scope("head"):
+            return ops.matmul(x, self.lm_weight, transpose_y=True)
 
 
 class GPTForCausalLMPipe(PipelineLayer):
