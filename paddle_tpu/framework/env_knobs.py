@@ -56,10 +56,12 @@ _k("PADDLE_TPU_DISABLE_PALLAS", "off", "bool",
 _k("PADDLE_TPU_FLASH_HEADPACK", "1", "int",
    "Head-packing toggle for the flash-attention kernel (0 disables).")
 _k("PADDLE_TPU_FLASH_BQ", "512", "int",
-   "Flash-attention query block rows (fitted down to divide the "
-   "sequence).")
+   "Flash-attention query rows of the resident block, what one grid "
+   "step holds in VMEM (fitted down to divide the sequence); the "
+   "packed kernels walk it as compute tiles sized from the shape.")
 _k("PADDLE_TPU_FLASH_BK", "1024", "int",
-   "Flash-attention key/value block rows.")
+   "Flash-attention key/value rows of the resident block (unset, the "
+   "packed dq kernel keeps up to 2048).")
 _k("PADDLE_TPU_FLASH_FUSED_BWD", "off", "bool",
    "Opt into the fused flash-attention backward kernel.")
 _k("PADDLE_TPU_FLASH_NO_PACKED", "off", "bool",

@@ -42,7 +42,7 @@ import functools
 import logging
 import math
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -765,15 +765,28 @@ def _pallas_flash_bwd(q, k, v, out, lse, do, q_seg=None, k_seg=None, *,
 # The [B,S,H,D]→[B*H,S,D] form above needs a physical S↔H transpose of
 # q/k/v/out in BOTH directions of every layer (XLA materialises a
 # layout-change copy per tensor because pallas_call pins default
-# layouts — measured ~4 ms/step on the GPT-2 bench, plus bigger grids).
-# Here the kernels instead read the projection output directly as
-# [B, S, H*D] (a free reshape): heads are packed into 128-lane groups
-# (``hpb`` heads per block when D < 128), the grid walks (B*G, ...)
-# with G = H/hpb lane-groups, and each kernel unrolls the per-head
-# online softmax over static lane slices of its block.  lse is stored
-# in the SAME [B, Sq, H*D] layout (per-head value broadcast over that
-# head's d lanes), so forward and backward agree without any
-# re-broadcasts.
+# layouts).  Here the kernels instead read the projection output
+# directly as [B, S, H*D] (a free reshape): heads are packed into
+# 128-lane groups (``hpb`` heads per block when D < 128), the grid
+# walks (B*G, ...) with G = H/hpb lane-groups, and each kernel unrolls
+# the per-head online softmax over static lane slices of its block.
+# lse is stored in the SAME [B, Sq, H*D] layout (per-head value
+# broadcast over that head's d lanes), so forward and backward agree
+# without any re-broadcasts.
+#
+# Two levels of blocking.  The *resident block* is what a BlockSpec
+# brings into VMEM for one grid step: ``block_q`` query rows against
+# ``block_k`` key rows (PADDLE_TPU_FLASH_BQ x PADDLE_TPU_FLASH_BK,
+# 512 x 1024), large so that a call takes few grid steps.  Inside a
+# step ``_walk_tiles`` runs the block as *compute tiles* of ``tile_q``
+# x ``tile_k`` rows (``_compute_tile``), sliced from the refs with
+# ``pl.ds``: two ``scf.for`` loops whose bounds follow from the grid
+# indices.  Under a causal mask a tile wholly above the diagonal is
+# never visited, a tile wholly below it is computed with no
+# iota/compare/select, and only a tile the diagonal crosses is masked.
+# Without a mask every tile is computed bare; with segment ids every
+# tile is visited and masked.  The grid-level ``pl.when`` still skips a
+# resident block that lies wholly above the diagonal.
 # ---------------------------------------------------------------------------
 def _packed_geometry(h: int, d: int):
     """lane-block width, heads per block, and group count — or None
@@ -792,9 +805,159 @@ def _packed_geometry(h: int, d: int):
     return lb, hpb, h // hpb
 
 
+def _compute_tile(block_q: int, block_k: int, causal: bool,
+                  has_seg: bool):
+    """(tile_q, tile_k): the compute tile of a resident block.  Each
+    divides its side of the block.
+
+    Under a causal mask, 512 x 512: of the sizes tried on a v5e it is
+    the one that beats the whole 512 x 1024 block in all three kernels
+    (PERF.md section 6, PR 27: 519 against 572 us a forward call at
+    b8 x s1024 x 16 heads x 64, 558 against 689 dq, 675 against 838
+    dkv).  A kernel's time goes with the number of tiles it visits as
+    well as with their area, so a narrower tile loses: 256 x 256 skips
+    37.5 % of the square where 512 x 512 skips 25 %, and costs 805,
+    657 and 825 us.  With nothing to skip (no mask, or segment ids,
+    which are not skipped by) a tile is the block's query rows against
+    up to 1024 keys, the widest the kernels have run with."""
+    if has_seg or not causal:
+        return block_q, math.gcd(block_k, 1024)
+    return math.gcd(block_q, 512), math.gcd(block_k, 512)
+
+
+def _tile_counts(sq: int, sk: int, tile_q: int, tile_k: int,
+                 causal: bool, has_seg: bool):
+    """Compute tiles of one head's score square: (all, visited,
+    masked), by the rule ``_walk_tiles`` follows."""
+    nq, nk = sq // tile_q, sk // tile_k
+    square = nq * nk
+    if has_seg:
+        return square, square, square
+    if not causal:
+        return square, square, 0
+    bare = sum(min((i * tile_q + 1) // tile_k, nk) for i in range(nq))
+    visited = sum(min(-(-(i + 1) * tile_q // tile_k), nk)
+                  for i in range(nq))
+    return square, visited, visited - bare
+
+
+def _note_tiles(heads: int, sq: int, sk: int, tile_q: int, tile_k: int,
+                causal: bool, has_seg: bool) -> None:
+    """Counts, when a kernel call is traced, the compute tiles it will
+    walk: ``flash_tiles_total{kind=square|visited|masked}``."""
+    from ..observability import metrics as _obs_metrics
+    reg = _obs_metrics.registry()
+    counts = _tile_counts(sq, sk, tile_q, tile_k, causal, has_seg)
+    for kind, n in zip(("square", "visited", "masked"), counts):
+        reg.counter("flash_tiles_total",
+                    "compute tiles of the packed flash kernels' score "
+                    "squares, counted a head and call when the call is "
+                    "traced: all of them, those visited, those masked",
+                    labels={"kind": kind}).inc(heads * n)
+
+
+def _walk_tiles(q_idx, kv_idx, q_rows, *, block_q: int, block_k: int,
+                tile_q: int, tile_k: int, causal: bool, has_seg: bool):
+    """Runs the resident block (``q_idx``, ``kv_idx``) as compute
+    tiles.  ``q_rows(qs)`` opens the query rows ``qs .. qs + tile_q``
+    of the block and returns ``k_cols(ks, off, masked)``, which
+    computes them against the key rows ``ks .. ks + tile_k``; ``off``
+    is the tile's first query position less its first key position, so
+    a causal mask keeps ``row + off >= col``.  All indices are int32:
+    the package-wide jax_enable_x64 makes a bare Python literal an i64
+    that Mosaic refuses."""
+    from jax.experimental import pallas as pl
+    i32 = np.int32
+
+    def loop(lo, hi, fn):
+        """``fn(i)`` for i in lo .. hi - 1, as an ``scf.for``."""
+        if not isinstance(hi, int):     # lo is an int32 too
+            jax.lax.fori_loop(lo, hi,
+                              lambda i, carry: (fn(i), carry)[1], i32(0))
+        elif hi - lo == 1:      # no loop: the tile's slices stay static
+            fn(i32(lo))
+        else:                   # fori_loop would count these in i64
+
+            def step(i, _):
+                fn(i)
+                return i + i32(1), None
+            jax.lax.scan(step, i32(lo), None, length=hi - lo)
+
+    nq, nk = block_q // tile_q, block_k // tile_k
+
+    def q_tile(i):
+        qs = pl.multiple_of(i * i32(tile_q), tile_q)
+        k_cols = q_rows(qs)
+        # first query position of the tile less the block's first key
+        rel = q_idx * i32(block_q) + qs - kv_idx * i32(block_k)
+
+        def run(lo, hi, masked):
+            def k_tile(j):
+                ks = pl.multiple_of(j * i32(tile_k), tile_k)
+                k_cols(ks, rel - ks, masked)
+            loop(lo, hi, k_tile)
+
+        if has_seg:
+            run(0, nk, True)
+        elif not causal:
+            run(0, nk, False)
+        else:
+            # every row of the tile sees the keys before rel + 1, none
+            # sees those from rel + tile_q on
+            def tiles_before(pos):
+                return jax.lax.div(jax.lax.clamp(
+                    i32(0), pos, i32(block_k)), i32(tile_k))
+            n_bare = tiles_before(rel + i32(1))
+            n_seen = tiles_before(rel + i32(tile_q + tile_k - 1))
+            run(i32(0), n_bare, False)
+            run(n_bare, n_seen, True)
+
+    if causal and not has_seg:
+        # a resident block wholly above the diagonal holds no tile
+        @pl.when(kv_idx * block_k <= q_idx * block_q + block_q - 1)
+        def _run():
+            loop(0, nq, q_tile)
+    else:
+        loop(0, nq, q_tile)
+
+
+def _tile_keep(off, tile_q: int, tile_k: int, causal: bool, q_ids,
+               k_ids, by_key: bool = False):
+    """What a masked compute tile keeps: [tile_q, tile_k] bool, or
+    [tile_k, tile_q] ``by_key`` (then ``q_ids`` is a row and ``k_ids``
+    a column)."""
+    keep = None
+    if causal:
+        shape = (tile_k, tile_q) if by_key else (tile_q, tile_k)
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, shape, int(by_key))
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, int(not by_key))
+        keep = q_pos + off >= k_pos
+    if q_ids is not None:
+        same = q_ids == k_ids
+        keep = same if keep is None else keep & same
+    return keep
+
+
+def _across(rows, width: int):
+    """A row statistic held once in every lane, [n, 128], laid against
+    a tile ``width`` columns wide: whole vregs reused, where a [n, 1]
+    column would be broadcast along the lanes on every use."""
+    if width <= _LANES:
+        return rows[:, :width]
+    return jnp.tile(rows, (1, width // _LANES))
+
+
+def _lane_sums(x):
+    """[n, 128] whose lanes add up to the row sums of ``x``: the
+    128-column pieces added to each other, no lane crossed."""
+    return functools.reduce(
+        jnp.add, (x[:, j:j + _LANES] for j in range(0, x.shape[1], _LANES)))
+
+
 def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
-                             block_q: int, block_k: int, seq_k: int,
-                             d: int, hpb: int, has_seg: bool):
+                             block_q: int, block_k: int, tile_q: int,
+                             tile_k: int, seq_k: int, d: int, hpb: int,
+                             has_seg: bool):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -813,49 +976,49 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    def body():
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            cmask = q_pos >= k_pos
-        if has_seg:
-            smask = qs_ref[0][:, :1] == ks_ref[0][:1, :]
-        for hh in range(hpb):
-            dsl = slice(hh * d, (hh + 1) * d)
-            lsl = slice(hh * _LANES, (hh + 1) * _LANES)
-            q = q_ref[0][:, dsl]
-            k = k_ref[0][:, dsl]
-            v = v_ref[0][:, dsl]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = jnp.where(cmask, s, -jnp.inf)
-            if has_seg:
-                s = jnp.where(smask, s, -jnp.inf)
-            m_prev = m_scr[:, lsl][:, :1]
-            l_prev = l_scr[:, lsl][:, :1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            m_safe = jnp.maximum(m_new, _LSE_FLOOR)
-            p = jnp.exp(s - m_safe)
-            alpha = jnp.exp(jnp.maximum(m_prev, _LSE_FLOOR) - m_safe)
-            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:, dsl] = acc_scr[:, dsl] * alpha + \
-                jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_scr[:, lsl] = jnp.broadcast_to(m_new, (block_q, _LANES))
-            l_scr[:, lsl] = jnp.broadcast_to(l_new, (block_q, _LANES))
+    def q_rows(qs):
+        rows = pl.ds(qs, tile_q)
+        q_blk = q_ref[0, rows, :]
+        q_ids = qs_ref[0, rows, :][:, :1] if has_seg else None
 
-    if causal and not has_seg:
-        @pl.when(kv_idx * block_k <= q_idx * block_q + block_q - 1)
-        def _run():
-            body()
-    else:
-        body()
+        def k_cols(ks, off, masked):
+            cols = pl.ds(ks, tile_k)
+            k_blk = k_ref[0, cols, :]
+            v_blk = v_ref[0, cols, :]
+            keep = _tile_keep(
+                off, tile_q, tile_k, causal, q_ids,
+                ks_ref[0, :, cols][:1, :] if has_seg else None) \
+                if masked else None
+            for hh in range(hpb):
+                dsl = slice(hh * d, (hh + 1) * d)
+                lsl = slice(hh * _LANES, (hh + 1) * _LANES)
+                v = v_blk[:, dsl]
+                s = jax.lax.dot_general(
+                    q_blk[:, dsl], k_blk[:, dsl],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if keep is not None:
+                    s = jnp.where(keep, s, -jnp.inf)
+                # m in every lane of its row; l as 128 partial sums a
+                # row, added up once, in _finish
+                m_prev = m_scr[rows, lsl]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                m_safe = jnp.maximum(m_new, _LSE_FLOOR)
+                p = jnp.exp(s - _across(m_safe, tile_k))
+                alpha = jnp.exp(jnp.maximum(m_prev, _LSE_FLOOR) - m_safe)
+                l_scr[rows, lsl] = alpha * l_scr[rows, lsl] + _lane_sums(p)
+                acc_scr[rows, dsl] = \
+                    acc_scr[rows, dsl] * _across(alpha, d) + \
+                    jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_scr[rows, lsl] = m_new
+        return k_cols
+
+    _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
+                tile_q=tile_q, tile_k=tile_k, causal=causal,
+                has_seg=has_seg)
 
     n_kv = seq_k // block_k
 
@@ -869,20 +1032,19 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
         for hh in range(hpb):
             dsl = slice(hh * d, (hh + 1) * d)
             lsl = slice(hh * _LANES, (hh + 1) * _LANES)
-            l_fin = l_scr[:, lsl][:, :1]
-            o_cols.append((acc_scr[:, dsl]
-                           / jnp.maximum(l_fin, 1e-30)).astype(
-                o_ref.dtype))
-            lse = (jnp.maximum(m_scr[:, lsl][:, :1], _LSE_FLOOR)
-                   + jnp.log(jnp.maximum(l_fin, 1e-30)))
-            lse_cols.append(jnp.broadcast_to(lse, (block_q, d)))
+            l_fin = jnp.maximum(
+                jnp.sum(l_scr[:, lsl], axis=-1, keepdims=True), 1e-30)
+            o_cols.append((acc_scr[:, dsl] / l_fin).astype(o_ref.dtype))
+            lse = jnp.maximum(m_scr[:, lsl], _LSE_FLOOR) + jnp.log(l_fin)
+            lse_cols.append(_across(lse, d))
         o_ref[0] = jnp.concatenate(o_cols, axis=-1)
         lse_ref[0] = jnp.concatenate(lse_cols, axis=-1)
 
 
 def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
-                                block_q: int, block_k: int, seq_k: int,
-                                d: int, hpb: int, has_seg: bool):
+                                block_q: int, block_k: int, tile_q: int,
+                                tile_k: int, seq_k: int, d: int,
+                                hpb: int, has_seg: bool):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -908,46 +1070,46 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
             delta_scr[:, lsl] = jnp.broadcast_to(d_row,
                                                  (block_q, _LANES))
 
-    def body():
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            cmask = q_pos >= k_pos
-        if has_seg:
-            smask = qs_ref[0][:, :1] == ks_ref[0][:1, :]
-        for hh in range(hpb):
-            dsl = slice(hh * d, (hh + 1) * d)
-            lsl = slice(hh * _LANES, (hh + 1) * _LANES)
-            q = q_ref[0][:, dsl]
-            k = k_ref[0][:, dsl]
-            v = v_ref[0][:, dsl]
-            do = do_ref[0][:, dsl]
-            lse = lse_ref[0][:, hh * d:hh * d + 1]
-            delta = delta_scr[:, lsl][:, :1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = jnp.where(cmask, s, -jnp.inf)
-            if has_seg:
-                s = jnp.where(smask, s, -jnp.inf)
-            p = jnp.exp(s - lse)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta) * scale).astype(k.dtype)
-            dq_scr[:, dsl] += jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+    def q_rows(qs):
+        rows = pl.ds(qs, tile_q)
+        q_blk = q_ref[0, rows, :]
+        do_blk = do_ref[0, rows, :]
+        lse_blk = lse_ref[0, rows, :]
+        q_ids = qs_ref[0, rows, :][:, :1] if has_seg else None
 
-    if causal and not has_seg:
-        @pl.when(kv_idx * block_k <= q_idx * block_q + block_q - 1)
-        def _run():
-            body()
-    else:
-        body()
+        def k_cols(ks, off, masked):
+            cols = pl.ds(ks, tile_k)
+            k_blk = k_ref[0, cols, :]
+            v_blk = v_ref[0, cols, :]
+            keep = _tile_keep(
+                off, tile_q, tile_k, causal, q_ids,
+                ks_ref[0, :, cols][:1, :] if has_seg else None) \
+                if masked else None
+            for hh in range(hpb):
+                dsl = slice(hh * d, (hh + 1) * d)
+                lsl = slice(hh * _LANES, (hh + 1) * _LANES)
+                k = k_blk[:, dsl]
+                lse = lse_blk[:, hh * d:hh * d + 1]
+                delta = delta_scr[rows, lsl][:, :1]
+                s = jax.lax.dot_general(
+                    q_blk[:, dsl], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if keep is not None:
+                    s = jnp.where(keep, s, -jnp.inf)
+                p = jnp.exp(s - lse)
+                dp = jax.lax.dot_general(
+                    do_blk[:, dsl], v_blk[:, dsl],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta) * scale).astype(k.dtype)
+                dq_scr[rows, dsl] += jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+        return k_cols
+
+    _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
+                tile_q=tile_q, tile_k=tile_k, causal=causal,
+                has_seg=has_seg)
 
     n_kv = seq_k // block_k
 
@@ -958,12 +1120,19 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
 
 def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
                                  block_q: int, block_k: int,
-                                 seq_q: int, d: int, hpb: int,
-                                 has_seg: bool):
+                                 tile_q: int, tile_k: int, seq_q: int,
+                                 d: int, hpb: int, has_seg: bool):
+    """Scores are computed by key, [tile_k, tile_q]: p and ds then stand
+    as the left operands of the dv and dk products with nothing to
+    transpose, which took a call at [8, 1024, 16 x 64] from 738 to 675
+    us (PERF.md section 6, PR 27).  The query rows' statistics (lse,
+    delta) are turned into rows once a query tile; the segment ids come
+    in the layouts that fit, keys down the sublanes and queries along
+    the lanes."""
     from jax.experimental import pallas as pl
 
     if has_seg:
-        q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref, ks_ref, \
+        q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, ks_ref, qs_ref, \
             dk_ref, dv_ref, dk_scr, dv_scr = refs
     else:
         q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref, \
@@ -978,50 +1147,54 @@ def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    def body():
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            cmask = q_pos >= k_pos
-        if has_seg:
-            smask = qs_ref[0][:, :1] == ks_ref[0][:1, :]
-        for hh in range(hpb):
-            dsl = slice(hh * d, (hh + 1) * d)
-            q = q_ref[0][:, dsl]
-            k = k_ref[0][:, dsl]
-            v = v_ref[0][:, dsl]
-            do = do_ref[0][:, dsl]
-            lse = lse_ref[0][:, hh * d:hh * d + 1]
-            delta = jnp.sum(do.astype(jnp.float32)
-                            * o_ref[0][:, dsl].astype(jnp.float32),
-                            axis=-1, keepdims=True)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = jnp.where(cmask, s, -jnp.inf)
-            if has_seg:
-                s = jnp.where(smask, s, -jnp.inf)
-            p = jnp.exp(s - lse)
-            dv_scr[:, dsl] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta) * scale).astype(q.dtype)
-            dk_scr[:, dsl] += jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+    def q_rows(qs):
+        rows = pl.ds(qs, tile_q)
+        q_blk = q_ref[0, rows, :]
+        do_blk = do_ref[0, rows, :]
+        # [lanes, tile_q]: a head's lse in each of its d rows, and the
+        # addends of its delta down them
+        lse_t = lse_ref[0, rows, :].T
+        delta_t = (do_blk.astype(jnp.float32)
+                   * o_ref[0, rows, :].astype(jnp.float32)).T
+        stats = [(lse_t[hh * d:hh * d + 1, :],
+                  jnp.sum(delta_t[hh * d:(hh + 1) * d, :], axis=0,
+                          keepdims=True)) for hh in range(hpb)]
+        q_ids = qs_ref[0, :, rows][:1, :] if has_seg else None
 
-    if causal and not has_seg:
-        @pl.when(q_idx * block_q + block_q - 1 >= kv_idx * block_k)
-        def _run():
-            body()
-    else:
-        body()
+        def k_cols(ks, off, masked):
+            cols = pl.ds(ks, tile_k)
+            k_blk = k_ref[0, cols, :]
+            v_blk = v_ref[0, cols, :]
+            keep = _tile_keep(
+                off, tile_q, tile_k, causal, q_ids,
+                ks_ref[0, cols, :][:, :1] if has_seg else None,
+                by_key=True) if masked else None
+            for hh in range(hpb):
+                dsl = slice(hh * d, (hh + 1) * d)
+                q = q_blk[:, dsl]
+                do = do_blk[:, dsl]
+                lse, delta = stats[hh]
+                s = jax.lax.dot_general(
+                    k_blk[:, dsl], q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if keep is not None:
+                    s = jnp.where(keep, s, -jnp.inf)
+                p = jnp.exp(s - lse)
+                dv_scr[cols, dsl] += jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(
+                    v_blk[:, dsl], do, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta) * scale).astype(q.dtype)
+                dk_scr[cols, dsl] += jax.lax.dot_general(
+                    ds, q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+        return k_cols
+
+    _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
+                tile_q=tile_q, tile_k=tile_k, causal=causal,
+                has_seg=has_seg)
 
     n_q = seq_q // block_q
 
@@ -1039,68 +1212,121 @@ def _packed_index_maps(g: int):
     jax_enable_x64, which Mosaic cannot lower (grid indices are never
     negative, so truncating division is floor division here).
 
-    Each factory takes ``at``, the grid position (1 or 2) whose index
-    walks the sequence dim: ``block`` for [B, S, H*D] tensors,
-    ``seg_rows`` for q-side [B, S, LANES] segment ids, ``seg_cols``
-    for k-side [B, SUBLANES, S] segment ids."""
+    ``at`` is the grid position (1 or 2) whose index walks the sequence
+    dim.  ``block(at)`` is the index map of a [B, S, H*D] tensor.
+    ``seg_down(ids, n, at)`` and ``seg_along(ids, n, at)`` lay [B, S]
+    segment ids down the sublanes, [B, S, LANES], or along the lanes,
+    [B, SUBLANES, S], ``n`` of them a grid step: the array and its block
+    spec."""
+    from jax.experimental import pallas as pl
     g32 = np.int32(g)
 
     def block(at):
         return lambda *idx: (jax.lax.div(idx[0], g32), idx[at],
                              jax.lax.rem(idx[0], g32))
 
-    def seg_rows(at):
-        return lambda *idx: (jax.lax.div(idx[0], g32), idx[at],
-                             idx[0] * 0)
+    def seg_down(ids, n, at):
+        return (jax.lax.broadcast_in_dim(ids, ids.shape + (_LANES,),
+                                         (0, 1)),
+                pl.BlockSpec((1, n, _LANES), lambda *idx: (
+                    jax.lax.div(idx[0], g32), idx[at], idx[0] * 0)))
 
-    def seg_cols(at):
-        return lambda *idx: (jax.lax.div(idx[0], g32), idx[0] * 0,
-                             idx[at])
+    def seg_along(ids, n, at):
+        return (jax.lax.broadcast_in_dim(
+            ids, (ids.shape[0], _SUBLANES, ids.shape[1]), (0, 2)),
+            pl.BlockSpec((1, _SUBLANES, n), lambda *idx: (
+                jax.lax.div(idx[0], g32), idx[0] * 0, idx[at])))
 
-    return block, seg_rows, seg_cols
+    return block, seg_down, seg_along
+
+
+class _PackedPlan(NamedTuple):
+    """What one packed kernel call is built from, beside its arrays:
+    hashable, so that it can be a static argument."""
+    heads: int
+    d: int
+    causal: bool
+    has_seg: bool
+    block_q: int
+    block_k: int
+    tile_q: int
+    tile_k: int
+
+    def kernel_args(self) -> dict:
+        return dict(scale=1.0 / math.sqrt(self.d), causal=self.causal,
+                    block_q=self.block_q, block_k=self.block_k,
+                    tile_q=self.tile_q, tile_k=self.tile_k, d=self.d,
+                    hpb=_packed_geometry(self.heads, self.d)[1],
+                    has_seg=self.has_seg)
+
+
+def _packed_plan(b, sq, sk, h, d, causal, has_seg, block_q, block_k, tile,
+                 default_block_k: int = 1024) -> _PackedPlan:
+    """Resident block and compute tile of one kernel call at this
+    shape.  ``block_q``/``block_k``/``tile`` override the defaults
+    (tests, sweeps).  Counts the call's tiles.  A block is at least 128
+    rows, which divides every sequence the packed path takes
+    (``_seq_eligible``): the dkv kernel lays the queries along the
+    lanes, the other two the keys."""
+    block_q = _fit_block(sq, max(_LANES, block_q or _block_default(
+        "PADDLE_TPU_FLASH_BQ", 512)))
+    block_k = _fit_block(sk, max(_LANES, block_k or _block_default(
+        "PADDLE_TPU_FLASH_BK", default_block_k)))
+    tile_q, tile_k = tile or _compute_tile(block_q, block_k, causal,
+                                           has_seg)
+    if block_q % tile_q or block_k % tile_k or tile_q % _LANES \
+            or tile_k % _LANES:
+        raise ValueError(
+            f"compute tile {tile_q} x {tile_k} must divide the resident "
+            f"block {block_q} x {block_k} (PADDLE_TPU_FLASH_BQ x "
+            f"PADDLE_TPU_FLASH_BK, fitted to the sequence) in whole "
+            f"groups of {_LANES} rows")
+    _note_tiles(b * h, sq, sk, tile_q, tile_k, causal, has_seg)
+    return _PackedPlan(h, d, causal, has_seg, block_q, block_k, tile_q,
+                       tile_k)
 
 
 def _pallas_flash_packed(q, k, v, h, d, q_seg=None, k_seg=None, *,
                          causal: bool, block_q: Optional[int] = None,
-                         block_k: Optional[int] = None):
+                         block_k: Optional[int] = None, tile=None):
     """q [B, Sq, H*D]; k/v [B, Sk, H*D] → (out [B, Sq, H*D],
     lse [B, Sq, H*D] f32, per-head value broadcast over its d lanes).
     Segment ids are [B, S*] (NOT per-head — the packed grid reuses one
     mask per lane-group)."""
+    plan = _packed_plan(q.shape[0], q.shape[1], k.shape[1], h, d, causal,
+                        q_seg is not None, block_q, block_k, tile)
+    return _flash_packed_fwd_call(q, k, v, q_seg, k_seg, plan=plan,
+                                  interpret=_interpret())
+
+
+# The calls themselves are jitted with everything the environment
+# decides passed in as static arguments: the layers of a model share one
+# trace and one Mosaic lowering of each kernel, where every call site
+# would otherwise build its own (a step of 24 layers holds 72; the
+# first step of gpt2-medium over a warm compile cache took 12.5 s
+# against 17.3, PERF.md section 6, PR 27).
+@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+def _flash_packed_fwd_call(q, k, v, q_seg, k_seg, *, plan: _PackedPlan,
+                           interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, hd = q.shape
     sk = k.shape[1]
-    lb, hpb, g = _packed_geometry(h, d)
-    block_q = _fit_block(
-        sq, block_q or _block_default("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(
-        sk, block_k or _block_default("PADDLE_TPU_FLASH_BK", 1024))
-    scale = 1.0 / math.sqrt(d)
-    has_seg = q_seg is not None
-    kw = dict(scale=scale, causal=causal, block_q=block_q,
-              block_k=block_k, d=d, hpb=hpb, has_seg=has_seg)
+    lb, hpb, g = _packed_geometry(plan.heads, plan.d)
+    block_q, block_k = plan.block_q, plan.block_k
 
-    block, seg_rows, seg_cols = _packed_index_maps(g)
+    block, seg_down, seg_along = _packed_index_maps(g)
     # grid (b*g, q, kv) — kv minor
     qspec = pl.BlockSpec((1, block_q, lb), block(1))
     kspec = pl.BlockSpec((1, block_k, lb), block(2))
-    if has_seg:
-        qs_b = jax.lax.broadcast_in_dim(q_seg, (b, sq, _LANES), (0, 1))
-        ks_b = jax.lax.broadcast_in_dim(k_seg, (b, _SUBLANES, sk),
-                                        (0, 2))
-        segq = pl.BlockSpec((1, block_q, _LANES), seg_rows(1))
-        segk = pl.BlockSpec((1, _SUBLANES, block_k), seg_cols(2))
-    in_specs = [qspec, kspec, kspec]
-    args = [q, k, v]
-    if has_seg:
-        in_specs += [segq, segk]
-        args += [qs_b, ks_b]
+    segs = [seg_down(q_seg, block_q, 1),
+            seg_along(k_seg, block_k, 2)] if plan.has_seg else []
     out, lse = pl.pallas_call(
-        functools.partial(_flash_packed_fwd_kernel, seq_k=sk, **kw),
+        functools.partial(_flash_packed_fwd_kernel, seq_k=sk,
+                          **plan.kernel_args()),
         grid=(b * g, sq // block_q, sk // block_k),
-        in_specs=in_specs,
+        in_specs=[qspec, kspec, kspec] + [spec for _, spec in segs],
         out_specs=[qspec, qspec],
         out_shape=[jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
                    jax.ShapeDtypeStruct((b, sq, hd), jnp.float32)],
@@ -1109,80 +1335,84 @@ def _pallas_flash_packed(q, k, v, h, d, q_seg=None, k_seg=None, *,
             pltpu.VMEM((block_q, hpb * _LANES), jnp.float32),
             pltpu.VMEM((block_q, lb), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(*args)
+        interpret=interpret,
+    )(q, k, v, *[ids for ids, _ in segs])
     return out, lse
 
 
 def _pallas_flash_packed_bwd(q, k, v, out, lse, do, h, d, q_seg=None,
                              k_seg=None, *, causal: bool,
                              block_q: Optional[int] = None,
-                             block_k: Optional[int] = None):
+                             block_k: Optional[int] = None, tile=None):
+    shape = (q.shape[0], q.shape[1], k.shape[1], h, d, causal,
+             q_seg is not None, block_q, block_k, tile)
+    # dq keeps up to 2048 keys resident: with one key block the K/V of
+    # a lane-group are fetched once and not once a query block, which
+    # took a dq call at [2, 2048, 8 x 128] from 334 to 234 us and did
+    # nothing for the other two kernels (PERF.md section 6, PR 27); the
+    # compute tile, not the resident block, bounds the temporaries.
+    return _flash_packed_bwd_call(
+        q, k, v, out, lse, do, q_seg, k_seg,
+        dq_plan=_packed_plan(*shape, default_block_k=2048),
+        dkv_plan=_packed_plan(*shape), interpret=_interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("dq_plan", "dkv_plan", "interpret"))
+def _flash_packed_bwd_call(q, k, v, out, lse, do, q_seg, k_seg, *,
+                           dq_plan: _PackedPlan, dkv_plan: _PackedPlan,
+                           interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, hd = q.shape
     sk = k.shape[1]
-    lb, hpb, g = _packed_geometry(h, d)
-    block_q = _fit_block(
-        sq, block_q or _block_default("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(
-        sk, block_k or _block_default("PADDLE_TPU_FLASH_BK", 1024))
-    scale = 1.0 / math.sqrt(d)
-    has_seg = q_seg is not None
-    kw = dict(scale=scale, causal=causal, block_q=block_q,
-              block_k=block_k, d=d, hpb=hpb, has_seg=has_seg)
-    if has_seg:
-        qs_b = jax.lax.broadcast_in_dim(q_seg, (b, sq, _LANES), (0, 1))
-        ks_b = jax.lax.broadcast_in_dim(k_seg, (b, _SUBLANES, sk),
-                                        (0, 2))
+    lb, hpb, g = _packed_geometry(dq_plan.heads, dq_plan.d)
+    args = [q, k, v, do, out, lse]
+    block, seg_down, seg_along = _packed_index_maps(g)
 
-    block, seg_rows, seg_cols = _packed_index_maps(g)
+    def specs(plan, q_at, k_at):
+        """Block specs of the six tensors for a grid whose position
+        ``q_at`` walks the queries and ``k_at`` the keys."""
+        qspec = pl.BlockSpec((1, plan.block_q, lb), block(q_at))
+        kspec = pl.BlockSpec((1, plan.block_k, lb), block(k_at))
+        return qspec, kspec, [qspec, kspec, kspec, qspec, qspec, qspec]
 
     # dq pass: grid (b*g, q, kv) — kv minor
-    qspec = pl.BlockSpec((1, block_q, lb), block(1))
-    kspec = pl.BlockSpec((1, block_k, lb), block(2))
-    in_specs = [qspec, kspec, kspec, qspec, qspec, qspec]
-    args = [q, k, v, do, out, lse]
-    if has_seg:
-        in_specs += [
-            pl.BlockSpec((1, block_q, _LANES), seg_rows(1)),
-            pl.BlockSpec((1, _SUBLANES, block_k), seg_cols(2))]
-        args += [qs_b, ks_b]
+    qspec, _, in_specs = specs(dq_plan, 1, 2)
+    segs = [seg_down(q_seg, dq_plan.block_q, 1),
+            seg_along(k_seg, dq_plan.block_k, 2)] if dq_plan.has_seg else []
     dq = pl.pallas_call(
-        functools.partial(_flash_packed_bwd_dq_kernel, seq_k=sk, **kw),
-        grid=(b * g, sq // block_q, sk // block_k),
-        in_specs=in_specs,
+        functools.partial(_flash_packed_bwd_dq_kernel, seq_k=sk,
+                          **dq_plan.kernel_args()),
+        grid=(b * g, sq // dq_plan.block_q, sk // dq_plan.block_k),
+        in_specs=in_specs + [spec for _, spec in segs],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, lb), jnp.float32),
-            pltpu.VMEM((block_q, hpb * _LANES), jnp.float32),
+            pltpu.VMEM((dq_plan.block_q, lb), jnp.float32),
+            pltpu.VMEM((dq_plan.block_q, hpb * _LANES), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(*args)
+        interpret=interpret,
+    )(*args, *[ids for ids, _ in segs])
 
-    # dkv pass: grid (b*g, kv, q) — q minor
-    qspec2 = pl.BlockSpec((1, block_q, lb), block(2))
-    kspec2 = pl.BlockSpec((1, block_k, lb), block(1))
-    in_specs2 = [qspec2, kspec2, kspec2, qspec2, qspec2, qspec2]
-    args2 = [q, k, v, do, out, lse]
-    if has_seg:
-        in_specs2 += [
-            pl.BlockSpec((1, block_q, _LANES), seg_rows(2)),
-            pl.BlockSpec((1, _SUBLANES, block_k), seg_cols(1))]
-        args2 += [qs_b, ks_b]
+    # dkv pass: grid (b*g, kv, q) — q minor; scores by key, so the
+    # keys' ids go down the sublanes and the queries' along the lanes
+    _, kspec, in_specs = specs(dkv_plan, 2, 1)
+    segs = [seg_down(k_seg, dkv_plan.block_k, 1),
+            seg_along(q_seg, dkv_plan.block_q, 2)] if dkv_plan.has_seg else []
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_packed_bwd_dkv_kernel, seq_q=sq, **kw),
-        grid=(b * g, sk // block_k, sq // block_q),
-        in_specs=in_specs2,
-        out_specs=[kspec2, kspec2],
+        functools.partial(_flash_packed_bwd_dkv_kernel, seq_q=sq,
+                          **dkv_plan.kernel_args()),
+        grid=(b * g, sk // dkv_plan.block_k, sq // dkv_plan.block_q),
+        in_specs=in_specs + [spec for _, spec in segs],
+        out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((b, sk, hd), k.dtype),
                    jax.ShapeDtypeStruct((b, sk, hd), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, lb), jnp.float32),
-                        pltpu.VMEM((block_k, lb), jnp.float32)],
-        interpret=_interpret(),
-    )(*args2)
+        scratch_shapes=[pltpu.VMEM((dkv_plan.block_k, lb), jnp.float32),
+                        pltpu.VMEM((dkv_plan.block_k, lb), jnp.float32)],
+        interpret=interpret,
+    )(*args, *[ids for ids, _ in segs])
     return dq, dk, dv
 
 

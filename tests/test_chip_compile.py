@@ -90,12 +90,12 @@ def _packed_bwd(topo, monkeypatch):
         q, k, v, o, l, do, H, D, causal=True)), (x, x, x, x, lse, x)
 
 
-def _packed_varlen(topo, monkeypatch):
+def _packed_varlen(topo, monkeypatch, s=S):
     """Packed forward and backward with segment ids (packed documents):
     the two segment-id layouts take their own index maps."""
     spec = _one_chip_spec(topo)
-    x = spec((B, S, H * D))
-    seg = spec((B, S), jnp.int32)
+    x = spec((B, s, H * D))
+    seg = spec((B, s), jnp.int32)
 
     def fwd_bwd(q, k, v, do, seg_):
         out, lse = pallas_ops._pallas_flash_packed(
@@ -104,6 +104,31 @@ def _packed_varlen(topo, monkeypatch):
             q, k, v, out, lse, do, H, D, seg_, seg_, causal=True)
 
     return fwd_bwd, (x, x, x, x, seg)
+
+
+def _packed_varlen_s2048(topo, monkeypatch):
+    """... at 2048 tokens, where dq keeps all the keys resident and
+    walks them as two tiles: the key-side segment ids are sliced along
+    the lanes at a loop index."""
+    return _packed_varlen(topo, monkeypatch, s=2048)
+
+
+def _packed_two_key_blocks(topo, monkeypatch):
+    """Packed forward and backward at the four-chip cell's local shape,
+    [2, 2048, 8 x 128]: one head a lane block, and two resident key
+    blocks, so the grid's skip and the tile loop's bounds compile
+    together."""
+    spec = _one_chip_spec(topo)
+    b, s, h, d = 2, 2048, 8, 128
+    x = spec((b, s, h * d))
+
+    def fwd_bwd(q, k, v, do):
+        out, lse = pallas_ops._pallas_flash_packed(q, k, v, h, d,
+                                                   causal=True)
+        return pallas_ops._pallas_flash_packed_bwd(
+            q, k, v, out, lse, do, h, d, causal=True)
+
+    return fwd_bwd, (x, x, x, x)
 
 
 def _sharded_public_op(topo, monkeypatch):
@@ -165,6 +190,10 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_packed_fwd, 1, None, id="flash_packed_fwd"),
     pytest.param(_packed_bwd, 2, None, id="flash_packed_bwd"),
     pytest.param(_packed_varlen, 3, None, id="flash_packed_varlen"),
+    pytest.param(_packed_varlen_s2048, 3, None,
+                 id="flash_packed_varlen_s2048"),
+    pytest.param(_packed_two_key_blocks, 3, None,
+                 id="flash_packed_two_key_blocks"),
     pytest.param(_sharded_public_op, 3, None,
                  id="flash_attention_dp2_mp2"),
     _refused(_lmce_fwd, "lmce_fwd",

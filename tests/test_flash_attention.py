@@ -469,3 +469,158 @@ def test_flash_attention_runs_per_device_under_a_mesh(_interpret_mode,
     for gm, go in zip(g_mesh, g_one):
         np.testing.assert_allclose(np.asarray(gm), np.asarray(go),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _tile_case(case_id, **kw):
+    case = dict(b=1, s=512, sk=None, h=2, d=64, causal=True, seg=False,
+                orphans=False, block_q=None, block_k=None, tile=None)
+    case.update(kw)
+    return pytest.param(case, id=case_id)
+
+
+# Each case names the tile classes it reaches: "bare" (below the
+# diagonal, or no mask at all), "masked" (the diagonal crosses it, or
+# segment ids), "skipped" (above the diagonal: by the loop's bounds
+# inside a resident block, by the grid's pl.when for a whole block).
+_TILE_CASES = [
+    # one resident block, several compute tiles
+    _tile_case("s512-t128-one-block", tile=(128, 128)),
+    _tile_case("s1024-default-rule-hpb2", s=1024),
+    _tile_case("s1024-t256x128", s=1024, tile=(256, 128)),
+    _tile_case("s512-t128x256", tile=(128, 256)),
+    # two resident key blocks: grid skip and loop skip together
+    _tile_case("s512-two-key-blocks", block_q=128, block_k=256,
+               tile=(128, 128)),
+    _tile_case("s1024-bq256-bk512-default-tile", s=1024, block_q=256,
+               block_k=512),
+    # no mask: every tile bare, also where Sq != Sk
+    _tile_case("full-t128", causal=False, tile=(128, 128)),
+    _tile_case("cross-sq256-sk512", s=256, sk=512, causal=False,
+               block_k=256, tile=(128, 128)),
+    _tile_case("cross-default-rule", s=256, sk=512, causal=False),
+    # segment ids: every tile visited and masked
+    _tile_case("seg-default-rule", seg=True),
+    _tile_case("seg-causal-t128", seg=True, tile=(128, 128)),
+    _tile_case("seg-full-t128x256", seg=True, causal=False,
+               tile=(128, 256)),
+    # query rows whose segment holds no key: all of the row masked
+    _tile_case("seg-rows-without-keys", seg=True, orphans=True,
+               causal=False, tile=(128, 128)),
+    _tile_case("seg-rows-without-keys-default-rule", seg=True,
+               orphans=True, d=128),
+    # hpb 1: one head fills the lane block
+    _tile_case("d128-hpb1-t128", d=128, tile=(128, 128)),
+    _tile_case("d128-hpb1-two-key-blocks", d=128, s=1024, block_k=512),
+]
+
+
+@pytest.mark.parametrize("case", _TILE_CASES)
+def test_pallas_packed_tiles_match_composed(_interpret_mode, _no_fallback,
+                                            case):
+    """The packed kernels walk their resident block as compute tiles
+    (``_walk_tiles``): forward and all three gradients against the
+    composed oracle, at shapes and tiles that reach every tile class."""
+    b, s, h, d = case["b"], case["s"], case["h"], case["d"]
+    sk, causal = case["sk"] or s, case["causal"]
+    rng = np.random.RandomState(17)
+    q = jnp.asarray(rng.randn(b, s, h * d).astype(np.float32) * 0.5)
+    k, v = (jnp.asarray(rng.randn(b, sk, h * d).astype(np.float32) * 0.5)
+            for _ in range(2))
+    do = jnp.asarray(rng.randn(b, s, h * d).astype(np.float32))
+    qseg = kseg = None
+    if case["seg"]:     # documents of uneven lengths, cut off the tiles
+        cuts = np.sort(rng.choice(np.arange(1, sk), 3, replace=False))
+        kseg = jnp.asarray(np.searchsorted(cuts, np.arange(sk),
+                                           side="right")[None, :]
+                           .repeat(b, 0).astype(np.int32))
+        qseg = kseg[:, :s]
+        if case["orphans"]:
+            qseg = qseg.at[:, 100:230].set(99)
+    over = dict(causal=causal, block_q=case["block_q"],
+                block_k=case["block_k"], tile=case["tile"])
+
+    def to_bh(t):
+        return jnp.moveaxis(t.reshape(b, t.shape[1], h, d), 2,
+                            1).reshape(b * h, t.shape[1], d)
+
+    def from_bh(t):
+        return jnp.moveaxis(t.reshape(b, h, t.shape[1], d), 1,
+                            2).reshape(b, t.shape[1], h * d)
+
+    def rep(seg):
+        return None if seg is None else jnp.repeat(seg, h, axis=0)
+
+    out, lse = pallas_ops._pallas_flash_packed(q, k, v, h, d, qseg, kseg,
+                                               **over)
+    grads = pallas_ops._pallas_flash_packed_bwd(
+        q, k, v, out, lse, do, h, d, qseg, kseg, **over)
+    ref, vjp = jax.vjp(
+        lambda q_, k_, v_: from_bh(_composed_oracle_bh(
+            to_bh(q_), to_bh(k_), to_bh(v_), causal, rep(qseg),
+            rep(kseg))), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    if case["orphans"]:
+        assert not np.asarray(out)[:, 100:230].any()
+    for got, want in zip(grads, vjp(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-4, atol=5e-5)
+
+
+def _tiles(kind):
+    from paddle_tpu.observability import metrics
+    return metrics.registry().counter(
+        "flash_tiles_total", labels={"kind": kind}).collect()
+
+
+@pytest.mark.parametrize("s, tile, causal", [
+    (1024, 256, True), (1024, 512, True), (1024, 128, True),
+    (2048, 256, True), (512, 128, False),
+    (1024, None, True), (2048, None, True)])    # the shape's own: 512
+def test_flash_tile_counter_reads_the_closed_form(_interpret_mode, s, tile,
+                                                  causal):
+    """``flash_tiles_total`` counts, as a call is traced, the compute
+    tiles of the score square, those visited and those masked, a head:
+    n², n (n + 1) / 2 and n for n = s / tile under a causal mask."""
+    b, h, d = 2, 2, 64
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32)
+    kinds = ("square", "visited", "masked")
+    before = [_tiles(kind) for kind in kinds]
+    jax.eval_shape(
+        lambda q, k, v: pallas_ops._pallas_flash_packed(
+            q, k, v, h, d, causal=causal, tile=tile and (tile, tile)),
+        x, x, x)
+    n = s // (tile or 512)
+    want = (n * n, n * (n + 1) // 2, n) if causal else (n * n, n * n, 0)
+    assert [_tiles(kind) - was for kind, was in zip(kinds, before)] == \
+        [b * h * w for w in want]
+    # the backward pass makes two calls over the same tiles
+    before = [_tiles(kind) for kind in kinds]
+    lse = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32)
+    jax.eval_shape(
+        lambda q, k, v, o, l, do: pallas_ops._pallas_flash_packed_bwd(
+            q, k, v, o, l, do, h, d, causal=causal,
+            tile=tile and (tile, tile)), x, x, x, x, lse, x)
+    assert [_tiles(kind) - was for kind, was in zip(kinds, before)] == \
+        [2 * b * h * w for w in want]
+
+
+def test_flash_tile_counts_by_brute_force():
+    """``_tile_counts`` against a walk over every tile's corners, for
+    tiles that are not square and squares that are not."""
+    for sq, sk, tq, tk in [(1024, 1024, 256, 256), (1024, 1024, 512, 256),
+                           (1024, 1024, 128, 512), (512, 1024, 128, 256),
+                           (2048, 2048, 256, 128)]:
+        visited = masked = 0
+        for q0 in range(0, sq, tq):
+            for k0 in range(0, sk, tk):
+                if k0 > q0 + tq - 1:
+                    continue        # no row sees any of these keys
+                visited += 1
+                masked += k0 + tk - 1 > q0  # the first row misses some
+        assert pallas_ops._tile_counts(sq, sk, tq, tk, True, False) == \
+            ((sq // tq) * (sk // tk), visited, masked)
+    assert pallas_ops._tile_counts(512, 1024, 128, 256, False, False) == \
+        (16, 16, 0)
+    assert pallas_ops._tile_counts(512, 512, 128, 128, True, True) == \
+        (16, 16, 16)
