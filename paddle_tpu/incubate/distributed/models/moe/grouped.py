@@ -1,0 +1,248 @@
+"""Dropless routed experts for one rank of an expert-parallel layout.
+
+The layer is told which experts it holds (``first .. first + held`` of
+``num_experts``).  It routes over all of them, keeps every (token,
+expert) pair whose expert lives here however uneven the routing, and
+returns its own experts' part of the layer's result:
+
+    p = softmax(y W_r) over all experts;  T_t = the k largest
+    g[t, e] = p[t, e] / sum of p over T_t      (normalised over all k,
+                                                held here or not)
+    out[t]  = sum over e in T_t held here of g[t, e] * expert_e(y[t])
+    expert_e(y) = (silu(y W1_e) * (y W3_e)) W2_e
+
+What the other ranks' experts would add is left out; summed over the
+ranks the parts give the whole layer (``tests/test_keye_lm.py``).  On
+one chip there is no exchange, and nothing here stands in for one.
+
+How: the pairs are sorted by expert (pairs of absent experts last), the
+tokens of the pairs held here are gathered into that order, the three
+projections run as grouped matrix products over the uneven groups
+(``jax.lax.ragged_dot``, which XLA lowers to Mosaic kernels on a TPU),
+and each token gathers its results back and adds them up in float32.
+
+Shapes must be static and the number of pairs held here is not.  The
+sorted pairs are taken a window of rows at a time, a window being twice
+the share a balanced router sends here.  The first window always runs;
+where the count overflows it, the later windows follow one after the
+other (``lax.cond`` on the count, then a ``lax.scan``), so no pair is
+ever dropped and the buffers stay a window large.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from .....nn.layer import Layer
+from .....nn import initializer as I
+from .....ops._primitive import apply_closure
+
+
+class Plan(NamedTuple):
+    """Where every (token, choice) pair goes."""
+    dest: jax.Array      # [T, k] row of the pair in the sorted order
+    here: jax.Array      # [T, k] bool: the pair's expert is held here
+    pairs: jax.Array     # [T * k] pair index (t * k + choice) of each row
+    sizes: jax.Array     # [held] pairs of each held expert
+    count: jax.Array     # [] pairs held here: the rows in use
+
+
+def route(logits, k: int):
+    """(experts ``[T, k]`` int32, gates ``[T, k]`` float32) from float32
+    router logits over all experts: the k largest probabilities,
+    normalised over the k chosen."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top, experts = jax.lax.top_k(probs, k)
+    return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+
+
+def plan(experts, first: int, held: int) -> Plan:
+    local = experts - first
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(-1)       # absent: last
+    pairs = jnp.argsort(group, stable=True).astype(jnp.int32)
+    dest = jnp.argsort(pairs).astype(jnp.int32).reshape(experts.shape)
+    sizes = (group[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+             ).sum(0, dtype=jnp.int32)
+    return Plan(dest, here, pairs, sizes, sizes.sum())
+
+
+@jax.checkpoint
+def _gated(gate, up):
+    """silu(gate) * up in float32; the backward pass keeps the two
+    operands as they are stored and computes the rest again."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def usual_rows(tokens: int, k: int, held: int, num_experts: int) -> int:
+    """Twice the share a balanced router sends here, in whole tiles."""
+    share = -(-2 * tokens * k * held // num_experts)
+    return min(tokens * k, -(-share // 512) * 512)
+
+
+class _Window(NamedTuple):
+    """The rows ``start .. start + rows`` of the sorted order."""
+    pairs: jax.Array     # [rows] pair index of each row
+    sizes: jax.Array     # [held] rows of each held expert in the window
+    dest: jax.Array      # [T, k] row of the pair inside the window
+    here: jax.Array      # [T, k] the pair is held here and in the window
+    in_use: jax.Array    # [rows, 1] the row holds a pair held here
+
+
+def _window(p: Plan, start, rows: int) -> _Window:
+    ends = jnp.cumsum(p.sizes)
+    clip = functools.partial(jnp.clip, min=start, max=start + rows)
+    padded = jnp.pad(p.pairs, (0, rows))        # a window may overhang
+    return _Window(
+        jax.lax.dynamic_slice(padded, (start,), (rows,)),
+        (clip(ends) - clip(ends - p.sizes)).astype(jnp.int32),
+        p.dest - start,
+        p.here & (p.dest >= start) & (p.dest < start + rows),
+        (start + jnp.arange(rows) < p.count)[:, None])
+
+
+def _window_forward(w: _Window, y, gates, w1, w3, w2):
+    """(the window's part of the result ``[T, d]`` float32, the four
+    intermediates its backward pass reads)."""
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=w.sizes)
+    with jax.named_scope("dispatch"):
+        x = y[w.pairs // gates.shape[1]]
+    with jax.named_scope("experts"):
+        gate, up = dot(x, w1), dot(x, w3)
+        rows = dot(_gated(gate, up), w2)
+    with jax.named_scope("combine"):
+        picked = jnp.where(w.here[..., None], rows[w.dest], 0)
+        out = (picked.astype(jnp.float32) * gates[..., None]).sum(1)
+    return out, (x, gate, up, rows)
+
+
+def _window_backward(w: _Window, kept, gates, w1, w3, w2, g):
+    """Gradients for (y, gates, w1, w3, w2) of the window's part.  Every
+    gather of the forward pass has a gather as its transpose, because
+    the sorted order is a permutation of the pairs."""
+    x, gate, up, rows = kept
+    k = gates.shape[1]
+
+    def dot(lhs, rhs):
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes=w.sizes)
+
+    with jax.named_scope("combine"):
+        g_rows = g[w.pairs // k]                                # [rows, d]
+        d_rows = jnp.where(w.in_use, g_rows * gates.reshape(-1)[w.pairs][
+            :, None], 0).astype(rows.dtype)
+        d_gate_rows = (rows.astype(jnp.float32) * g_rows).sum(-1)
+        d_gates = jnp.where(w.here, d_gate_rows[w.dest], 0).astype(
+            gates.dtype)
+    with jax.named_scope("experts"):
+        # each product's forward result is not used again: XLA drops it
+        h, gated_back = jax.vjp(_gated, gate, up)
+        d_h, d_w2 = jax.vjp(dot, h, w2)[1](d_rows)
+        d_gate, d_up = gated_back(d_h)
+        d_x1, d_w1 = jax.vjp(dot, x, w1)[1](d_gate)
+        d_x3, d_w3 = jax.vjp(dot, x, w3)[1](d_up)
+    with jax.named_scope("dispatch"):
+        picked = jnp.where(w.here[..., None], (d_x1 + d_x3)[w.dest], 0)
+        d_y = picked.astype(jnp.float32).sum(1).astype(x.dtype)
+    return d_y, d_gates, d_w1, d_w3, d_w2
+
+
+def _later_windows(usual: int, p: Plan):
+    """First rows of the windows after the first."""
+    return jnp.arange(usual, p.pairs.shape[0], usual, dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(usual: int, y, gates, w1, w3, w2, p: Plan):
+    """The layer's result, the sorted pairs taken ``usual`` rows at a
+    time.  The first window always runs and keeps its four intermediates
+    for the backward pass.  Where the pairs held here overflow it
+    (``lax.cond`` on the count, forward and backward), the later windows
+    run one after the other in the same buffers, and the backward pass
+    computes theirs again."""
+    return _experts_fwd(usual, y, gates, w1, w3, w2, p)[0]
+
+
+def _experts_fwd(usual, y, gates, w1, w3, w2, p):
+    operands = (y, gates, w1, w3, w2)
+    out, kept = _window_forward(_window(p, 0, usual), *operands)
+
+    def overflow(out_):
+        def one(acc, start):
+            return acc + _window_forward(_window(p, start, usual),
+                                         *operands)[0], None
+        return jax.lax.scan(one, out_, _later_windows(usual, p))[0]
+
+    if usual < p.pairs.shape[0]:
+        out = jax.lax.cond(p.count <= usual, lambda o: o, overflow, out)
+    return out, operands + (p, kept)
+
+
+def _experts_bwd(usual, res, g):
+    y, gates, w1, w3, w2, p, kept = res
+    grads = _window_backward(_window(p, 0, usual), kept, gates, w1, w3, w2,
+                             g)
+
+    def overflow(grads_):
+        def one(acc, start):
+            w = _window(p, start, usual)
+            again = _window_forward(w, y, gates, w1, w3, w2)[1]
+            more = _window_backward(w, again, gates, w1, w3, w2, g)
+            return tuple(a + m for a, m in zip(acc, more)), None
+        return jax.lax.scan(one, grads_, _later_windows(usual, p))[0]
+
+    if usual < p.pairs.shape[0]:
+        grads = jax.lax.cond(p.count <= usual, lambda x: x, overflow, grads)
+    return tuple(grads) + (None,)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def experts_forward(y, experts, gates, w1, w3, w2, first: int,
+                    num_experts: int):
+    """(out float32 ``[T, d]``, pairs of each held expert ``[held]``)
+    for tokens ``y [T, d]`` routed to ``experts``/``gates`` ``[T, k]``."""
+    tokens, k = experts.shape
+    held = w1.shape[0]
+    with jax.named_scope("dispatch"):
+        p = plan(experts, first, held)
+    usual = usual_rows(tokens, k, held, num_experts)
+    return _experts(usual, y, gates, w1, w3, w2, p), p.sizes
+
+
+class GroupedSwiGLUExperts(Layer):
+    """``held`` SiLU-gated experts of ``num_experts``, stacked:
+    ``w1``, ``w3`` ``[held, d_model, d_hidden]`` and ``w2`` ``[held,
+    d_hidden, d_model]``; expert e of the model is row ``e - first``."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 first: int, held: int, initializer_range: float = 0.02):
+        super().__init__()
+        if not 0 <= first <= first + held <= num_experts:
+            raise ValueError(f"experts {first}..{first + held} of "
+                             f"{num_experts}")
+        self.num_experts, self.first, self.held = num_experts, first, held
+        init = I.Normal(0.0, initializer_range)
+        self.w1 = self.create_parameter(shape=[held, d_model, d_hidden],
+                                        default_initializer=init)
+        self.w3 = self.create_parameter(shape=[held, d_model, d_hidden],
+                                        default_initializer=init)
+        self.w2 = self.create_parameter(shape=[held, d_hidden, d_model],
+                                        default_initializer=init)
+
+    def forward(self, y, experts, gates):
+        """``y [T, d]``, ``experts``/``gates`` ``[T, k]`` over all
+        ``num_experts`` -> (float32 ``[T, d]``, int32 ``[held]``)."""
+        idx = experts._value
+
+        def closure(y_, gates_, w1, w3, w2):
+            return experts_forward(y_, idx, gates_, w1, w3, w2, self.first,
+                                   self.num_experts)
+
+        return apply_closure(closure, [y, gates, self.w1, self.w3, self.w2],
+                             name="grouped_swiglu_experts")

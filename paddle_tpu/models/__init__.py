@@ -14,3 +14,6 @@ from .bert import (  # noqa
     BertForSequenceClassification, ErnieConfig, ErnieModel,
     ErnieForPretraining, ErniePretrainingCriterion,
     ErnieForSequenceClassification, bert_tiny, bert_base, ernie_3_base)
+from .keye_lm import (  # noqa
+    KeyeLMConfig, KeyeLMModel, KeyeLMForCausalLM,
+    KeyeLMPretrainingCriterion, keye_lm_tiny)

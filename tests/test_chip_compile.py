@@ -152,6 +152,49 @@ def _sharded_public_op(topo, monkeypatch):
     return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
 
 
+def _sparse_core(topo, monkeypatch):
+    """The selected-key attention of the cell keye2-lm-ep8share-s8192 at
+    its own shape, [8192, 32 over 4 heads, 128] under an int8 [8192,
+    8192] selection: forward, dq, dkv and the head-averaged
+    probabilities (``ops/sparse_attention.py``)."""
+    from paddle_tpu.ops import sparse_attention as dsa
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    heads, kv, seq, dim = 32, 4, 8192, 128
+    q, k = spec((seq, heads, dim)), spec((seq, kv, dim))
+    assert dsa.kernels_eligible(seq, dim)
+
+    def fwd_bwd(q_, k_, v_, mask):
+        def loss(a, b, c):
+            out, lse = dsa.core(a, b, c, mask)
+            return out.astype(jnp.float32).sum(), lse
+        grads, lse = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q_, k_, v_)
+        return grads, dsa.mean_head_probs(q_, k_, lse, mask)
+
+    return fwd_bwd, (q, k, k, spec((seq, seq), jnp.int8))
+
+
+def _dropless_experts(topo, monkeypatch):
+    """The same cell's expert layer, 16 held experts of 128 at width 768
+    on 8192 tokens: XLA lowers ``jax.lax.ragged_dot`` and its two
+    gradients to Mosaic kernels, in both sizes of the row buffer
+    (``incubate/distributed/models/moe/grouped.py``)."""
+    from paddle_tpu.incubate.distributed.models.moe import grouped
+    spec = _one_chip_spec(topo)
+    tokens, d, f, held, k = 8192, 2048, 768, 16, 8
+
+    def loss(y, logits, w1, w3, w2):
+        experts, gates = grouped.route(logits, k)
+        out, _ = grouped.experts_forward(y, experts, gates, w1, w3, w2,
+                                         0, 128)
+        return out.sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), (
+        spec((tokens, d)), spec((tokens, 128), jnp.float32),
+        spec((held, d, f)), spec((held, d, f)), spec((held, f, d)))
+
+
 def _lmce_fwd(topo, monkeypatch):
     spec = _one_chip_spec(topo)
     return pallas_lmce._call_fwd, (
@@ -196,6 +239,8 @@ def _refused(build, case_id, pattern, why):
                  id="flash_packed_two_key_blocks"),
     pytest.param(_sharded_public_op, 3, None,
                  id="flash_attention_dp2_mp2"),
+    pytest.param(_sparse_core, 4, None, id="sparse_core_s8192"),
+    pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
     _refused(_lmce_fwd, "lmce_fwd",
              r"failed to legalize operation 'tpu\.truncf'",
              "(f64) -> f32 — the package-wide jax_enable_x64 reaches "
