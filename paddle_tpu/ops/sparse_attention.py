@@ -22,10 +22,12 @@ tie at the threshold is broken by a second search over positions, which
 runs only where a row has one.
 
 The core is dense attention under that mask.  On a TPU it is four Mosaic
-kernels after ``pallas_ops``' unpacked flash family with the mask as one
-more operand (forward, dq, dkv, and the head-averaged probabilities the
-indexer learns from); elsewhere plain ``jax.numpy``, which is also what
-the kernels are checked against.
+kernels (forward, dq, dkv, and the head-averaged probabilities the
+indexer learns from) whose visit is a key head's group: one key, value
+and selection tile, fetched and decoded once, against the query heads
+that read that key head, over the causal triangle of tiles only;
+elsewhere plain ``jax.numpy``, which is also what the kernels are
+checked against.
 """
 
 from __future__ import annotations
@@ -35,12 +37,22 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import pallas_ops
+from .pallas_ops import _LSE_FLOOR, _across, _lane_sums
 
 _LANES = 128
 _NEG = -1e30
 BLOCK = 512                  # the kernels' query and key block
+GROUP = 8                    # the most query heads one visit holds
+_ROOM = 16 << 20             # VMEM for a visit's [BLOCK, BLOCK] values
+# Heads of a visit that one iteration of a kernel's loop holds.  All
+# eight unrolled is 8 % faster a kernel (nothing of one head waits for
+# the last) and 4.95 MB of executable for five layers where two a turn
+# is 0.65: each of a run's two executables then loads 6.3 s slower, 17 %
+# of the Keye cell's `setup_s` (PERF.md section 6, PR 29).
+_TURN = 2
 
 
 # --------------------------------------------------------------------------
@@ -192,17 +204,106 @@ def _block(seq: int) -> int:
     return pallas_ops._fit_block(seq, BLOCK)
 
 
-def _masked_scores(q_ref, k_ref, mask_ref, scale):
-    s = jax.lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    return jnp.where(mask_ref[...].astype(jnp.int32) != 0, s, -jnp.inf)
+def _heads_a_visit(rep: int) -> int:
+    """Query heads one visit runs against its key head's tile: the whole
+    group where it has at most ``GROUP`` heads, else its largest part
+    that divides it, so that the visit's blocks stay inside VMEM."""
+    return max(n for n in range(1, GROUP + 1) if rep % n == 0)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, blocks):
+def _lower_triangle(n: int, parts: int = 0):
+    """The visits of the causal triangle ``j <= i`` of ``n`` blocks a
+    side, and nothing above it, in the order the flat grid axis walks
+    them: int32 tables that the index maps and the kernels read.  By
+    query block (query block i, key block j), a row's key blocks one
+    after the other; with ``parts``, by key block (i, j, part c of the
+    key head's group): a key block's parts and, inside a part, its query
+    blocks.  A square grid's steps above the diagonal fetch and compute
+    nothing and still cost 0.10 us each (PERF.md section 6, PR 29)."""
+    if parts:
+        steps = [(i, j, c) for j in range(n) for c in range(parts)
+                 for i in range(j, n)]
+    else:
+        steps = [(i, j) for i in range(n) for j in range(i + 1)]
+    return tuple(np.asarray(t, np.int32) for t in zip(*steps))
+
+
+def _note_visits(visits: int, n: int, heads: int) -> None:
+    """Counts, when a kernel call is traced, the grid steps a square grid
+    would take and the group visits that compute:
+    ``dsa_core_visits_total{kind=square|visited}``, and the query heads
+    of a visit, ``dsa_core_heads_per_visit``."""
+    from ..observability import metrics as _obs_metrics
+    reg = _obs_metrics.registry()
+    for kind, steps in (("square", n * n), ("visited", n * (n + 1) // 2)):
+        reg.counter("dsa_core_visits_total",
+                    "visits of the sparse core's kernels, counted a call "
+                    "when the call is traced: a key head's part of its "
+                    "query heads against one key block, over the whole "
+                    "square and over the causal triangle that is computed",
+                    labels={"kind": kind}).inc(visits * steps)
+    reg.gauge("dsa_core_heads_per_visit",
+              "query heads that share one fetch and one decoding of a "
+              "key, value and selection tile in the sparse core's "
+              "kernels").set(heads)
+
+
+def _addend(mask_ref):
+    """The selection tile as what the scores take: 0 on a kept key, -inf
+    off it.  Made once a visit, for all its heads."""
+    return jnp.where(mask_ref[...].astype(jnp.int32) != 0,
+                     jnp.float32(0.0), jnp.float32(-jnp.inf))
+
+
+def _visit(q_ref, k_ref):
+    """(rows of a block, head width, query heads) of a visit, read off
+    its query and key blocks."""
+    blk, d = k_ref.shape
+    return blk, d, q_ref.shape[1] // d
+
+
+def _each_head(heads: int, d: int, fn):
+    """``fn(h, cols)`` for every query head of a visit, ``cols`` its lane
+    block of the query block, as a loop inside the kernel that holds
+    ``_TURN`` heads an iteration (an int32 of its own: ``fori_loop``
+    counts in int64 under the package's x64, and Pallas takes ``scan``'s
+    ``unroll`` only at 1 or all)."""
     from jax.experimental import pallas as pl
-    i, j = pl.program_id(1), pl.program_id(2)
-    floor = pallas_ops._LSE_FLOOR
+    if heads == 1:
+        return fn(0, slice(0, d))
+    turn = math.gcd(_TURN, heads)
+
+    def step(i, _):
+        for r in range(turn):
+            h = i * np.int32(turn) + np.int32(r)
+            fn(h, pl.ds(pl.multiple_of(h * np.int32(d), d), d))
+        return i + np.int32(1), None
+    jax.lax.scan(step, np.int32(0), None, length=heads // turn)
+
+
+def _delta(do, o):
+    """``sum_d do * o`` of a row, held in every lane of it."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
+                    keepdims=True)
+    return jnp.broadcast_to(delta, (delta.shape[0], _LANES))
+
+
+def _scores(q, k, scale, addend):
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32
+                               ) * scale + addend
+
+
+def _fwd_kernel(i_tab, j_tab, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                lse_ref, m_scr, l_scr, acc_scr, *, scale):
+    """A visit: one key, value and selection tile against ``heads`` query
+    heads, each a lane block of the query block.  ``m`` is held in every
+    lane of its row and ``l`` as 128 partial sums a row, added up in
+    ``_finish`` (PERF.md section 6, PR 27)."""
+    from jax.experimental import pallas as pl
+    t = pl.program_id(1)
+    i, j = i_tab[t], j_tab[t]
+    blk, d, heads = _visit(q_ref, k_ref)
 
     @pl.when(j == 0)
     def _init():
@@ -210,133 +311,178 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    @pl.when(j <= i)
-    def _run():
-        s = _masked_scores(q_ref, k_ref, mask_ref, scale)
-        m_prev, l_prev = m_scr[...][:, :1], l_scr[...][:, :1]
+    addend = _addend(mask_ref)
+    k, v = k_ref[...], v_ref[...]
+
+    def head(h, cols):
+        s = _scores(q_ref[:, cols], k, scale, addend)
+        m_prev = m_scr[h]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.maximum(m_new, floor)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.exp(jnp.maximum(m_prev, floor) - m_safe)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[...]
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_safe = jnp.maximum(m_new, _LSE_FLOOR)
+        p = jnp.exp(s - _across(m_safe, blk))
+        alpha = jnp.exp(jnp.maximum(m_prev, _LSE_FLOOR) - m_safe)
+        l_scr[h] = alpha * l_scr[h] + _lane_sums(p)
+        acc_scr[:, cols] = acc_scr[:, cols] * _across(alpha, d) + \
+            jax.lax.dot_general(p.astype(v.dtype), v,
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
+    _each_head(heads, d, head)
 
-    @pl.when(j == blocks - 1)
+    @pl.when(j == i)                    # a row ends on the diagonal
     def _finish():
-        l_fin = jnp.maximum(l_scr[...][:, :1], 1e-30)
-        o_ref[...] = (acc_scr[...] / l_fin).astype(o_ref.dtype)
-        lse = jnp.maximum(m_scr[...][:, :1], floor) + jnp.log(l_fin)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        def head(h, cols):
+            l_fin = jnp.maximum(
+                jnp.sum(l_scr[h], axis=-1, keepdims=True), 1e-30)
+            o_ref[:, cols] = (acc_scr[:, cols] / l_fin).astype(o_ref.dtype)
+            lse_ref[h] = jnp.maximum(m_scr[h], _LSE_FLOOR) + jnp.log(l_fin)
+        _each_head(heads, d, head)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, mask_ref,
-               dq_ref, dq_scr, delta_scr, *, scale, blocks):
+def _dq_kernel(i_tab, j_tab, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+               mask_ref, dq_ref, dq_scr, delta_scr, *, scale):
+    """``ds`` is rounded without the scale, which the sum takes once in
+    ``_finish``: one pass over the tile less a head."""
     from jax.experimental import pallas as pl
-    i, j = pl.program_id(1), pl.program_id(2)
+    t = pl.program_id(1)
+    i, j = i_tab[t], j_tab[t]
+    blk, d, heads = _visit(q_ref, k_ref)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
-        delta = jnp.sum(do_ref[...].astype(jnp.float32)
-                        * o_ref[...].astype(jnp.float32), -1, keepdims=True)
-        delta_scr[...] = jnp.broadcast_to(delta, delta_scr.shape)
 
-    @pl.when(j <= i)
-    def _run():
-        k = k_ref[...]
-        p = jnp.exp(_masked_scores(q_ref, k_ref, mask_ref, scale)
-                    - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...],
+        def head(h, cols):
+            delta_scr[h] = _delta(do_ref[:, cols], o_ref[:, cols])
+        _each_head(heads, d, head)
+
+    addend = _addend(mask_ref)
+    k, v = k_ref[...], v_ref[...]
+
+    def head(h, cols):
+        p = jnp.exp(_scores(q_ref[:, cols], k, scale, addend)
+                    - _across(lse_ref[h], blk))
+        dp = jax.lax.dot_general(do_ref[:, cols], v,
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_scr[:, :1]) * scale).astype(k.dtype)
-        dq_scr[...] += jax.lax.dot_general(
+        ds = (p * (dp - _across(delta_scr[h], blk))).astype(k.dtype)
+        dq_scr[:, cols] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+    _each_head(heads, d, head)
 
-    @pl.when(j == blocks - 1)
+    @pl.when(j == i)
     def _finish():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, mask_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, blocks, rep):
-    """Grid (key head, key block, query head of the group, query block):
-    a key block's gradient adds up over the query heads that read it and
-    over their query blocks, in scratch."""
+def _dkv_kernel(i_tab, j_tab, c_tab, q_ref, k_ref, v_ref, do_ref, o_ref,
+                lse_ref, mask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                scale, parts, blocks):
+    """A key block's gradient adds up over the query heads that read it
+    (the visit's, then the group's further parts) and over their query
+    blocks, in scratch."""
     from jax.experimental import pallas as pl
-    j, r, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    t = pl.program_id(1)
+    i, j, c = i_tab[t], j_tab[t], c_tab[t]
+    blk, d, heads = _visit(q_ref, k_ref)
 
-    @pl.when((r == 0) & (i == 0))
+    @pl.when((c == 0) & (i == j))
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    @pl.when(i >= j)
-    def _run():
-        q, do = q_ref[...], do_ref[...]
-        delta = jnp.sum(do.astype(jnp.float32)
-                        * o_ref[...].astype(jnp.float32), -1, keepdims=True)
-        p = jnp.exp(_masked_scores(q_ref, k_ref, mask_ref, scale)
-                    - lse_ref[0][:, :1])
+    addend = _addend(mask_ref)
+    k, v = k_ref[...], v_ref[...]
+
+    def head(h, cols):
+        q, do = q_ref[:, cols], do_ref[:, cols]
+        p = jnp.exp(_scores(q, k, scale, addend) - _across(lse_ref[h], blk))
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[...], (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        ds = (p * (dp - _across(_delta(do, o_ref[:, cols]), blk))
+              ).astype(q.dtype)
         dk_scr[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+    _each_head(heads, d, head)
 
-    @pl.when((r == rep - 1) & (i == blocks - 1))
+    @pl.when((c == parts - 1) & (i == blocks - 1))
     def _finish():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, scale, heads):
+def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, scale, of):
+    """A visit's heads are added up before the output block is touched,
+    which stays resident over the visits of a tile."""
     from jax.experimental import pallas as pl
-    i, j, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    i, j, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    blk, d, heads = _visit(q_ref, k_ref)
 
-    @pl.when(h == 0)
+    @pl.when(b == 0)
     def _init():
         p_ref[...] = jnp.zeros_like(p_ref[...])
 
     @pl.when(j <= i)
     def _run():
-        p_ref[...] += jnp.exp(_masked_scores(q_ref, k_ref, mask_ref, scale)
-                              - lse_ref[0][:, :1]) * (1.0 / heads)
+        addend = _addend(mask_ref)
+        k = k_ref[...]
+
+        def head(h, cols):      # the mean's 1 / of goes with the lse
+            p_ref[...] += jnp.exp(
+                _scores(q_ref[:, cols], k, scale, addend)
+                - _across(lse_ref[h] + math.log(of), blk))
+        _each_head(heads, d, head)
 
 
 def _lanes(lse):
     return jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
 
 
-def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, *args):
+def _bytes(shape, dtype) -> int:
+    return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, *args,
+          tables=(), room=_ROOM):
+    """One kernel call; the specs and shapes of its results come as
+    lists and so do the results.  ``tables`` are scalar-prefetched: the index maps
+    and the kernel get them after the grid indices and before the
+    operands.  The VMEM limit is reckoned from what the call holds: every
+    operand block twice (it is fetched while the last one is in use), the
+    scratch, and ``room`` for the score-sized values of a visit (1 MiB
+    each at 512 rows); the compiler's default of 16 MiB is less than a
+    group of eight heads' blocks alone.  The room is not only a ceiling:
+    the compiler lays a kernel out by it.  With 64 MiB in place of 16 a
+    call at [8192, 32 over 4, 128] takes 5895 for 6007 us (dq) and 7265
+    for 7449 (dkv), and 4162 for 4047 (forward) and 3255 for 2584
+    (probabilities): PERF.md section 6, PR 29; so the two backward
+    kernels ask for ``4 * _ROOM``."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    held = sum(2 * _bytes(spec.block_shape, x.dtype) for spec, x in zip(
+        in_specs + out_specs, list(args) + out_shape))
+    held += sum(_bytes(s.shape, s.dtype) for s in scratch)
     return pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, scratch_shapes=list(scratch),
-        interpret=pallas_ops._interpret())(*args)
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=list(scratch)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=held + room),
+        interpret=pallas_ops._interpret())(*tables, *args)
 
 
-def _key_head(h, rep: int):
-    return jax.lax.div(h, jnp.int32(rep))
-
-
-def _specs(blk: int, d: int, where):
-    """Block specs of the operands: q-like ``[S, H * D]`` and key-like
-    ``[S, G * D]`` (a head is a lane block), the lane-broadcast lse and
-    the mask.  ``where(*grid indices)`` gives (query head, key head,
-    query block, key block) of a grid step; a step above the diagonal
-    computes nothing, and the block it would fetch is held at the
-    diagonal's so that it fetches nothing either.  ``h * 0`` and
+def _specs(blk: int, d: int, heads: int, where):
+    """Block specs of the operands: q-like ``[S, H * D]``, of which a
+    visit takes ``heads`` adjacent lane blocks, and key-like ``[S, G *
+    D]``, of which it takes one; the lane-broadcast lse and the mask.
+    ``where(*grid indices, *tables)`` gives (block of query heads, key
+    head, query block, key block) of a grid step.  ``b * 0`` and
     ``lax.div``: under jax_enable_x64 a literal 0 or a ``//`` traces as
     int64, and Mosaic refuses the index map."""
     from jax.experimental import pallas as pl
@@ -344,42 +490,60 @@ def _specs(blk: int, d: int, where):
     def spec(shape, index):
         return pl.BlockSpec(shape, lambda *ids: index(*where(*ids)))
 
-    rows = spec((blk, d), lambda h, g, i, j: (i, h))
-    keys = spec((blk, d), lambda h, g, i, j: (j, g))
-    lse = spec((1, blk, _LANES), lambda h, g, i, j: (h, i, h * 0))
-    mask = spec((blk, blk), lambda h, g, i, j: (i, j))
+    rows = spec((blk, heads * d), lambda b, g, i, j: (i, b))
+    keys = spec((blk, d), lambda b, g, i, j: (j, g))
+    lse = spec((heads, blk, _LANES), lambda b, g, i, j: (b, i, b * 0))
+    mask = spec((blk, blk), lambda b, g, i, j: (i, j))
     return rows, keys, lse, mask
 
 
-def _by_query_block(rep: int):
-    """Grid (query head, query block, key block)."""
-    return lambda h, i, j: (h, _key_head(h, rep), i, jnp.minimum(i, j))
+def _key_head(b, parts: int):
+    return jax.lax.div(b, jnp.int32(parts))
 
 
-def _by_key_block(rep: int):
-    """Grid (key head, key block, query head of the group, query block)."""
-    return lambda g, j, r, i: (g * rep + r, g, jnp.maximum(i, j), j)
+def _by_query_block(parts: int):
+    """Grid (block of query heads, visit of the triangle)."""
+    return lambda b, t, i_tab, j_tab: (
+        b, _key_head(b, parts), i_tab[t], j_tab[t])
+
+
+def _by_key_block(parts: int):
+    """Grid (key head, visit of the triangle and part of the group)."""
+    return lambda g, t, i_tab, j_tab, c_tab: (
+        g * parts + c_tab[t], g, i_tab[t], j_tab[t])
 
 
 def _flat(x):
     return x.reshape(x.shape[0], -1)
 
 
+def _geometry(q, k):
+    """(block, blocks a side, query heads a visit, visits' parts of a
+    key head's group) of a call."""
+    s, h, _ = q.shape
+    blk, rep = _block(s), h // k.shape[1]
+    heads = _heads_a_visit(rep)
+    return blk, s // blk, heads, rep // heads
+
+
 def _core_fwd_kernels(q, k, v, mask):
     from jax.experimental.pallas import tpu as pltpu
     s, h, d = q.shape
-    blk, rep = _block(s), h // k.shape[1]
-    n = s // blk
-    rows, keys, lse_spec, mask_spec = _specs(blk, d, _by_query_block(rep))
+    blk, n, heads, parts = _geometry(q, k)
+    _note_visits(h // heads, n, heads)
+    tables = _lower_triangle(n)
+    rows, keys, lse_spec, mask_spec = _specs(blk, d, heads,
+                                             _by_query_block(parts))
     out, lse = _call(
-        functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d), blocks=n),
-        (h, n, n), [rows, keys, keys, mask_spec], [rows, lse_spec],
+        functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d)),
+        (h // heads, len(tables[0])), [rows, keys, keys, mask_spec],
+        [rows, lse_spec],
         [jax.ShapeDtypeStruct((s, h * d), q.dtype),
          jax.ShapeDtypeStruct((h, s, _LANES), jnp.float32)],
-        [pltpu.VMEM((blk, _LANES), jnp.float32),
-         pltpu.VMEM((blk, _LANES), jnp.float32),
-         pltpu.VMEM((blk, d), jnp.float32)],
-        _flat(q), _flat(k), _flat(v), mask)
+        [pltpu.VMEM((heads, blk, _LANES), jnp.float32),
+         pltpu.VMEM((heads, blk, _LANES), jnp.float32),
+         pltpu.VMEM((blk, heads * d), jnp.float32)],
+        _flat(q), _flat(k), _flat(v), mask, tables=tables)
     return out.reshape(q.shape), lse[..., 0]
 
 
@@ -387,52 +551,57 @@ def _core_bwd_kernels(q, k, v, mask, out, lse, do):
     from jax.experimental.pallas import tpu as pltpu
     s, h, d = q.shape
     g = k.shape[1]
-    blk, rep = _block(s), h // g
-    n = s // blk
+    blk, n, heads, parts = _geometry(q, k)
+    _note_visits(2 * (h // heads), n, heads)
     scale = 1.0 / math.sqrt(d)
-    lse_b = _lanes(lse)
-    operands = tuple(_flat(x) for x in (q, k, v, do, out)) + (lse_b, mask)
-    rows, keys, lse_spec, mask_spec = _specs(blk, d, _by_query_block(rep))
-    dq = _call(
-        functools.partial(_dq_kernel, scale=scale, blocks=n), (h, n, n),
-        [rows, keys, keys, rows, rows, lse_spec, mask_spec], rows,
-        jax.ShapeDtypeStruct((s, h * d), q.dtype),
-        [pltpu.VMEM((blk, d), jnp.float32),
-         pltpu.VMEM((blk, _LANES), jnp.float32)],
-        *operands)
-    rows, keys, lse_spec, mask_spec = _specs(blk, d, _by_key_block(rep))
+    operands = tuple(_flat(x) for x in (q, k, v, do, out)) + (
+        _lanes(lse), mask)
+    tables = _lower_triangle(n)
+    rows, keys, lse_spec, mask_spec = _specs(blk, d, heads,
+                                             _by_query_block(parts))
+    dq, = _call(
+        functools.partial(_dq_kernel, scale=scale),
+        (h // heads, len(tables[0])),
+        [rows, keys, keys, rows, rows, lse_spec, mask_spec], [rows],
+        [jax.ShapeDtypeStruct((s, h * d), q.dtype)],
+        [pltpu.VMEM((blk, heads * d), jnp.float32),
+         pltpu.VMEM((heads, blk, _LANES), jnp.float32)],
+        *operands, tables=tables, room=4 * _ROOM)
+    tables = _lower_triangle(n, parts)
+    rows, keys, lse_spec, mask_spec = _specs(blk, d, heads,
+                                             _by_key_block(parts))
     dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, blocks=n, rep=rep),
-        (g, n, rep, n),
+        functools.partial(_dkv_kernel, scale=scale, parts=parts, blocks=n),
+        (g, len(tables[0])),
         [rows, keys, keys, rows, rows, lse_spec, mask_spec], [keys, keys],
         [jax.ShapeDtypeStruct((s, g * d), k.dtype)] * 2,
         [pltpu.VMEM((blk, d), jnp.float32),
          pltpu.VMEM((blk, d), jnp.float32)],
-        *operands)
+        *operands, tables=tables, room=4 * _ROOM)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _mean_head_probs_kernels(q, k, lse, mask):
+    """Grid (query block, key block, block of query heads): the output
+    tile stays resident over its visits.  Above the diagonal a step
+    writes its zeros and fetches nothing: every block index is held where
+    the last step left it."""
     from jax.experimental import pallas as pl
     s, h, d = q.shape
-    blk, rep = _block(s), h // k.shape[1]
-    n = s // blk
+    blk, n, heads, parts = _geometry(q, k)
+    _note_visits(h // heads, n, heads)
 
-    def head(i, j, hh):          # held at 0 above the diagonal: no fetch
-        return jnp.where(j <= i, hh, hh * 0)
+    def where(i, j, b):
+        b = jnp.where(j <= i, b, b * 0)
+        return b, _key_head(b, parts), i, jnp.minimum(i, j)
 
-    rows = pl.BlockSpec((blk, d), lambda i, j, hh: (i, head(i, j, hh)))
-    keys = pl.BlockSpec((blk, d), lambda i, j, hh: (
-        jnp.minimum(i, j), _key_head(head(i, j, hh), rep)))
-    lse_spec = pl.BlockSpec((1, blk, _LANES), lambda i, j, hh: (
-        head(i, j, hh), i, hh * 0))
-    tile = pl.BlockSpec((blk, blk), lambda i, j, hh: (i, jnp.minimum(i, j)))
-    out = pl.BlockSpec((blk, blk), lambda i, j, hh: (i, j))
+    rows, keys, lse_spec, mask_spec = _specs(blk, d, heads, where)
     return _call(
-        functools.partial(_probs_kernel, scale=1.0 / math.sqrt(d), heads=h),
-        (n, n, h), [rows, keys, lse_spec, tile], out,
-        jax.ShapeDtypeStruct((s, s), jnp.float32), (),
-        _flat(q), _flat(k), _lanes(lse), mask)
+        functools.partial(_probs_kernel, scale=1.0 / math.sqrt(d), of=h),
+        (n, n, h // heads), [rows, keys, lse_spec, mask_spec],
+        [pl.BlockSpec((blk, blk), lambda i, j, b: (i, j))],
+        [jax.ShapeDtypeStruct((s, s), jnp.float32)], (),
+        _flat(q), _flat(k), _lanes(lse), mask)[0]
 
 
 @jax.custom_vjp

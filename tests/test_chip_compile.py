@@ -152,15 +152,17 @@ def _sharded_public_op(topo, monkeypatch):
     return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
 
 
-def _sparse_core(topo, monkeypatch):
+def _sparse_core(topo, monkeypatch, heads=32, kv=4, seq=8192):
     """The selected-key attention of the cell keye2-lm-ep8share-s8192 at
     its own shape, [8192, 32 over 4 heads, 128] under an int8 [8192,
     8192] selection: forward, dq, dkv and the head-averaged
-    probabilities (``ops/sparse_attention.py``)."""
+    probabilities (``ops/sparse_attention.py``), a visit eight query
+    heads of a key head, with the VMEM limit the calls reckon
+    themselves (25 to 81 MiB; the compiler's default is 16)."""
     from paddle_tpu.ops import sparse_attention as dsa
     monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
     spec = _one_chip_spec(topo)
-    heads, kv, seq, dim = 32, 4, 8192, 128
+    dim = 128
     q, k = spec((seq, heads, dim)), spec((seq, kv, dim))
     assert dsa.kernels_eligible(seq, dim)
 
@@ -173,6 +175,12 @@ def _sparse_core(topo, monkeypatch):
         return grads, dsa.mean_head_probs(q_, k_, lse, mask)
 
     return fwd_bwd, (q, k, k, spec((seq, seq), jnp.int8))
+
+
+def _sparse_core_group_of_one(topo, monkeypatch):
+    """... with as many key heads as query heads, [2048, 8 over 8, 128]:
+    a visit is one query head."""
+    return _sparse_core(topo, monkeypatch, heads=8, kv=8, seq=2048)
 
 
 def _dropless_experts(topo, monkeypatch):
@@ -240,6 +248,8 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_sharded_public_op, 3, None,
                  id="flash_attention_dp2_mp2"),
     pytest.param(_sparse_core, 4, None, id="sparse_core_s8192"),
+    pytest.param(_sparse_core_group_of_one, 4, None,
+                 id="sparse_core_group_of_one_s2048"),
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
     _refused(_lmce_fwd, "lmce_fwd",
              r"failed to legalize operation 'tpu\.truncf'",

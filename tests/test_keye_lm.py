@@ -200,19 +200,59 @@ def test_topk_at_least_the_sequence_is_dense_causal_attention():
     np.testing.assert_allclose(out, want._value[0], rtol=1e-4, atol=1e-5)
 
 
-def test_mosaic_kernels_agree_with_the_plain_core(monkeypatch):
-    """The four kernels, interpreted: forward, both backward kernels and
-    the head-averaged probabilities."""
+def _selection(kind, seq, block, rng):
+    """An int8 ``[seq, seq]`` selection of the named kind."""
+    rows, cols = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    causal = cols <= rows
+    if kind in ("topk", "causal"):      # what the program makes: the top
+        qi, ki, wi = (                  # 48 of a row, or topk >= S
+            jnp.asarray(rng.standard_normal(s), jnp.float32)
+            for s in ((seq, 2, 8), (seq, 8), (seq, 2)))
+        mask = dsa.select(qi, ki, wi, 48 if kind == "topk" else seq, 64)
+        assert (np.asarray(mask) == causal).all() == (kind == "causal")
+        return mask
+    if kind == "rows_without_a_tile":
+        # odd rows keep their own block's keys only: in every tile below
+        # the diagonal half the rows keep nothing, and no tile is empty
+        keep = causal & ((rows % 2 == 0) | (cols >= rows // block * block))
+        tiles = keep.reshape(seq // block, block, seq // block, block)
+        assert tiles.any((1, 3))[np.tril_indices(seq // block)].all()
+        assert not tiles.any(3)[:, 1::2][1:, :, 0].any()
+    else:                       # "an_empty_tile": the last 100 keys
+        keep = causal & (cols > rows - 100)
+        tiles = keep.reshape(seq // block, block, seq // block, block)
+        assert not tiles.any((1, 3))[2, 0]
+    return jnp.asarray(keep, jnp.int8)
+
+
+@pytest.mark.parametrize("heads, kv, blocks, selection, group", [
+    pytest.param(8, 1, 3, "topk", 8, id="rep8-three_blocks"),
+    pytest.param(4, 2, 3, "topk", 8, id="rep2-three_blocks"),
+    pytest.param(2, 2, 3, "topk", 8, id="rep1-three_blocks"),
+    pytest.param(4, 2, 1, "topk", 8, id="rep2-one_block"),
+    pytest.param(4, 2, 4, "rows_without_a_tile", 8,
+                 id="rep2-rows_that_keep_no_key_of_a_tile"),
+    pytest.param(4, 2, 4, "an_empty_tile", 8, id="rep2-an_empty_tile"),
+    pytest.param(4, 2, 2, "causal", 8, id="rep2-topk_at_least_the_sequence"),
+    pytest.param(4, 1, 3, "topk", 2, id="rep4-a_group_in_two_parts"),
+])
+def test_mosaic_kernels_agree_with_the_plain_core(monkeypatch, heads, kv,
+                                                  blocks, selection, group):
+    """The four kernels, interpreted, against the plain core: out, lse,
+    dq, dk, dv and the head-averaged probabilities, for a visit of
+    eight, two and one query head, one block and several, and the
+    selections that leave a row or a tile without a key."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(dsa, "BLOCK", 128)
+    monkeypatch.setattr(dsa, "GROUP", group)
     rng = np.random.default_rng(6)
-    seq, heads, kv, d = 256, 4, 2, 128
+    seq, d = blocks * 128, 128
     q, k, v, w = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in (
         (seq, heads, d), (seq, kv, d), (seq, kv, d), (seq, heads, d)))
-    qi, ki, wi = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in (
-        (seq, 2, 8), (seq, 8), (seq, 2)))
-    mask = dsa.select(qi, ki, wi, 48, 64)
-    monkeypatch.setattr(dsa, "BLOCK", 128)        # more than one block
+    mask = _selection(selection, seq, 128, rng)
     assert dsa.kernels_eligible(seq, d)
+    assert dsa._geometry(q, k) == (128, blocks, min(heads // kv, group),
+                                   max(heads // kv // group, 1))
 
     def weighted(core):
         def fn(q_, k_, v_):
@@ -227,6 +267,44 @@ def test_mosaic_kernels_agree_with_the_plain_core(monkeypatch):
     np.testing.assert_allclose(
         dsa.mean_head_probs(q, k, lse, mask),
         dsa.mean_head_probs_plain(q, k, lse_p, mask), atol=1e-6)
+
+
+def _visits(kind):
+    from paddle_tpu.observability import metrics
+    return metrics.registry().counter(
+        "dsa_core_visits_total", labels={"kind": kind}).collect()
+
+
+@pytest.mark.parametrize("seq, heads, kv", [
+    (8192, 32, 4), (2048, 8, 8), (1024, 16, 1)])
+def test_visit_counter_reads_the_closed_form(monkeypatch, seq, heads, kv):
+    """``dsa_core_visits_total`` counts, as a kernel call is traced, the
+    steps of a square grid of visits and the visits of the causal
+    triangle, G n² and G n (n + 1) / 2 for n = S / 512 where a visit is a
+    key head's whole group, and ``dsa_core_heads_per_visit`` is the
+    group: H / G, at most ``GROUP``."""
+    from paddle_tpu.observability import metrics
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    d, n = 128, seq // 512
+    per_visit = min(heads // kv, dsa.GROUP)
+    want = [heads // per_visit * n * n,
+            heads // per_visit * n * (n + 1) // 2]
+    if heads // kv <= dsa.GROUP:
+        assert want == [kv * n * n, kv * n * (n + 1) // 2]
+    q = jax.ShapeDtypeStruct((seq, heads, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((seq, kv, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((heads, seq), jnp.float32)
+    mask = jax.ShapeDtypeStruct((seq, seq), jnp.int8)
+    for calls, fn, args in (
+            (1, dsa._core_fwd_kernels, (q, k, k, mask)),
+            (2, dsa._core_bwd_kernels, (q, k, k, mask, q, lse, q)),
+            (1, dsa._mean_head_probs_kernels, (q, k, lse, mask))):
+        before = [_visits(kind) for kind in ("square", "visited")]
+        jax.eval_shape(fn, *args)
+        assert [_visits(kind) - was for kind, was in zip(
+            ("square", "visited"), before)] == [calls * w for w in want]
+        assert metrics.registry().gauge(
+            "dsa_core_heads_per_visit").collect() == per_visit
 
 
 def test_three_equal_position_streams_are_plain_rotary():
