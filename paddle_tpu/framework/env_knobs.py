@@ -47,32 +47,12 @@ def _k(name: str, default: str, kind: str, doc: str) -> None:
     KNOBS[name] = Knob(name, default, kind, doc)
 
 
-# -- kernels (ops/pallas_ops.py, ops/pallas_lmce.py) ------------------------
+# -- kernels (ops/pallas_ops.py) --------------------------------------------
 _k("PADDLE_TPU_PALLAS_INTERPRET", "off", "bool",
    "Run Pallas kernels in interpreter mode so CPU tests exercise the "
    "actual kernel code, not just the composed fallback.")
 _k("PADDLE_TPU_DISABLE_PALLAS", "off", "bool",
    "Force the composed JAX fallback for every Pallas kernel.")
-_k("PADDLE_TPU_FLASH_HEADPACK", "1", "int",
-   "Head-packing toggle for the flash-attention kernel (0 disables).")
-_k("PADDLE_TPU_FLASH_BQ", "512", "int",
-   "Flash-attention query rows of the resident block, what one grid "
-   "step holds in VMEM (fitted down to divide the sequence); the "
-   "packed kernels walk it as compute tiles sized from the shape.")
-_k("PADDLE_TPU_FLASH_BK", "1024", "int",
-   "Flash-attention key/value rows of the resident block (unset, the "
-   "packed dq kernel keeps up to 2048).")
-_k("PADDLE_TPU_FLASH_FUSED_BWD", "off", "bool",
-   "Opt into the fused flash-attention backward kernel.")
-_k("PADDLE_TPU_FLASH_NO_PACKED", "off", "bool",
-   "Disable the packed (batch*heads-collapsed) flash kernel variant.")
-_k("PADDLE_TPU_FUSED_LMCE", "off", "bool",
-   "Bench A/B gate: fold the LM head into the streaming-CE kernel "
-   "(read by bench.py).")
-_k("PADDLE_TPU_LMCE_BN", "256", "int",
-   "Fused LM-head CE row-block size.")
-_k("PADDLE_TPU_LMCE_BV", "512", "int",
-   "Fused LM-head CE vocab-block size.")
 
 # -- datasets ---------------------------------------------------------------
 _k("PADDLE_TPU_SYNTH_N", "dataset-native size", "int",

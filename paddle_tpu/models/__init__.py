@@ -7,7 +7,6 @@ Built from fleet.meta_parallel layers so the same model runs serial
 
 from .gpt import (  # noqa
     GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion,
-    enable_fused_lmce,
     GPTForCausalLMPipe, gpt_tiny, gpt2_small, gpt3_1p3b)
 from .bert import (  # noqa
     BertConfig, BertModel, BertForPretraining, BertPretrainingCriterion,

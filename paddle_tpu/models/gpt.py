@@ -16,7 +16,6 @@ from typing import Optional
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from .. import ops
 from ..tensor import Tensor
@@ -213,27 +212,18 @@ class GPTModel(nn.Layer):
 
 class GPTForCausalLM(nn.Layer):
     """LM head ties the vocab-parallel embedding weight (upstream
-    parity: GPT lm head matmuls against word_embeddings.weight^T).
-
-    ``skip_lm_head=True`` (set by enabling the fused lm-head CE path —
-    see GPTPretrainingCriterion) returns the final hidden states
-    instead of logits; the criterion then folds the vocab matmul into
-    the Pallas streaming-CE kernel so the [B, S, V] logits never hit
-    HBM (ops/pallas_lmce.py)."""
+    parity: GPT lm head matmuls against word_embeddings.weight^T)."""
 
     def __init__(self, config: GPTConfig):
         super().__init__()
         self.gpt = GPTModel(config)
         self.config = config
-        self.skip_lm_head = False
 
     def lm_weight(self):
         return self.gpt.embeddings.word_embeddings.weight
 
     def forward(self, input_ids, position_ids=None):
         hidden = self.gpt(input_ids, position_ids)
-        if self.skip_lm_head:
-            return hidden
         with jax.named_scope("head"):
             logits = ops.matmul(hidden, self.lm_weight(),
                                 transpose_y=True)
@@ -241,58 +231,22 @@ class GPTForCausalLM(nn.Layer):
 
 
 class GPTPretrainingCriterion(nn.Layer):
-    """Causal LM loss (parallel cross entropy over the sharded vocab).
+    """Causal LM loss (parallel cross entropy over the sharded vocab)."""
 
-    ``lm_weight_fn``: enables the FUSED lm-head+CE path — forward then
-    expects final HIDDEN states (the model must set
-    ``skip_lm_head=True``) and computes the loss with the Pallas
-    streaming kernel, never materializing logits.  Enable both sides
-    with ``enable_fused_lmce(model, criterion)``."""
-
-    def __init__(self, config: Optional[GPTConfig] = None,
-                 lm_weight_fn=None):
+    def __init__(self, config: Optional[GPTConfig] = None):
         super().__init__()
         self.loss_fn = ParallelCrossEntropy()
-        self._lm_weight_fn = lm_weight_fn
 
     @jax.named_scope("loss")
     def forward(self, logits, labels, loss_mask=None):
-        # logits [b, s, V] (or hidden [b, s, D] in fused mode);
-        # labels [b, s] — shift-by-one is the caller's responsibility
-        if self._lm_weight_fn is not None:
-            loss = self._fused_loss(logits, labels)
-        else:
-            loss = self.loss_fn(logits, labels)
+        # logits [b, s, V]; labels [b, s] — shift-by-one is the caller's
+        # responsibility
+        loss = self.loss_fn(logits, labels)
         if loss_mask is not None:
             loss = loss * loss_mask
             return ops.sum(loss) / ops.maximum(
                 ops.sum(loss_mask), ops.full([], 1e-9))
         return ops.mean(loss)
-
-    def _fused_loss(self, hidden, labels):
-        from ..ops.pallas_lmce import fused_linear_cross_entropy
-        from ..ops._primitive import apply_closure
-        from ..tensor import Tensor as _T
-        w = self._lm_weight_fn()
-        b, s, d = hidden.shape
-        lab = (labels._value if isinstance(labels, _T)
-               else jnp.asarray(labels)).reshape(-1)
-
-        def closure(h_v, w_v):
-            per_tok = fused_linear_cross_entropy(
-                h_v.reshape(-1, d), w_v, lab)
-            return per_tok.reshape(b, s)
-
-        return apply_closure(closure, [hidden, w], name="fused_lmce")
-
-
-def enable_fused_lmce(model: "GPTForCausalLM",
-                      criterion: "GPTPretrainingCriterion"):
-    """Switch the (model, criterion) pair to the fused lm-head CE path
-    (PADDLE_TPU_FUSED_LMCE bench knob)."""
-    model.skip_lm_head = True
-    criterion._lm_weight_fn = model.lm_weight
-    return model, criterion
 
 
 # ---------------------------------------------------------------------------

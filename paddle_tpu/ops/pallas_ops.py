@@ -25,10 +25,13 @@ Feature coverage (upstream flash_attn / flash_attn_varlen parity):
   streaming Pallas kernel is used on the dropout-free path (the common
   LLM-training configuration).  Semantics are never silently dropped.
 
-Which form runs is decided by platform and shape alone.  A kernel the
-compiler refuses is a compile error in the caller's step: nothing here
-catches it and hands the call to the composed form, because a training
-run that quietly lost its kernels looks exactly like one that has them.
+Which form runs (the packed kernels, the [BH, S, D] kernels or the
+composed form) is decided in one place, ``_attention_form``, from the
+platform and the shape one device holds; no environment variable picks
+a kernel or a block size.  A kernel the compiler refuses is a compile
+error in the caller's step: nothing here catches it and hands the call
+to the composed form, because a training run that quietly lost its
+kernels looks exactly like one that has them.
 Under a mesh of several devices the kernels run per device inside a
 ``shard_map`` (``_per_device``): Mosaic kernels cannot be partitioned
 by GSPMD.
@@ -41,7 +44,6 @@ from __future__ import annotations
 import functools
 import logging
 import math
-import os
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -69,13 +71,6 @@ def _warn_once(tag: str, msg: str) -> None:
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def _block_default(name: str, fallback: int) -> int:
-    try:
-        return int(env_knobs.get_raw(name, fallback))  # lint: allow(env-knobs): literal-name pass-through — every call site passes a registered literal (the wiring census sees them) and get_raw still KeyErrors on typos at runtime
-    except ValueError:
-        return fallback
 
 
 def _interpret() -> bool:
@@ -110,6 +105,13 @@ def _fit_block(seq: int, requested: int) -> int:
 # pallas flash kernel).
 _LANES = 128
 _SUBLANES = 8
+
+# The resident block, what one grid step holds in VMEM: query rows
+# against key rows, each fitted down to divide its sequence.  Of the
+# sizes run on a v5e these won (PERF.md section 6, PR 27); a kernel
+# entry's ``block_q``/``block_k`` arguments override them.
+_BLOCK_Q = 512
+_BLOCK_K = 1024
 
 
 def _flash_kernel(*refs, scale: float, causal: bool, block_q: int,
@@ -190,125 +192,6 @@ def _flash_kernel(*refs, scale: float, causal: bool, block_q: int,
         lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LANES))
 
 
-def _flash_kernel_hpack(*refs, scale: float, causal: bool, hp: int,
-                        block_q: int, block_k: int, seq_k: int):
-    """Head-PAIR forward kernel (PADDLE_TPU_FLASH_HEADPACK=2): each
-    program instance owns ``hp`` consecutive heads, blocks are
-    [hp, block_q, d], and the QK^T / PV contractions run as BATCHED
-    dots.  The MXU-utilisation experiment VERDICT r4 #9 names: at
-    head_dim 64 a single head's contraction uses half the 128-lane
-    datapath; co-resident head pairs give Mosaic two back-to-back
-    64-contraction matmuls per block plus full-width vector work for
-    the softmax — whether that wins on real hardware is not measured.
-    Segment-ids not supported (caller falls back to hp=1)."""
-    from jax.experimental import pallas as pl
-
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-
-    @pl.when(kv_idx == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr[...], -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr[...])
-        acc_scr[...] = jnp.zeros_like(acc_scr[...])
-
-    def body():
-        q = q_ref[...]                       # [hp, bq, d]
-        k = k_ref[...]                       # [hp, bk, d]
-        v = v_ref[...]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [hp, bq, bk]
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where((q_pos >= k_pos)[None], s, -jnp.inf)
-        m_prev = m_scr[...][:, :, :1]        # [hp, bq, 1]
-        l_prev = l_scr[...][:, :, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.maximum(m_new, _LSE_FLOOR)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.exp(jnp.maximum(m_prev, _LSE_FLOOR) - m_safe)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    if causal:
-        @pl.when(kv_idx * block_k <= q_idx * block_q + block_q - 1)
-        def _run():
-            body()
-    else:
-        body()
-
-    n_kv = seq_k // block_k
-
-    @pl.when(kv_idx == n_kv - 1)
-    def _finish():
-        l_fin = l_scr[...][:, :, :1]
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l_fin, 1e-30)).astype(
-            o_ref.dtype)
-        lse = (jnp.maximum(m_scr[...][:, :, :1], _LSE_FLOOR) +
-               jnp.log(jnp.maximum(l_fin, 1e-30)))
-        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
-
-
-def _headpack() -> int:
-    try:
-        return int(env_knobs.get_raw("PADDLE_TPU_FLASH_HEADPACK", "1"))
-    except ValueError:
-        return 1
-
-
-def _pallas_flash_bh_hpack(q, k, v, hp, *, causal, block_q, block_k):
-    """hp-head-per-program variant of _pallas_flash_bh (same outputs)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    block_q = _fit_block(
-        sq, block_q or _block_default("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(
-        sk, block_k or _block_default("PADDLE_TPU_FLASH_BK", 1024))
-    scale = 1.0 / math.sqrt(d)
-    grid = (bh // hp, sq // block_q, sk // block_k)
-    kernel = functools.partial(
-        _flash_kernel_hpack, scale=scale, causal=causal, hp=hp,
-        block_q=block_q, block_k=block_k, seq_k=sk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((hp, block_q, d), lambda b, i, j: (b, i, b * 0)),
-            pl.BlockSpec((hp, block_k, d), lambda b, i, j: (b, j, b * 0)),
-            pl.BlockSpec((hp, block_k, d), lambda b, i, j: (b, j, b * 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((hp, block_q, d), lambda b, i, j: (b, i, b * 0)),
-            pl.BlockSpec((hp, block_q, _LANES),
-                         lambda b, i, j: (b, i, b * 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
-            pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
-            pltpu.VMEM((hp, block_q, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(q, k, v)
-    return out, lse
-
-
 def _pallas_flash_bh(q, k, v, q_seg=None, k_seg=None, *, causal: bool,
                      block_q: Optional[int] = None,
                      block_k: Optional[int] = None):
@@ -323,15 +206,8 @@ def _pallas_flash_bh(q, k, v, q_seg=None, k_seg=None, *, causal: bool,
     bh, sq, d = q.shape
     sk = k.shape[1]
     has_seg = q_seg is not None
-    hp = _headpack()
-    if (hp > 1 and not has_seg and bh % hp == 0 and d <= 64):
-        # head-dim-64 MXU experiment: hp consecutive heads per program
-        return _pallas_flash_bh_hpack(q, k, v, hp, causal=causal,
-                                      block_q=block_q, block_k=block_k)
-    block_q = _fit_block(
-        sq, block_q or _block_default("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(
-        sk, block_k or _block_default("PADDLE_TPU_FLASH_BK", 1024))
+    block_q = _fit_block(sq, block_q or _BLOCK_Q)
+    block_k = _fit_block(sk, block_k or _BLOCK_K)
     scale = 1.0 / math.sqrt(d)
     grid = (bh, sq // block_q, sk // block_k)
     kernel = functools.partial(
@@ -382,113 +258,9 @@ def _pallas_flash_bh(q, k, v, q_seg=None, k_seg=None, *, causal: bool,
 
 # ---------------------------------------------------------------------------
 # Pallas backward kernels — standard flash-attention backward: recompute
-# P per block from the saved lse; never materialise [Sq, Sk] in HBM.
-#
-# Preferred path: ONE fused kernel over grid (bh, kv, q) computing dq,
-# dk, dv AND the delta rowsum in a single sweep — s/p are recomputed
-# once per (q, kv) block pair instead of once in a dQ pass and again in
-# a dK/dV pass (5 block-matmuls vs 7, half the HBM input reads, no
-# [bh, sq, LANES] delta broadcast in XLA).  dq accumulates in a
-# whole-sequence VMEM scratch (grid steps run sequentially on a TPU
-# core, so scratch persists across the kv loop) and is flushed on the
-# last kv iteration.  The split dQ / dK/dV kernels are kept below as a
-# fallback for shapes whose full-seq dq scratch would not fit VMEM.
+# P per block from the saved lse; never materialise [Sq, Sk] in HBM.  A
+# dQ pass, then a dK/dV pass, each with one block of scratch.
 # ---------------------------------------------------------------------------
-def _flash_bwd_fused_kernel(*refs, scale: float, causal: bool,
-                            block_q: int, block_k: int, seq_q: int,
-                            seq_k: int, has_seg: bool):
-    from jax.experimental import pallas as pl
-
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref, ks_ref,
-         dq_ref, dk_ref, dv_ref, dq_scr, delta_scr, dk_scr,
-         dv_scr) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dk_ref,
-         dv_ref, dq_scr, delta_scr, dk_scr, dv_scr) = refs
-        qs_ref = ks_ref = None
-
-    kv_idx = pl.program_id(1)
-    q_idx = pl.program_id(2)
-    n_kv = seq_k // block_k
-    n_q = seq_q // block_q
-    qrows = pl.ds(q_idx * block_q, block_q)
-
-    @pl.when(kv_idx == 0)
-    def _init_q():
-        # first kv sweep visits every q block: zero its dq rows and
-        # compute delta_i = rowsum(dO_i * O_i) once per q row
-        dq_scr[qrows, :] = jnp.zeros((block_q, dq_scr.shape[1]),
-                                     jnp.float32)
-        d_row = jnp.sum(do_ref[0].astype(jnp.float32)
-                        * o_ref[0].astype(jnp.float32), axis=-1,
-                        keepdims=True)
-        delta_scr[qrows, :] = jnp.broadcast_to(d_row, (block_q, _LANES))
-
-    @pl.when(q_idx == 0)
-    def _init_kv():
-        dk_scr[...] = jnp.zeros_like(dk_scr[...])
-        dv_scr[...] = jnp.zeros_like(dv_scr[...])
-
-    def body():
-        # bf16 matmul inputs + f32 accumulation (full-rate MXU)
-        q = q_ref[0]                              # [bq, d]
-        k = k_ref[0]                              # [bk, d]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                   # [bq, 1]
-        delta = delta_scr[qrows, :1]              # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        if has_seg:
-            s = jnp.where(qs_ref[0][:, :1] == ks_ref[0][:1, :], s,
-                          -jnp.inf)
-        p = jnp.exp(s - lse)                      # [bq, bk]
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [bq, bk]
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [bk, d]
-        dq_scr[qrows, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [bq, d]
-
-    if causal and not has_seg:
-        @pl.when(q_idx * block_q + block_q - 1 >= kv_idx * block_k)
-        def _run():
-            body()
-    else:
-        body()
-
-    @pl.when(kv_idx == n_kv - 1)
-    def _flush_dq():
-        dq_ref[0] = dq_scr[qrows, :].astype(dq_ref.dtype)
-
-    @pl.when(q_idx == n_q - 1)
-    def _flush_dkv():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-
-
-# VMEM budget for the fused backward's whole-sequence scratch (dq
-# [Sq, D] + delta [Sq, LANES], both f32): beyond this use the split
-# dQ / dK/dV kernels whose scratch is one block.
-_FUSED_BWD_MAX_SCRATCH_BYTES = 4 << 20
-
-
-
 def _flash_bwd_dq_kernel(*refs, scale: float, causal: bool,
                          block_q: int, block_k: int, seq_k: int,
                          has_seg: bool):
@@ -644,10 +416,8 @@ def _pallas_flash_bwd(q, k, v, out, lse, do, q_seg=None, k_seg=None, *,
 
     bh, sq, d = q.shape
     sk = k.shape[1]
-    block_q = _fit_block(
-        sq, block_q or _block_default("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(
-        sk, block_k or _block_default("PADDLE_TPU_FLASH_BK", 1024))
+    block_q = _fit_block(sq, block_q or _BLOCK_Q)
+    block_k = _fit_block(sk, block_k or _BLOCK_K)
     scale = 1.0 / math.sqrt(d)
     has_seg = q_seg is not None
     lse_b = lse
@@ -656,54 +426,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, do, q_seg=None, k_seg=None, *,
         ks_b = jax.lax.broadcast_in_dim(
             k_seg, (bh, _SUBLANES, sk), (0, 2))
 
-    # the fused sweep does 5 block-matmuls where the split pair does 7,
-    # but measures ~18% SLOWER on v5e (the whole-seq dq scratch RMW
-    # defeats Mosaic's software pipelining of the simple per-block
-    # accumulators), so the split kernels are the default; flag kept
-    # for re-evaluation on other TPU generations.
-    fused_scratch = sq * (d + _LANES) * 4
-    if (fused_scratch <= _FUSED_BWD_MAX_SCRATCH_BYTES
-            and env_knobs.get_raw("PADDLE_TPU_FLASH_FUSED_BWD")):
-        # single-sweep fused backward; grid (bh, kv, q) with q minor
-        qspec = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, b * 0))
-        kspec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, b * 0))
-        rowq = pl.BlockSpec((1, block_q, _LANES),
-                            lambda b, j, i: (b, i, b * 0))
-        rowk = pl.BlockSpec((1, _SUBLANES, block_k),
-                            lambda b, j, i: (b, b * 0, j))
-        in_specs = [qspec, kspec, kspec, qspec, qspec, rowq]
-        args = [q, k, v, do, out, lse_b]
-        if has_seg:
-            in_specs += [rowq, rowk]
-            args += [qs_b, ks_b]
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _flash_bwd_fused_kernel, scale=scale, causal=causal,
-                block_q=block_q, block_k=block_k, seq_q=sq, seq_k=sk,
-                has_seg=has_seg),
-            grid=(bh, sk // block_k, sq // block_q),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, b * 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, b * 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, b * 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((sq, d), jnp.float32),        # dq accumulator
-                pltpu.VMEM((sq, _LANES), jnp.float32),   # delta rows
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
-            interpret=_interpret(),
-        )(*args)
-        return dq, dk, dv
-
-    # split kernels: dQ pass then dK/dV pass
+    # dQ pass: grid (bh, q, kv), kv the minor (sequential) axis
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, b * 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, b * 0))
     rowq = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, b * 0))
@@ -776,8 +499,8 @@ def _pallas_flash_bwd(q, k, v, out, lse, do, q_seg=None, k_seg=None, *,
 #
 # Two levels of blocking.  The *resident block* is what a BlockSpec
 # brings into VMEM for one grid step: ``block_q`` query rows against
-# ``block_k`` key rows (PADDLE_TPU_FLASH_BQ x PADDLE_TPU_FLASH_BK,
-# 512 x 1024), large so that a call takes few grid steps.  Inside a
+# ``block_k`` key rows (``_BLOCK_Q`` x ``_BLOCK_K``, 512 x 1024),
+# large so that a call takes few grid steps.  Inside a
 # step ``_walk_tiles`` runs the block as *compute tiles* of ``tile_q``
 # x ``tile_k`` rows (``_compute_tile``), sliced from the refs with
 # ``pl.ds``: two ``scf.for`` loops whose bounds follow from the grid
@@ -1261,26 +984,23 @@ class _PackedPlan(NamedTuple):
 
 
 def _packed_plan(b, sq, sk, h, d, causal, has_seg, block_q, block_k, tile,
-                 default_block_k: int = 1024) -> _PackedPlan:
+                 default_block_k: int = _BLOCK_K) -> _PackedPlan:
     """Resident block and compute tile of one kernel call at this
     shape.  ``block_q``/``block_k``/``tile`` override the defaults
     (tests, sweeps).  Counts the call's tiles.  A block is at least 128
     rows, which divides every sequence the packed path takes
-    (``_seq_eligible``): the dkv kernel lays the queries along the
+    (``_attention_form``): the dkv kernel lays the queries along the
     lanes, the other two the keys."""
-    block_q = _fit_block(sq, max(_LANES, block_q or _block_default(
-        "PADDLE_TPU_FLASH_BQ", 512)))
-    block_k = _fit_block(sk, max(_LANES, block_k or _block_default(
-        "PADDLE_TPU_FLASH_BK", default_block_k)))
+    block_q = _fit_block(sq, max(_LANES, block_q or _BLOCK_Q))
+    block_k = _fit_block(sk, max(_LANES, block_k or default_block_k))
     tile_q, tile_k = tile or _compute_tile(block_q, block_k, causal,
                                            has_seg)
     if block_q % tile_q or block_k % tile_k or tile_q % _LANES \
             or tile_k % _LANES:
         raise ValueError(
             f"compute tile {tile_q} x {tile_k} must divide the resident "
-            f"block {block_q} x {block_k} (PADDLE_TPU_FLASH_BQ x "
-            f"PADDLE_TPU_FLASH_BK, fitted to the sequence) in whole "
-            f"groups of {_LANES} rows")
+            f"block {block_q} x {block_k} (block_q x block_k, fitted to "
+            f"the sequence) in whole groups of {_LANES} rows")
     _note_tiles(b * h, sq, sk, tile_q, tile_k, causal, has_seg)
     return _PackedPlan(h, d, causal, has_seg, block_q, block_k, tile_q,
                        tile_k)
@@ -1441,9 +1161,9 @@ _flash_core_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Kernel eligibility — decided by platform and shape only.  A kernel
-# the compiler refuses is a compile error in the user's step, never a
-# quiet hand-over to the composed form.
+# Which form runs — decided in ``_attention_form`` and nowhere else, from
+# platform and shape.  A kernel the compiler refuses is a compile error
+# in the user's step, never a quiet hand-over to the composed form.
 # ---------------------------------------------------------------------------
 def _kernels_enabled() -> bool:
     """True where the Pallas kernels run at all: on a TPU (or under the
@@ -1453,21 +1173,24 @@ def _kernels_enabled() -> bool:
     return _on_tpu() or _interpret()
 
 
-def _seq_eligible(sq: int, sk: int) -> bool:
+def _attention_form(h: int, d: int, sq: int, sk: int) -> Optional[str]:
+    """The form of dropout-free attention on the [b, sq | sk, h, d]
+    arrays one device holds: ``"packed"`` (the transpose-free kernels),
+    ``"bh"`` (the [BH, S, D] kernels) or None, the composed form.
+
+    A kernel takes sequences of whole 128-row groups with at least 256
+    queries (128 under the interpreter, which lets the tests stay
+    small); the benchmark's cell gpt2m-short-s128 is the composed form
+    at work.  The packed kernels take the heads that fill 128-lane
+    groups (``_packed_geometry``), the [BH, S, D] kernels the rest: a
+    head width that neither divides 128 nor is a multiple of it, or a
+    head count that leaves a group part empty."""
+    if not _kernels_enabled():
+        return None
     min_s = 128 if _interpret() else 256
-    return sq >= min_s and sq % 128 == 0 and sk % 128 == 0
-
-
-def _packed_eligible(h: int, d: int, sq: int, sk: int) -> bool:
-    if not _kernels_enabled() or \
-            env_knobs.get_raw("PADDLE_TPU_FLASH_NO_PACKED"):
-        return False
-    return _packed_geometry(h, d) is not None and _seq_eligible(sq, sk)
-
-
-def _pallas_eligible(q, k):
-    return (_kernels_enabled() and _seq_eligible(q.shape[1], k.shape[1])
-            and q.shape[0] == k.shape[0] and q.shape[2] == k.shape[2])
+    if sq < min_s or sq % 128 or sk % 128:
+        return None
+    return "packed" if _packed_geometry(h, d) is not None else "bh"
 
 
 # ---------------------------------------------------------------------------
@@ -1502,19 +1225,22 @@ def _seg_or_none(seg):
     return seg if seg is not None and seg.size else None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _flash_core(q, k, v, q_seg, k_seg, causal):
-    out, _ = _flash_fwd(q, k, v, q_seg, k_seg, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_core(q, k, v, q_seg, k_seg, causal, kernel):
+    """Attention on [BH, S, D] by the kernels (``kernel``, the caller's
+    ``_attention_form``) or in the composed form, whose backward
+    recomputes the probabilities rather than keep them."""
+    out, _ = _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel)
     return out
 
 
-def _flash_fwd(q, k, v, q_seg, k_seg, causal):
+def _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel):
     qs, ks = _seg_or_none(q_seg), _seg_or_none(k_seg)
-    if _pallas_eligible(q, k):
+    if kernel:
         out, lse = _pallas_flash_bh(q, k, v, qs, ks, causal=causal)
     else:
         out = _flash_reference(q, k, v, causal, qs, ks)
-        # empty lse marks the composed form for the backward dispatch
+        # the composed backward recomputes: it is handed no lse
         lse = jnp.zeros((0,), jnp.float32)
     return out, (q, k, v, out, lse, q_seg, k_seg)
 
@@ -1524,10 +1250,10 @@ def _int_zero_ct(x):
     return np.zeros(np.shape(x), dtype=jax.dtypes.float0)
 
 
-def _flash_bwd(causal, res, g):
+def _flash_bwd(causal, kernel, res, g):
     q, k, v, out, lse, q_seg, k_seg = res
     qs, ks = _seg_or_none(q_seg), _seg_or_none(k_seg)
-    if lse.size:  # kernel forward: block-streaming backward, no [S,S] in HBM
+    if kernel:    # block-streaming backward, no [S,S] in HBM
         dq, dk, dv = _pallas_flash_bwd(q, k, v, out, lse, g, qs, ks,
                                        causal=causal)
     else:         # composed forward: differentiate the composed form
@@ -1561,7 +1287,8 @@ def _flash_local(query, key, value, qseg, kseg, *, causal):
     shape."""
     b, sq, h, d = query.shape
     sk = key.shape[1]
-    if _packed_eligible(h, d, sq, sk):
+    form = _attention_form(h, d, sq, sk)
+    if form == "packed":
         # transpose-free path: [B,S,H,D] → [B,S,H*D] is a free reshape;
         # segment ids stay [B, S] (one mask per lane-group)
         out = _flash_core_packed(
@@ -1571,7 +1298,8 @@ def _flash_local(query, key, value, qseg, kseg, *, causal):
     if qseg.size:
         qseg, kseg = jnp.repeat(qseg, h, axis=0), jnp.repeat(kseg, h, axis=0)
     out = _flash_core(_heads_to_batch(query), _heads_to_batch(key),
-                      _heads_to_batch(value), qseg, kseg, causal)
+                      _heads_to_batch(value), qseg, kseg, causal,
+                      form == "bh")
     return _batch_to_heads(out, b)
 
 
@@ -1660,6 +1388,8 @@ def flash_attention(query, key, value, causal=False, dropout=0.0,
 
     local = functools.partial(_flash_local, causal=causal)
     if _kernels_enabled():
+        # the form follows from the shape a device holds, so the split
+        # comes first (_flash_local asks _attention_form inside it)
         local = _per_device(local, b, hq, qseg is not None)
     empty = jnp.zeros((0,), jnp.int32)
     return local(query, key, value,

@@ -6,9 +6,9 @@ chip's compiler does not.  Each case lowers one kernel (or the sharded
 public call) at GPT-2-small widths — b8 · s1024 · 12 heads · d64,
 bf16 — for a described ``v5e:2x2`` and compiles it with libtpu.
 Nothing runs, so this says nothing about values or times: it says the
-chip's compiler accepts the program.  The two ``xfail(strict=True)``
-cases carry the compiler's own message; the PR that repairs either
-kernel flips its case.
+chip's compiler accepts the program.  The ``xfail(strict=True)``
+case carries the compiler's own message; the PR that repairs the
+kernel flips it.
 """
 
 import os
@@ -24,10 +24,9 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
 
 import paddle_tpu  # noqa: F401 — turns on x64, which the kernels must survive
 from paddle_tpu.distributed import collective
-from paddle_tpu.ops import pallas_ops, pallas_lmce
+from paddle_tpu.ops import pallas_ops
 
 B, S, H, D = 8, 1024, 12, 64
-VOCAB, HIDDEN = 50304, 768
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +73,22 @@ def _bh_bwd(topo, monkeypatch):
     lse = spec((B * H, S, pallas_ops._LANES), jnp.float32)
     return (lambda q, k, v, o, l, do: pallas_ops._pallas_flash_bwd(
         q, k, v, o, l, do, causal=True)), (x, x, x, x, lse, x)
+
+
+def _bh_own_geometry(topo, monkeypatch):
+    """The [BH, S, D] kernels at a geometry that reaches them
+    (``_attention_form``): GPT-2 XL's 25 heads of 64, which leave half
+    a 128-lane group empty.  Forward, dq and dkv, b2 x s1024."""
+    b, h = 2, 25
+    assert pallas_ops._packed_geometry(h, D) is None
+    x = _one_chip_spec(topo)((b * h, S, D))
+
+    def fwd_bwd(q, k, v, do):
+        out, lse = pallas_ops._pallas_flash_bh(q, k, v, causal=True)
+        return pallas_ops._pallas_flash_bwd(q, k, v, out, lse, do,
+                                            causal=True)
+
+    return fwd_bwd, (x, x, x, x)
 
 
 def _packed_fwd(topo, monkeypatch):
@@ -203,13 +218,6 @@ def _dropless_experts(topo, monkeypatch):
         spec((held, d, f)), spec((held, d, f)), spec((held, f, d)))
 
 
-def _lmce_fwd(topo, monkeypatch):
-    spec = _one_chip_spec(topo)
-    return pallas_lmce._call_fwd, (
-        spec((B * S, HIDDEN)), spec((VOCAB, HIDDEN)),
-        spec((B * S,), jnp.int32))
-
-
 def _paged_decode(topo, monkeypatch):
     from paddle_tpu.inference.serving.paged_attention_kernel import \
         paged_ragged_attention
@@ -238,6 +246,7 @@ def _refused(build, case_id, pattern, why):
 @pytest.mark.parametrize("build, n_calls, refused", [
     pytest.param(_bh_fwd, 1, None, id="flash_bh_fwd"),
     pytest.param(_bh_bwd, 2, None, id="flash_bh_bwd"),
+    pytest.param(_bh_own_geometry, 3, None, id="flash_bh_25_heads_of_64"),
     pytest.param(_packed_fwd, 1, None, id="flash_packed_fwd"),
     pytest.param(_packed_bwd, 2, None, id="flash_packed_bwd"),
     pytest.param(_packed_varlen, 3, None, id="flash_packed_varlen"),
@@ -251,10 +260,6 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_sparse_core_group_of_one, 4, None,
                  id="sparse_core_group_of_one_s2048"),
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
-    _refused(_lmce_fwd, "lmce_fwd",
-             r"failed to legalize operation 'tpu\.truncf'",
-             "(f64) -> f32 — the package-wide jax_enable_x64 reaches "
-             "the kernel's scalars (ROADMAP D5)"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
              r"\"#tpu\.dot_dimension_numbers",

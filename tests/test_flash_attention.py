@@ -194,7 +194,7 @@ def test_pallas_kernel_bwd_matches_composed(_interpret_mode):
 
     def f_kernel(q_, k_, v_):
         return pallas_ops._flash_core(q_, k_, v_, empty, empty,
-                                      True).sum()
+                                      True, True).sum()
 
     def f_ref(q_, k_, v_):
         return pallas_ops._flash_reference(q_, k_, v_, True).sum()
@@ -246,34 +246,32 @@ def test_pallas_kernel_non_block_multiple_seq(_interpret_mode):
                                rtol=2e-4, atol=2e-5)
 
 
-def test_pallas_fused_bwd_matches_composed(_interpret_mode, monkeypatch):
-    """The single-sweep fused backward (PADDLE_TPU_FLASH_FUSED_BWD) —
-    off by default on v5e for perf — must stay numerically correct."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "128")
-    rng = np.random.RandomState(12)
-    b, s, h, d = 1, 256, 2, 16
-    q, k, v = _rand_qkv(rng, b=b, s=s, h=h, d=d)
-    qbh = jnp.moveaxis(jnp.asarray(q), 2, 1).reshape(b * h, s, d)
-    kbh = jnp.moveaxis(jnp.asarray(k), 2, 1).reshape(b * h, s, d)
-    vbh = jnp.moveaxis(jnp.asarray(v), 2, 1).reshape(b * h, s, d)
-    empty = jnp.zeros((0,), jnp.int32)
-    for causal in (False, True):
-        def f_kernel(q_, k_, v_):
-            return pallas_ops._flash_core(q_, k_, v_, empty, empty,
-                                          causal).sum()
+def _rand_bh(rng, s, h, d):
+    """q, k, v as the [BH, S, D] kernels take them, one batch row."""
+    return tuple(jnp.moveaxis(jnp.asarray(x), 2, 1).reshape(h, s, d)
+                 for x in _rand_qkv(rng, b=1, s=s, h=h, d=d))
 
-        def f_ref(q_, k_, v_):
-            return pallas_ops._flash_reference(q_, k_, v_, causal).sum()
 
-        # multiple q/kv blocks so the fused kernel's cross-sweep dq
-        # accumulation and flush-ordering are actually exercised
-        g_kernel = jax.grad(f_kernel, argnums=(0, 1, 2))(qbh, kbh, vbh)
-        g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(qbh, kbh, vbh)
-        for gk, gr in zip(g_kernel, g_ref):
-            np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
-                                       rtol=5e-4, atol=5e-5)
+@pytest.mark.parametrize("causal", [False, True])
+def test_pallas_bh_bwd_several_blocks_matches_composed(_interpret_mode,
+                                                       causal):
+    """The split dq and dkv kernels over two query and two key blocks
+    (block_q = block_k = 128 at s256): dq is carried across the key
+    blocks, dk and dv across the query blocks, and under a causal mask
+    one block pair is skipped."""
+    qbh, kbh, vbh = _rand_bh(np.random.RandomState(12), 256, 2, 16)
+    do = jnp.ones_like(qbh)
+    out, lse = pallas_ops._pallas_flash_bh(
+        qbh, kbh, vbh, causal=causal, block_q=128, block_k=128)
+    g_kernel = pallas_ops._pallas_flash_bwd(
+        qbh, kbh, vbh, out, lse, do, causal=causal, block_q=128,
+        block_k=128)
+    g_ref = jax.grad(
+        lambda q_, k_, v_: pallas_ops._flash_reference(
+            q_, k_, v_, causal).sum(), argnums=(0, 1, 2))(qbh, kbh, vbh)
+    for gk, gr in zip(g_kernel, g_ref):
+        np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
+                                   rtol=5e-4, atol=5e-5)
 
 
 def _composed_oracle_bh(q, k, v, causal, q_seg=None, k_seg=None):
@@ -389,48 +387,143 @@ def test_flash_attention_public_uses_packed(_interpret_mode,
                                rtol=2e-4, atol=2e-5)
 
 
-def test_pallas_kernel_headpack2_matches_composed(_interpret_mode,
-                                                  monkeypatch):
-    """PADDLE_TPU_FLASH_HEADPACK=2 (head-pair kernel, VERDICT r4 #9):
-    identical outputs + lse to the hp=1 kernel and the oracle."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH_HEADPACK", "2")
-    rng = np.random.RandomState(11)
-    b, s, h, d = 1, 256, 4, 64
-    q, k, v = _rand_qkv(rng, b=b, s=s, h=h, d=d)
-    qbh = jnp.moveaxis(jnp.asarray(q), 2, 1).reshape(b * h, s, d)
-    kbh = jnp.moveaxis(jnp.asarray(k), 2, 1).reshape(b * h, s, d)
-    vbh = jnp.moveaxis(jnp.asarray(v), 2, 1).reshape(b * h, s, d)
-    for causal in (False, True):
-        out, lse = pallas_ops._pallas_flash_bh(
-            qbh, kbh, vbh, causal=causal, block_q=128, block_k=128)
-        ref = pallas_ops._flash_reference(qbh, kbh, vbh, causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-5)
-        monkeypatch.delenv("PADDLE_TPU_FLASH_HEADPACK")
-        out1, lse1 = pallas_ops._pallas_flash_bh(
-            qbh, kbh, vbh, causal=causal, block_q=128, block_k=128)
-        monkeypatch.setenv("PADDLE_TPU_FLASH_HEADPACK", "2")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(out1),
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse1),
-                                   rtol=1e-5, atol=1e-6)
+@pytest.mark.parametrize("causal", [False, True])
+def test_pallas_bh_fwd_several_blocks_matches_composed(_interpret_mode,
+                                                       causal):
+    """The [BH, S, D] forward kernel over two query and two key blocks
+    at 4 heads x 64: the output against the oracle, and the saved
+    log-sum-exp, the same in every lane, against the scores' own."""
+    s, h, d = 256, 4, 64
+    qbh, kbh, vbh = _rand_bh(np.random.RandomState(11), s, h, d)
+    out, lse = pallas_ops._pallas_flash_bh(
+        qbh, kbh, vbh, causal=causal, block_q=128, block_k=128)
+    ref = pallas_ops._flash_reference(qbh, kbh, vbh, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    scores = jnp.einsum("bqd,bkd->bqk", qbh, kbh) / np.sqrt(d)
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores,
+                           -jnp.inf)
+    want = jax.scipy.special.logsumexp(scores, axis=-1)
+    assert lse.shape == (h, s, pallas_ops._LANES)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.broadcast_to(np.asarray(want)[..., None],
+                                         lse.shape), rtol=1e-5, atol=1e-5)
 
 
-def test_headpack_ineligible_falls_back(_interpret_mode, monkeypatch):
-    """d>64 or odd head count → the hp path must quietly defer to the
-    standard kernel (same numbers)."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH_HEADPACK", "2")
-    rng = np.random.RandomState(12)
-    for (h, d) in [(2, 128), (3, 64)]:
-        q, k, v = _rand_qkv(rng, b=1, s=256, h=h, d=d)
-        qbh = jnp.moveaxis(jnp.asarray(q), 2, 1).reshape(h, 256, d)
-        kbh = jnp.moveaxis(jnp.asarray(k), 2, 1).reshape(h, 256, d)
-        vbh = jnp.moveaxis(jnp.asarray(v), 2, 1).reshape(h, 256, d)
-        out, _ = pallas_ops._pallas_flash_bh(
-            qbh, kbh, vbh, causal=True, block_q=128, block_k=128)
-        ref = pallas_ops._flash_reference(qbh, kbh, vbh, True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-5)
+@pytest.mark.parametrize("h, d", [(2, 128), (3, 64)])
+def test_pallas_bh_kernel_takes_any_head_geometry(_interpret_mode, h, d):
+    """The [BH, S, D] kernel sees one head a program, so it takes a
+    head as wide as the lanes and an odd count of narrow ones alike."""
+    qbh, kbh, vbh = _rand_bh(np.random.RandomState(12), 256, h, d)
+    out, _ = pallas_ops._pallas_flash_bh(
+        qbh, kbh, vbh, causal=True, block_q=128, block_k=128)
+    ref = pallas_ops._flash_reference(qbh, kbh, vbh, True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+
+
+def test_flash_attention_public_reaches_bh_kernels(_interpret_mode,
+                                                   _no_fallback):
+    """Three heads of 64 leave half a lane group empty, so the public op
+    takes the [BH, S, D] kernels (``_attention_form``), forward and
+    backward: values and the three gradients against the oracle, with
+    the composed form forbidden."""
+    rng = np.random.RandomState(18)
+    b, s, h, d = 1, 256, 3, 64
+    assert pallas_ops._attention_form(h, d, s, s) == "bh"
+    q, k, v = (jnp.asarray(x) for x in _rand_qkv(rng, b=b, s=s, h=h, d=d))
+    do = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
+
+    out, vjp = jax.vjp(
+        lambda q_, k_, v_: pallas_ops.flash_attention.raw(
+            q_, k_, v_, causal=True), q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), _oracle(*(np.asarray(x) for x in (q, k, v)),
+                                 causal=True), rtol=2e-4, atol=2e-5)
+
+    to_bh = pallas_ops._heads_to_batch
+    _, vjp_ref = jax.vjp(
+        lambda q_, k_, v_: pallas_ops._batch_to_heads(_composed_oracle_bh(
+            to_bh(q_), to_bh(k_), to_bh(v_), True), b), q, k, v)
+    for got, want in zip(vjp(do), vjp_ref(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-4, atol=5e-5)
+
+
+# The eight PADDLE_TPU_* variables that once chose a kernel or a block
+# (PR 30 took them out): the names are put together here so that a
+# search for any of them finds no reader.
+_DEAD_KNOBS = ["PADDLE_TPU_" + "_".join(parts) for parts in (
+    ("FLASH", "HEADPACK"), ("FLASH", "BQ"), ("FLASH", "BK"),
+    ("FLASH", "FUSED", "BWD"), ("FLASH", "NO", "PACKED"),
+    ("FUSED", "LMCE"), ("LMCE", "BN"), ("LMCE", "BV"))]
+
+_ON_TPU_CASES = [
+    # the shapes users bring: (heads, head width, sq, sk) -> form
+    pytest.param(16, 64, 1024, 1024, "packed", id="gpt2-medium-16x64"),
+    pytest.param(16, 128, 1024, 1024, "packed", id="gpt3-xl-16x128"),
+    pytest.param(8, 128, 2048, 2048, "packed", id="gpt3-xl-mp2-8x128"),
+    pytest.param(25, 64, 1024, 1024, "bh", id="gpt2-xl-25x64"),
+    pytest.param(32, 80, 2048, 2048, "bh", id="gpt3-2p7b-32x80"),
+    pytest.param(16, 96, 2048, 2048, "bh", id="gpt3-large-16x96"),
+    pytest.param(16, 64, 128, 128, None, id="s128-composed"),
+    pytest.param(16, 64, 1024, 1000, None, id="sk-not-of-128-composed"),
+    pytest.param(16, 64, 320, 320, None, id="sq-not-of-128-composed"),
+]
+
+
+@pytest.fixture()
+def _as_on_a_tpu(monkeypatch):
+    """A TPU backend with nothing set, as far as ``_attention_form`` can
+    tell."""
+    for name in _DEAD_KNOBS + ["PADDLE_TPU_PALLAS_INTERPRET",
+                               "PADDLE_TPU_DISABLE_PALLAS"]:
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("h, d, sq, sk, want", _ON_TPU_CASES)
+def test_attention_form_follows_the_shape(_as_on_a_tpu, h, d, sq, sk,
+                                          want):
+    """``_attention_form`` is the one place that says which attention
+    runs: on a TPU the shape one device holds decides."""
+    assert pallas_ops._attention_form(h, d, sq, sk) == want
+
+
+def test_attention_form_ignores_the_dead_knobs(_as_on_a_tpu, monkeypatch):
+    """All eight set, to the values that once chose another kernel or
+    block: every shape gets the answer it gets without them."""
+    for name, value in zip(_DEAD_KNOBS, ("2", "256", "256", "1", "1", "1",
+                                         "128", "256")):
+        monkeypatch.setenv(name, value)
+    for case in _ON_TPU_CASES:
+        *shape, want = case.values
+        assert pallas_ops._attention_form(*shape) == want, case.id
+
+
+@pytest.mark.parametrize("on_tpu, switch, h, d, s, want", [
+    pytest.param(True, "PADDLE_TPU_DISABLE_PALLAS", 16, 64, 1024, None,
+                 id="tpu-kernels-disabled"),
+    pytest.param(False, None, 16, 64, 1024, None, id="cpu"),
+    pytest.param(False, "PADDLE_TPU_PALLAS_INTERPRET", 16, 64, 128,
+                 "packed", id="cpu-interpreter-takes-s128"),
+    pytest.param(False, "PADDLE_TPU_PALLAS_INTERPRET", 3, 64, 256, "bh",
+                 id="cpu-interpreter-3x64"),
+])
+def test_attention_form_follows_the_platform(_as_on_a_tpu, monkeypatch,
+                                             on_tpu, switch, h, d, s, want):
+    """Beside the shape it reads the backend and the two switches the
+    tests and the harness use, and nothing else."""
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: on_tpu)
+    if switch:
+        monkeypatch.setenv(switch, "1")
+    assert pallas_ops._attention_form(h, d, s, s) == want
+
+
+def test_dead_knobs_are_not_registered():
+    from paddle_tpu.framework import env_knobs
+    assert not set(_DEAD_KNOBS) & set(env_knobs.KNOBS)
 
 
 def test_flash_attention_runs_per_device_under_a_mesh(_interpret_mode,
