@@ -326,7 +326,13 @@ class KeyeDecoderLayer(nn.Layer):
         attn, aux = self.self_attn(self.input_layernorm(h), positions, trace)
         h = h + attn
         moe, sizes, experts = self.mlp(self.post_attention_layernorm(h))
-        return h + moe, self.self_attn.indexer_loss(aux), sizes, experts, aux
+        # the next layer waits for this one's indexer loss: left to
+        # itself the compiler puts every layer's off to the end of the
+        # forward pass and holds their [S, S] float32 arrays till then
+        out, loss = apply_closure(
+            lambda *both: jax.lax.optimization_barrier(both),
+            [h + moe, self.self_attn.indexer_loss(aux)], name="keye_in_turn")
+        return out, loss, sizes, experts, aux
 
 
 class KeyeLMModel(nn.Layer):

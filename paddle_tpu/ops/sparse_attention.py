@@ -9,25 +9,33 @@ kept, and attention runs over the kept keys only.
 
 Everything here is a pure function of one sequence's arrays (the model
 loops over the batch), laid out as the projections leave them: q ``[S,
-H, D]``, k and v ``[S, G, D]``; the kernels read head h as the lane
-block h of ``[S, H * D]``, so nothing is transposed.  Work is laid out in query chunks of ``chunk``
-rows against the causal extent of the chunk, so no ``[S, S]`` array of
-more than one head's width is ever alive: index scores ``[chunk, E]``
-float32, the selection as one ``[S, S]`` int8 mask.
+H, D]``, k and v ``[S, G, D]``, the indexer's qI ``[S, J, DI]``, kI ``[S,
+DI]`` and w ``[S, J]``; the kernels read a head as a lane block of ``[S,
+H * D]``, so nothing is transposed.  Nothing ``[S, S]`` of more than one
+head's width is ever alive, nor the indexer's products by head at all:
+the index scores are one float32 ``[S, S]`` (or, in the plain form,
+``[chunk, E]`` of a query chunk against its causal extent), the
+selection one ``[S, S]`` int8 mask.
 
 The selection is exact.  Only the ``topk``-th largest score of a row is
 needed, so no row is sorted: the scores are mapped to integers of the
 same order and the threshold is found bit by bit (32 counts a row); a
 tie at the threshold is broken by a second search over positions, which
-runs only where a row has one.
+runs only where a row has one.  It runs in query chunks of ``chunk``
+rows against the chunk's causal extent.
 
 The core is dense attention under that mask.  On a TPU it is four Mosaic
 kernels (forward, dq, dkv, and the head-averaged probabilities the
 indexer learns from) whose visit is a key head's group: one key, value
 and selection tile, fetched and decoded once, against the query heads
-that read that key head, over the causal triangle of tiles only;
-elsewhere plain ``jax.numpy``, which is also what the kernels are
-checked against.
+that read that key head.  The index scores are two more (``I`` itself,
+for the selection and again for the indexer's loss, and its three
+gradients in one walk): a head's ``[512, 512]`` products live in VMEM
+only, are passed through ReLU, weighted and added to the tile's sum over
+the heads there.  No kernel computes a tile above the diagonal.
+Elsewhere, and for shapes that fill no lane group or block
+(:func:`kernels_eligible`, :func:`scores_eligible`), plain
+``jax.numpy``, which is also what the kernels are checked against.
 """
 
 from __future__ import annotations
@@ -140,16 +148,21 @@ def select(q_idx, k_idx, w_idx, topk: int, chunk: int, with_scores=False):
     float32 (0 above the diagonal)."""
     seq = q_idx.shape[0]
     masks, all_scores = [], []
+    whole = None
+    if scores_eligible(*q_idx.shape):
+        with jax.named_scope("indexer"):
+            whole = _scores_kernels(q_idx, k_idx, w_idx)
     for r0, c, e in _chunks(seq, chunk):
         with jax.named_scope("indexer"):
-            scores = chunk_scores(q_idx[r0:r0 + c], k_idx[:e],
-                                  w_idx[r0:r0 + c])
+            scores = whole[r0:r0 + c, :e] if whole is not None else \
+                chunk_scores(q_idx[r0:r0 + c], k_idx[:e], w_idx[r0:r0 + c])
         with jax.named_scope("select"):
             keep = select_chunk(scores, r0, topk)
             masks.append(jnp.pad(keep.astype(jnp.int8),
                                  ((0, 0), (0, seq - e))))
             # one chunk after the other: left to itself the compiler
-            # computes every chunk's [C, J, E] scores first and holds them
+            # computes every chunk's plain [C, J, E] scores first and
+            # holds them
             q_idx, masks[-1] = _in_turn(q_idx, masks[-1])
         if with_scores:
             causal = jnp.arange(e)[None, :] <= r0 + jnp.arange(c)[:, None]
@@ -198,6 +211,13 @@ def kernels_eligible(seq: int, head_dim: int) -> bool:
     """On a TPU (or under the interpreter), for lane-aligned shapes."""
     return (pallas_ops._kernels_enabled() and head_dim % _LANES == 0
             and seq % _LANES == 0)
+
+
+def scores_eligible(seq: int, heads: int, width: int) -> bool:
+    """On a TPU (or under the interpreter), where the indexer's heads
+    fill whole lane groups and the sequence whole blocks."""
+    return (pallas_ops._kernels_enabled() and _LANES % width == 0
+            and heads * width % _LANES == 0 and seq % BLOCK == 0)
 
 
 def _block(seq: int) -> int:
@@ -646,29 +666,259 @@ def mean_head_probs(q, k, lse, mask):
 
 
 # --------------------------------------------------------------------------
+# the index scores: Mosaic kernels
+# --------------------------------------------------------------------------
+def _note_score_visits(n: int, heads: int) -> None:
+    """Counts, when a score-kernel call is traced, the head-tiles of the
+    whole square and those of the causal triangle that are computed:
+    ``dsa_indexer_visits_total{kind=square|visited}`` (a name of its own
+    beside :func:`_note_visits`: instrument names are literals)."""
+    from ..observability import metrics as _obs_metrics
+    reg = _obs_metrics.registry()
+    for kind, tiles in (("square", n * n), ("visited", n * (n + 1) // 2)):
+        reg.counter("dsa_indexer_visits_total",
+                    "head-tiles of the index-score kernels, counted a call "
+                    "when the call is traced: one indexer head's products "
+                    "of a query block and a key block, over the whole "
+                    "square and over the causal triangle that is computed",
+                    labels={"kind": kind}).inc(tiles * heads)
+
+
+def _spread_weights(w_ref, w_scr, scale):
+    """A query block's head weights, times the scores' scale, each held
+    in every lane of its row: once a query block, for all its tiles."""
+    w = w_ref[...].astype(jnp.float32) * scale
+    for h in range(w_scr.shape[0]):
+        w_scr[h] = jnp.broadcast_to(w[:, h:h + 1], w_scr.shape[1:])
+
+
+def _on_the_diagonal(k_idx, blk: int, share: int):
+    """The key tiles ``[blk, width]`` each as ``[share * blk, 128]``:
+    ``share`` copies down the diagonal of lane blocks ``width`` wide,
+    zeros beside them.  A lane group of the query block holds ``share``
+    heads, and its one full-depth product against this is their ``[blk,
+    blk]`` products side by side, nothing sliced inside a lane group; a
+    head of 64 fills half of the MXU's depth either way."""
+    seq, width = k_idx.shape
+    tiles = k_idx.reshape(seq // blk, blk, width)
+    return jnp.concatenate(
+        [jnp.pad(tiles, ((0, 0), (0, 0),
+                         (x * width, _LANES - (x + 1) * width)))
+         for x in range(share)], 1).reshape(share * seq, _LANES)
+
+
+def _off_the_diagonal(dk, blk: int, share: int):
+    """The gradient of what :func:`_on_the_diagonal` made."""
+    width = _LANES // share
+    dk = dk.reshape(-1, share, blk, share, width)
+    return sum(dk[:, x, :, x] for x in range(share)).reshape(-1, width)
+
+
+def _group_products(q_ref, group, cols, k, blk: int):
+    """(head, its ``[blk, blk]`` products) for the heads of a lane group
+    of the query block, ``cols``: one product against the key tile as
+    :func:`_on_the_diagonal` laid it out."""
+    share = k.shape[0] // blk
+    pre = jax.lax.dot_general(q_ref[:, cols], k, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    return [(group * np.int32(share) + np.int32(x),
+             pre[:, x * blk:(x + 1) * blk]) for x in range(share)]
+
+
+def _score_fwd_kernel(i_tab, j_tab, q_ref, k_ref, w_ref, o_ref, w_scr, *,
+                      scale):
+    """A tile of ``I``: a head's ``[blk, blk]`` products are passed
+    through ReLU, weighted and added to the tile where they are made."""
+    from jax.experimental import pallas as pl
+    blk, k = o_ref.shape[0], k_ref[...]
+
+    @pl.when(j_tab[pl.program_id(0)] == 0)
+    def _init():
+        _spread_weights(w_ref, w_scr, scale)
+
+    o_ref[...] = jnp.zeros_like(o_ref[...])
+
+    def group(g, cols):
+        o_ref[...] += functools.reduce(jnp.add, (
+            jnp.maximum(pre, 0.0) * _across(w_scr[h], blk)
+            for h, pre in _group_products(q_ref, g, cols, k, blk)))
+    _each_head(q_ref.shape[1] // _LANES, _LANES, group)
+
+
+def _score_bwd_kernel(i_tab, j_tab, q_ref, k_ref, w_ref, di_ref, dq_ref,
+                      dk_ref, dw_ref, dq_scr, w_scr, *, scale):
+    """The three gradients in one walk by query block.  ``dq`` adds up
+    over a row's key tiles in scratch and ``dw`` as 128 partial sums a
+    row in its output block.  ``dk`` adds up across query blocks in
+    float32 in an output block that is the whole array and stays in VMEM
+    for the call: the indexer has one key head, so that is 8 MB at
+    ``[8192, 64]`` in the key tiles' layout, where a second walk by key
+    block would make every product and every ReLU again."""
+    from jax.experimental import pallas as pl
+    t = pl.program_id(0)
+    i, j = i_tab[t], j_tab[t]
+    blk, k, di = di_ref.shape[0], k_ref[...], di_ref[...]
+    keys = pl.ds(pl.multiple_of(j * np.int32(k.shape[0]), k.shape[0]),
+                 k.shape[0])
+
+    @pl.when(t == 0)
+    def _first():
+        dk_ref[...] = jnp.zeros_like(dk_ref[...])
+
+    @pl.when(j == 0)
+    def _init():
+        _spread_weights(w_ref, w_scr, scale)
+        dq_scr[...] = jnp.zeros_like(dq_scr[...])
+        dw_ref[...] = jnp.zeros_like(dw_ref[...])
+
+    def group(g, cols):
+        dpre = []
+        for h, pre in _group_products(q_ref, g, cols, k, blk):
+            dw_ref[h] += _lane_sums(jnp.maximum(pre, 0.0) * di)
+            dpre.append(jnp.where(pre > 0.0, di * _across(w_scr[h], blk),
+                                  0.0).astype(k.dtype))
+        dpre = jnp.concatenate(dpre, 1)
+        dq_scr[:, cols] += jax.lax.dot_general(
+            dpre, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_ref[keys, :] += jax.lax.dot_general(
+            dpre, q_ref[:, cols], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    _each_head(q_ref.shape[1] // _LANES, _LANES, group)
+
+    @pl.when(j == i)
+    def _finish():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _score_geometry(q_idx, k_idx, w_idx):
+    """What both score kernels' calls share: (block, heads a lane group,
+    the scores' scale, the triangle's tables, the three operands, and the
+    block specs of the query block's rows ``[blk, J * width]``, the key
+    tile, the weights ``[blk, J]`` and the ``[blk, blk]`` tile over the
+    flat grid)."""
+    from jax.experimental import pallas as pl
+    seq, heads, width = q_idx.shape
+    blk, share = _block(seq), _LANES // width
+    _note_score_visits(seq // blk, heads)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda t, i_tab, j_tab: index(
+            i_tab[t], j_tab[t]))
+
+    return (blk, share, 1.0 / math.sqrt(width * heads),
+            _lower_triangle(seq // blk),
+            (_flat(q_idx), _on_the_diagonal(k_idx, blk, share), w_idx),
+            [spec((blk, heads * width), lambda i, j: (i, i * 0)),
+             spec((share * blk, _LANES), lambda i, j: (j, j * 0)),
+             spec((blk, heads), lambda i, j: (i, i * 0)),
+             spec((blk, blk), lambda i, j: (i, j))])
+
+
+def _scores_fwd_kernels(q_idx, k_idx, w_idx):
+    from jax.experimental.pallas import tpu as pltpu
+    seq, heads, _ = q_idx.shape
+    blk, _, scale, tables, operands, specs = _score_geometry(q_idx, k_idx,
+                                                             w_idx)
+    return _call(
+        functools.partial(_score_fwd_kernel, scale=scale),
+        (len(tables[0]),), specs[:3], specs[3:],
+        [jax.ShapeDtypeStruct((seq, seq), jnp.float32)],
+        [pltpu.VMEM((heads, blk, _LANES), jnp.float32)],
+        *operands, tables=tables)[0]
+
+
+def _scores_bwd_kernels(q_idx, k_idx, w_idx, d_scores):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    seq, heads, width = q_idx.shape
+    blk, share, scale, tables, operands, specs = _score_geometry(
+        q_idx, k_idx, w_idx)
+    dq, dk, dw = _call(
+        functools.partial(_score_bwd_kernel, scale=scale),
+        (len(tables[0]),), specs,
+        [specs[0],
+         pl.BlockSpec((share * seq, _LANES),
+                      lambda t, i_tab, j_tab: (t * 0, t * 0)),
+         pl.BlockSpec((heads, blk, _LANES),
+                      lambda t, i_tab, j_tab: (t * 0, i_tab[t], t * 0))],
+        [jax.ShapeDtypeStruct((seq, heads * width), q_idx.dtype),
+         jax.ShapeDtypeStruct((share * seq, _LANES), jnp.float32),
+         jax.ShapeDtypeStruct((heads, seq, _LANES), jnp.float32)],
+        [pltpu.VMEM((blk, heads * width), jnp.float32),
+         pltpu.VMEM((heads, blk, _LANES), jnp.float32)],
+        *operands, d_scores, tables=tables, room=4 * _ROOM)
+    return (dq.reshape(q_idx.shape),
+            _off_the_diagonal(dk, blk, share).astype(k_idx.dtype),
+            (dw.sum(-1).T * scale).astype(w_idx.dtype))
+
+
+@jax.custom_vjp
+def _scores_kernels(q_idx, k_idx, w_idx):
+    """``I`` of a sequence, float32 ``[S, S]``: the tiles of the causal
+    triangle; the tiles above it are never written and carry no
+    gradient."""
+    return _scores_fwd_kernels(q_idx, k_idx, w_idx)
+
+
+def _scores_kernels_fwd(q_idx, k_idx, w_idx):
+    return _scores_fwd_kernels(q_idx, k_idx, w_idx), (q_idx, k_idx, w_idx)
+
+
+_scores_kernels.defvjp(
+    _scores_kernels_fwd, lambda res, d: _scores_bwd_kernels(*res, d))
+
+
+# --------------------------------------------------------------------------
 # what the indexer learns from
 # --------------------------------------------------------------------------
+def _kl_chunk(scores, probs, mask, seq: int):
+    """Of a chunk's rows: sum_t KL(P_t || softmax over S_t of I_t), and
+    the gradient of the sequence's mean of it in I, (Q - P) / S on the
+    selection and 0 off it."""
+    keep = mask != 0
+    logits = jnp.where(keep, scores, _NEG)
+    log_q = logits - jax.nn.logsumexp(logits, -1, keepdims=True)
+    p = jnp.where(keep, probs, 0.0)
+    kl = jnp.where(p > 0.0, p * (jnp.log(jnp.maximum(p, 1e-37)) - log_q),
+                   0.0).sum()
+    return kl, jnp.where(keep, jnp.exp(log_q) - p, 0.0) / seq
+
+
 def _kl_and_grads(q_idx, k_idx, w_idx, probs, mask, chunk):
     """mean_t KL(P_t || softmax over S_t of I_t) of one sequence, and its
-    gradients for (q_idx, k_idx, w_idx), chunk by chunk: the gradient of
-    the loss in I is (Q - P) / S on the selection, and it is pushed back
-    through the chunk's scores at once, so nothing ``[S, S]`` waits for a
-    backward pass."""
+    gradients for (q_idx, k_idx, w_idx) in float32.  The scores are
+    computed again and their gradient is pushed back at once, so nothing
+    ``[S, S]`` waits for a backward pass."""
     seq = q_idx.shape[0]
     total = jnp.zeros((), jnp.float32)
+    if scores_eligible(*q_idx.shape):
+        # one kernel call each way over the sequence; and not while the
+        # selection's scores are alive: left to itself the forward call
+        # runs as soon as the indexer's three outputs are there
+        q_idx, probs = _in_turn(q_idx, probs)
+        scores, back = jax.vjp(_scores_kernels, q_idx, k_idx, w_idx)
+        d_scores = []
+        for r0, c, e in _chunks(seq, chunk):
+            kl, d_chunk = _kl_chunk(scores[r0:r0 + c, :e],
+                                    probs[r0:r0 + c, :e],
+                                    mask[r0:r0 + c, :e], seq)
+            total += kl
+            d_scores.append(jnp.pad(d_chunk, ((0, 0), (0, seq - e))))
+        grads = back(jnp.concatenate(d_scores, 0))
+        # the loss is there when its gradients are, so that who waits
+        # for the one (models/keye_lm.py) has waited for the other
+        return _in_turn(total / seq, tuple(
+            g.astype(jnp.float32) for g in grads))
     gq, gw = [], []
     gk = jnp.zeros(k_idx.shape, jnp.float32)
     for r0, c, e in _chunks(seq, chunk):
         scores, back = jax.vjp(chunk_scores, q_idx[r0:r0 + c], k_idx[:e],
                                w_idx[r0:r0 + c])
-        keep = mask[r0:r0 + c, :e] != 0
-        logits = jnp.where(keep, scores, _NEG)
-        log_q = logits - jax.nn.logsumexp(logits, -1, keepdims=True)
-        p = jnp.where(keep, probs[r0:r0 + c, :e], 0.0)
-        total += jnp.where(p > 0.0, p * (jnp.log(jnp.maximum(p, 1e-37))
-                                         - log_q), 0.0).sum()
-        d_scores = jnp.where(keep, jnp.exp(log_q) - p, 0.0) / seq
-        dq_c, dk_c, dw_c = back(d_scores)
+        kl, d_chunk = _kl_chunk(scores, probs[r0:r0 + c, :e],
+                                mask[r0:r0 + c, :e], seq)
+        total += kl
+        dq_c, dk_c, dw_c = back(d_chunk)
         gq.append(dq_c.astype(jnp.float32))
         gw.append(dw_c.astype(jnp.float32))
         gk = gk.at[:e].add(dk_c.astype(jnp.float32))

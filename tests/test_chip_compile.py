@@ -198,6 +198,25 @@ def _sparse_core_group_of_one(topo, monkeypatch):
     return _sparse_core(topo, monkeypatch, heads=8, kv=8, seq=2048)
 
 
+def _indexer_scores(topo, monkeypatch):
+    """The same cell's index scores, qI ``[8192, 16 heads, 64]`` against
+    one key head with a weight a head: the forward kernel and the one
+    that makes the three gradients, over the causal triangle of 512 x
+    512 tiles (``ops/sparse_attention.py``)."""
+    from paddle_tpu.ops import sparse_attention as dsa
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    seq, heads, width = 8192, 16, 64
+    assert dsa.scores_eligible(seq, heads, width)
+
+    def fwd_bwd(q_idx, k_idx, w_idx, d_scores):
+        scores, back = jax.vjp(dsa._scores_kernels, q_idx, k_idx, w_idx)
+        return scores, back(d_scores)
+
+    return fwd_bwd, (spec((seq, heads, width)), spec((seq, width)),
+                     spec((seq, heads)), spec((seq, seq), jnp.float32))
+
+
 def _dropless_experts(topo, monkeypatch):
     """The same cell's expert layer, 16 held experts of 128 at width 768
     on 8192 tokens: XLA lowers ``jax.lax.ragged_dot`` and its two
@@ -259,6 +278,7 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_sparse_core, 4, None, id="sparse_core_s8192"),
     pytest.param(_sparse_core_group_of_one, 4, None,
                  id="sparse_core_group_of_one_s2048"),
+    pytest.param(_indexer_scores, 2, None, id="indexer_scores_s8192"),
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "

@@ -269,10 +269,9 @@ def test_mosaic_kernels_agree_with_the_plain_core(monkeypatch, heads, kv,
         dsa.mean_head_probs_plain(q, k, lse_p, mask), atol=1e-6)
 
 
-def _visits(kind):
+def _visits(kind, name="dsa_core_visits_total"):
     from paddle_tpu.observability import metrics
-    return metrics.registry().counter(
-        "dsa_core_visits_total", labels={"kind": kind}).collect()
+    return metrics.registry().counter(name, labels={"kind": kind}).collect()
 
 
 @pytest.mark.parametrize("seq, heads, kv", [
@@ -305,6 +304,112 @@ def test_visit_counter_reads_the_closed_form(monkeypatch, seq, heads, kv):
             ("square", "visited"), before)] == [calls * w for w in want]
         assert metrics.registry().gauge(
             "dsa_core_heads_per_visit").collect() == per_visit
+
+
+def _indexer_inputs(seq, heads, width, rng, dead_row=None):
+    q, k, w = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in (
+        (seq, heads, width), (seq, width), (seq, heads)))
+    if dead_row is not None:
+        # a query against every key's opposite: all its products are
+        # negative, ReLU kills every head and the row scores 0
+        q = q.at[dead_row].set(-jnp.abs(q[dead_row]))
+        k = jnp.abs(k)
+    return q, k, w
+
+
+@pytest.mark.parametrize("blocks, heads, width, dead_row, kernels", [
+    pytest.param(1, 4, 64, None, True, id="one_block"),
+    pytest.param(3, 8, 64, None, True, id="three_blocks"),
+    pytest.param(3, 6, 64, None, True,
+                 id="three_lane_groups_no_multiple_of_the_turn"),
+    pytest.param(2, 2, 128, None, True, id="a_head_a_lane_group"),
+    pytest.param(2, 8, 32, None, True, id="four_heads_a_lane_group"),
+    pytest.param(3, 4, 64, 200, True, id="a_row_relu_kills"),
+    pytest.param(3, 3, 64, None, False, id="refused-heads_fill_no_group"),
+    pytest.param(2, 4, 48, None, False, id="refused-width_48"),
+])
+def test_score_kernels_agree_with_chunk_scores(monkeypatch, blocks, heads,
+                                               width, dead_row, kernels):
+    """The index scores and their three gradients by the two Mosaic
+    kernels, interpreted, against ``chunk_scores`` and its ``jax.vjp``:
+    through ``select(with_scores)`` and ``indexer_kl``, so the mask, the
+    loss and dq_idx, dk_idx, dw_idx of both paths; a shape the
+    predicate refuses takes the plain path and is equal to the bit."""
+    monkeypatch.setattr(dsa, "BLOCK", 128)
+    rng = np.random.default_rng(8)
+    seq, topk, chunk = blocks * 128, 48, 64
+    q, k, w = _indexer_inputs(seq, heads, width, rng, dead_row)
+    probs = jax.nn.softmax(jnp.where(
+        np.tril(np.ones((seq, seq), bool)),
+        jnp.asarray(rng.standard_normal((seq, seq)), jnp.float32), -1e30), -1)
+
+    def both():
+        mask, scores = dsa.select(q, k, w, topk, chunk, with_scores=True)
+        return (mask, scores) + jax.value_and_grad(
+            dsa.indexer_kl, (0, 1, 2))(q, k, w, probs, mask, chunk)
+
+    want = both()
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert dsa.scores_eligible(seq, heads, width) == kernels
+    before = [_visits(kind, "dsa_indexer_visits_total")
+              for kind in ("square", "visited")]
+    got = both()
+    counted = [_visits(kind, "dsa_indexer_visits_total") - was
+               for kind, was in zip(("square", "visited"), before)]
+    # two forward calls and one backward
+    assert counted == [3 * heads * n * kernels for n in (
+        blocks * blocks, blocks * (blocks + 1) // 2)]
+    flat = lambda out: out[:3] + tuple(out[3])
+    for name, a, b in zip(("mask", "scores", "loss", "dq", "dk", "dw"),
+                          flat(got), flat(want)):
+        if not kernels or name == "mask":
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            scale = float(jnp.abs(b).max())
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * scale,
+                                       err_msg=name)
+    if dead_row is not None:
+        assert float(jnp.abs(want[1][dead_row]).max()) == 0.0
+        assert float(jnp.abs(got[1][dead_row]).max()) == 0.0
+        assert float(jnp.abs(got[3][0][dead_row]).max()) == 0.0
+    if kernels:
+        # the kernels themselves, on a gradient that is not the loss's
+        d = jnp.asarray(rng.standard_normal((seq, seq)), jnp.float32
+                        ) * np.tril(np.ones((seq, seq), np.float32))
+        scores, back = jax.vjp(dsa._scores_kernels, q, k, w)
+        want_scores, want_back = jax.vjp(dsa.chunk_scores, q, k, w)
+        tiles = np.tril(np.ones((blocks, blocks), bool)).repeat(
+            128, 0).repeat(128, 1)
+        np.testing.assert_allclose(np.where(tiles, scores, 0.0),
+                                   np.where(tiles, want_scores, 0.0),
+                                   rtol=1e-5, atol=1e-5)
+        for a, b in zip(back(d), want_back(d)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * float(
+                jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("seq, heads, width", [
+    (8192, 16, 64), (1024, 4, 128), (2048, 8, 32)])
+def test_score_visit_counter_reads_the_closed_form(monkeypatch, seq, heads,
+                                                   width):
+    """``dsa_indexer_visits_total`` counts, as a score-kernel call is
+    traced, the head-tiles of the square and of the causal triangle:
+    J n² and J n (n + 1) / 2 for n = S / 512."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    n = seq // 512
+    q = jax.ShapeDtypeStruct((seq, heads, width), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((seq, width), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((seq, heads), jnp.bfloat16)
+    d = jax.ShapeDtypeStruct((seq, seq), jnp.float32)
+    assert dsa.scores_eligible(seq, heads, width)
+    for fn, args in ((dsa._scores_fwd_kernels, (q, k, w)),
+                     (dsa._scores_bwd_kernels, (q, k, w, d))):
+        before = [_visits(kind, "dsa_indexer_visits_total")
+                  for kind in ("square", "visited")]
+        jax.eval_shape(fn, *args)
+        assert [_visits(kind, "dsa_indexer_visits_total") - was
+                for kind, was in zip(("square", "visited"), before)] == [
+            heads * n * n, heads * n * (n + 1) // 2]
 
 
 def test_three_equal_position_streams_are_plain_rotary():
