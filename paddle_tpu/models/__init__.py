@@ -16,3 +16,6 @@ from .bert import (  # noqa
 from .keye_lm import (  # noqa
     KeyeLMConfig, KeyeLMModel, KeyeLMForCausalLM,
     KeyeLMPretrainingCriterion, keye_lm_tiny)
+from .granite_hybrid import (  # noqa
+    GraniteHybridConfig, GraniteHybridModel, GraniteHybridForCausalLM,
+    GraniteHybridPretrainingCriterion, granite_hybrid_tiny)

@@ -1,0 +1,221 @@
+"""State-space layers: the selective scan of Mamba-2 in its chunked
+(state-space duality) form, and the causal depthwise convolution in
+front of it.  Plain XLA operations; a sequence at a time.
+
+The recurrence, for head h with state ``H [P, N]`` (P the head's width,
+N the state's), ``a_t = dt_t A`` (A < 0):
+
+    H_t = exp(a_t) H_{t-1} + dt_t x_t (x) B_t        y_t = H_t C_t + D x_t
+
+In chunks of Q positions it is four products.  With ``cum_l`` the sum of
+a over a chunk's positions up to l, and ``Hin`` the state a chunk starts
+from:
+
+    inside a chunk   y_l += sum_{s<=l} (C_l . B_s) exp(cum_l - cum_s) dt_s x_s
+    a chunk's state  S    = sum_s exp(cum_Q - cum_s) dt_s x_s (x) B_s
+    chunk to chunk   Hin' = exp(cum_Q) Hin + S
+    from the state   y_l += exp(cum_l) Hin C_l
+
+Decays are float32; products take their inputs in x's dtype (bf16 under
+O2) and sum in float32.  The ``[heads, Q, Q]`` matrices of the first
+line are the large ones (``[64, 32, 256, 256]`` float32 is 537 MB at
+8192 positions): :func:`ssd_scan` makes them ``CHUNKS_AT_ONCE`` chunks
+at a time and keeps none of them for the backward pass, which makes
+them again, as many at a time.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+# chunks whose [heads, Q, Q] matrices are alive together, forward and
+# backward: 64 heads x 256 x 256 float32 is 16.8 MB a chunk, and the
+# backward pass holds about six such arrays
+CHUNKS_AT_ONCE = 4
+
+
+# --------------------------------------------------------------------------
+# the convolution
+# --------------------------------------------------------------------------
+def causal_conv1d(x, weight, bias=None):
+    """``x [S, C]``, depthwise: ``out[t, c] = bias[c] + sum_k weight[c, k]
+    x[t - (W - 1) + k, c]`` with ``weight [C, W]``; positions before the
+    sequence read 0.  Sums in float32, returns x's dtype."""
+    seq, width = x.shape[0], weight.shape[1]
+    padded = jnp.pad(x, ((width - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    out = sum(padded[k:k + seq].astype(jnp.float32) * w[:, k]
+              for k in range(width))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# the scan
+# --------------------------------------------------------------------------
+def _dot(spec, a, b):
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _chunk_states(x, dt, cum, B):
+    """``S [c, g, r, P, N]`` of every chunk, from ``x [c, Q, g, r, P]``,
+    ``dt``, ``cum [c, Q, g, r]`` and ``B [c, Q, g, N]``."""
+    to_end = jnp.exp(cum[:, -1:] - cum) * dt
+    scaled = (x.astype(jnp.float32) * to_end[..., None]).astype(x.dtype)
+    return _dot("cqgrp,cqgn->cgrpn", scaled, B)
+
+
+def _starting_states(x, dt, cum, B):
+    """The state each chunk starts from, ``[c, g, r, P, N]`` float32, the
+    first from nothing: ``Hin' = exp(cum_Q) Hin + S``, chunk to chunk."""
+    states = _chunk_states(x, dt, cum, B)
+
+    def step(h, args):
+        s, total = args
+        return jnp.exp(total)[..., None, None] * h + s, h
+
+    return jax.lax.scan(step, jnp.zeros_like(states[0]),
+                        (states, cum[:, -1]))[1]
+
+
+def _chunk_outputs(x, dt, cum, B, C, starts):
+    """y without the D term, ``[c, Q, g, r, P]`` float32, of some chunks:
+    the decayed lower triangle inside each and what its starting state
+    adds."""
+    q = x.shape[1]
+    scores = _dot("clgn,csgn->cgls", C, B)
+    lower = jnp.tril(jnp.ones((q, q), bool))
+    by_head = cum.transpose(0, 2, 3, 1)                  # [c, g, r, Q]
+    # masked before the exponential: above the diagonal cum_l - cum_s
+    # is positive and may overflow
+    decay = jnp.exp(jnp.where(
+        lower, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    local = (decay * dt.transpose(0, 2, 3, 1)[..., None, :]
+             * scores[:, :, None])                       # [c, g, r, l, s]
+    y = _dot("cgrls,csgrp->clgrp", local.astype(x.dtype), x)
+    from_state = _dot("clgn,cgrpn->clgrp", C, starts.astype(x.dtype))
+    return y + jnp.exp(cum)[..., None] * from_state
+
+
+def _groups_of(array, size):
+    return array.reshape((array.shape[0] // size, size) + array.shape[1:])
+
+
+def _chunked(x, dt, A, B, C, chunk):
+    """The inputs by chunk and by group of heads: x ``[c, Q, g, r, P]``,
+    dt and cum ``[c, Q, g, r]`` float32, B and C ``[c, Q, g, N]``."""
+    seq, heads, width = x.shape
+    groups = B.shape[1]
+    if seq % chunk:
+        raise ValueError(
+            f"ssd_scan: a sequence of {seq} positions is no whole number "
+            f"of chunks of {chunk}; pad it (dt = 0 leaves the state as "
+            "it is) or choose a chunk that divides it")
+    if heads % groups:
+        raise ValueError(f"ssd_scan: {heads} heads in {groups} groups")
+    n = seq // chunk
+    shape = (n, chunk, groups, heads // groups)
+    dt = dt.astype(jnp.float32).reshape(shape)
+    cum = jnp.cumsum(dt * A.astype(jnp.float32).reshape(shape[2:]), axis=1)
+    return (x.reshape(shape + (width,)), dt, cum,
+            B.reshape(n, chunk, groups, -1).astype(x.dtype),
+            C.reshape(n, chunk, groups, -1).astype(x.dtype))
+
+
+def _at_once(n_chunks: int, at_once: int) -> int:
+    """The largest divisor of ``n_chunks`` that is at most ``at_once``."""
+    return max(k for k in range(1, min(at_once, n_chunks) + 1)
+               if n_chunks % k == 0)
+
+
+def _scan_forward(x, dt, A, B, C, D, chunk, at_once):
+    xc, dtc, cum, Bc, Cc = _chunked(x, dt, A, B, C, chunk)
+    starts = _starting_states(xc, dtc, cum, Bc)
+    k = _at_once(xc.shape[0], at_once)
+    y = jax.lax.map(lambda args: _chunk_outputs(*args), tuple(
+        _groups_of(a, k) for a in (xc, dtc, cum, Bc, Cc, starts)))
+    y = y.reshape(x.shape) + D.astype(jnp.float32)[:, None] * x.astype(
+        jnp.float32)
+    return y.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _ssd_scan(x, dt, A, B, C, D, chunk, at_once):
+    return _scan_forward(x, dt, A, B, C, D, chunk, at_once)
+
+
+def _ssd_scan_fwd(x, dt, A, B, C, D, chunk, at_once):
+    # the inputs are all the backward pass is given
+    return (_scan_forward(x, dt, A, B, C, D, chunk, at_once),
+            (x, dt, A, B, C, D))
+
+
+def _ssd_scan_bwd(chunk, at_once, inputs, dy):
+    """The chunk states and the starting states again (no ``[Q, Q]``
+    matrix in them), then ``CHUNKS_AT_ONCE`` chunks at a time: their
+    matrices again and, through them, the gradients; then back through
+    the states, last chunk first."""
+    x, dt, A, B, C, D = inputs
+
+    def states_of(x_, dt_, A_, B_):
+        return _starting_states(*_chunked(x_, dt_, A_, B_, C, chunk)[:4])
+
+    starts, back_through_states = jax.vjp(states_of, x, dt, A, B)
+    k = _at_once(starts.shape[0], at_once)
+
+    def some_chunks(args):
+        x_, dt_, B_, C_, starts_, dy_ = args
+
+        def outputs(x__, dt__, A__, B__, C__, starts__):
+            shape = dt__.shape[2:]
+            cum = jnp.cumsum(dt__ * A__.reshape(shape), axis=1)
+            return _chunk_outputs(x__, dt__, cum, B__, C__, starts__)
+
+        _, vjp = jax.vjp(outputs, x_, dt_, A.astype(jnp.float32), B_, C_,
+                         starts_)
+        return vjp(dy_.astype(jnp.float32))
+
+    xc, dtc, _, Bc, Cc = _chunked(x, dt, A, B, C, chunk)
+    dx, ddt, dA, dB, dC, dstarts = jax.lax.map(some_chunks, tuple(
+        _groups_of(a, k) for a in (xc, dtc, Bc, Cc, starts,
+                                   dy.reshape(xc.shape))))
+    dx2, ddt2, dA2, dB2 = back_through_states(
+        dstarts.reshape(starts.shape))
+    xf, dyf = x.astype(jnp.float32), dy.astype(jnp.float32)
+    dx = (dx.reshape(x.shape).astype(jnp.float32) + dx2.astype(jnp.float32)
+          + D.astype(jnp.float32)[:, None] * dyf)
+    return (dx.astype(x.dtype),
+            (ddt.reshape(dt.shape) + ddt2.astype(jnp.float32)).astype(
+                dt.dtype),
+            (dA.sum(0).reshape(A.shape) + dA2).astype(A.dtype),
+            (dB.reshape(B.shape).astype(jnp.float32)
+             + dB2.astype(jnp.float32)).astype(B.dtype),
+            dC.reshape(C.shape).astype(C.dtype),
+            (dyf * xf).sum((0, 2)).astype(D.dtype))
+
+
+_ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
+
+
+def ssd_scan(x, dt, A, B, C, D, chunk: int):
+    """``y [S, H, P]`` of one sequence: ``x [S, H, P]``, ``dt [S, H]``
+    (positive: after its softplus), ``A [H]`` (negative), ``B`` and ``C
+    [S, G, N]`` (G groups of H / G heads share them), ``D [H]``.  S is a
+    whole number of chunks: anything else is refused, since what a
+    caller pads with decides what the state sees."""
+    return _ssd_scan(x, dt, A, B, C, D, int(chunk), CHUNKS_AT_ONCE)
+
+
+def scan_chunks(seq: int, heads: int, chunk: int) -> int:
+    """Chunks x heads a call of :func:`ssd_scan` walks."""
+    return seq // chunk * heads
+
+
+def scan_state_bytes(seq: int, heads: int, width: int, state: int,
+                     chunk: int) -> int:
+    """Bytes of the float32 states a call passes from chunk to chunk."""
+    return seq // chunk * heads * width * state * 4

@@ -17,6 +17,7 @@ import re
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import pytest
+from pytest import approx
 import jax
 import jax.numpy as jnp
 from jax.sharding import (NamedSharding, PartitionSpec as P,
@@ -295,3 +296,52 @@ def test_compiles_for_v5e(topo, monkeypatch, build, n_calls, refused):
             raise CompilerRefused(refused) from e
         raise
     assert text.count("tpu_custom_call") >= n_calls
+
+
+def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell granite4h-micro-stage0-s8192
+    (ten layers at published widths, b1 x s8192, bf16 O2 with float32
+    master weights, every layer recomputed), as ``DistributedRunner``
+    builds it, compiled for one described v5e chip: what it needs on the
+    device stays under the configuration's limit, and the attention
+    layer's kernels are in it (forward, the forward again, dq, dkv).
+    The parameters are zeros placeholders (``LazyGuard``) and nothing is
+    put on a device: the step is lowered on shapes."""
+    import numpy as np
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_granite_lm as driver
+    from benchmarks.harness import cells
+    config = cells.load_cell("granite4h-micro-stage0-s8192", root).config
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    prev_mesh = collective.get_mesh()
+    try:
+        with paddle_tpu.LazyGuard():
+            runner = driver.build_runner(config, 0, topo.devices[:1])
+        monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
+        ids = np.zeros((1, 8192), np.int64)
+        data = sum(runner._prep_step_args([ids], [ids]), [])
+        on_chip = NamedSharding(runner.mesh, P())
+
+        def shapes(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=on_chip), tree)
+
+        compiled = runner._step_fn.lower(
+            *shapes(runner._sync_val_cache()), shapes(runner._opt_state),
+            *shapes([jnp.float32(0), jnp.uint32(1)] + data)).compile()
+    finally:
+        collective.set_mesh(prev_mesh)
+    memory = compiled.memory_analysis()
+    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    # 772 160 448 parameters at 14 bytes, and the batch
+    assert memory.argument_size_in_bytes == approx(10.81e9, rel=2e-3)
+    assert step < config["step_bytes_limit"] == 15.6e9
+    recorded = config["notes"]["compiled_step_bytes_a_device"][
+        "pretrain-b1-s8192"]
+    assert step == approx(recorded["step"], rel=0.02), \
+        "the configuration's notes hold another figure: bring them up to date"
+    assert compiled.as_text().count("tpu_custom_call") == \
+        recorded["tpu_custom_call"] == 4
