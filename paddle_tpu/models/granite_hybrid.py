@@ -1,7 +1,10 @@
 """Granite 4.0-H (``model_type`` granitemoehybrid) without routed experts:
 a pre-norm RMSNorm decoder whose token mixer is chosen a layer from
 ``layer_types``, a Mamba-2 mixer (``ops/ssm.py``: causal depthwise
-convolution, selective scan in chunks, gated RMSNorm) or grouped-query
+convolution, selective scan in chunks, gated RMSNorm; the scan runs as
+the Mosaic kernels of ``ops/ssm_kernels.py`` on a TPU at shapes that
+``ssm.scan_form`` gives them, the published ones among them, and as XLA
+operations elsewhere) or grouped-query
 attention with no positions at all, a SiLU-gated MLP in every layer, four
 muP-style multipliers and an output head tied to the embedding.
 
@@ -200,6 +203,8 @@ class GraniteMambaMixer(nn.Layer):
             state = c.mamba_n_groups * c.mamba_d_state
             xs, b, cc = jnp.split(xbc, (c.d_inner, c.d_inner + state), -1)
         with jax.named_scope("ssm_scan"):
+            # [seq, heads, width] is a view of the projection's own
+            # [seq, d_inner]: the kernels read and write it as it lies
             y = ssm.ssd_scan(
                 xs.reshape(seq, c.mamba_n_heads, c.mamba_d_head),
                 _step_sizes(dt, dt_bias), -jnp.exp(a_log.astype(jnp.float32)),

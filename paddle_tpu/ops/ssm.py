@@ -1,6 +1,12 @@
 """State-space layers: the selective scan of Mamba-2 in its chunked
 (state-space duality) form, and the causal depthwise convolution in
-front of it.  Plain XLA operations; a sequence at a time.
+front of it; a sequence at a time.  The convolution is plain XLA
+operations.  The scan has two forms and :func:`scan_form` names the one
+that runs, from platform and shape: on a TPU, for shapes that fill lane
+groups and whole chunks, the Mosaic kernels of ``ops/ssm_kernels.py``
+(a chunk's matrices and the running state in VMEM only); everywhere
+else (the CPU, odd shapes) the plain XLA operations below, which are
+also what the kernels are checked against.
 
 The recurrence, for head h with state ``H [P, N]`` (P the head's width,
 N the state's), ``a_t = dt_t A`` (A < 0):
@@ -19,9 +25,10 @@ from:
 Decays are float32; products take their inputs in x's dtype (bf16 under
 O2) and sum in float32.  The ``[heads, Q, Q]`` matrices of the first
 line are the large ones (``[64, 32, 256, 256]`` float32 is 537 MB at
-8192 positions): :func:`ssd_scan` makes them ``CHUNKS_AT_ONCE`` chunks
-at a time and keeps none of them for the backward pass, which makes
-them again, as many at a time.
+8192 positions): the XLA form makes them ``CHUNKS_AT_ONCE`` chunks at a
+time and keeps none of them for the backward pass, which makes them
+again, as many at a time; the kernels make one head's at a time, in
+VMEM, forward and backward.
 """
 
 from __future__ import annotations
@@ -31,9 +38,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# chunks whose [heads, Q, Q] matrices are alive together, forward and
-# backward: 64 heads x 256 x 256 float32 is 16.8 MB a chunk, and the
-# backward pass holds about six such arrays
+from . import pallas_ops, ssm_kernels
+
+_LANES = 128
+# the XLA form's: chunks whose [heads, Q, Q] matrices are alive together,
+# forward and backward: 64 heads x 256 x 256 float32 is 16.8 MB a chunk,
+# and the backward pass holds about six such arrays
 CHUNKS_AT_ONCE = 4
 
 
@@ -55,7 +65,7 @@ def causal_conv1d(x, weight, bias=None):
 
 
 # --------------------------------------------------------------------------
-# the scan
+# the scan: the XLA form
 # --------------------------------------------------------------------------
 def _dot(spec, a, b):
     return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
@@ -201,13 +211,35 @@ def _ssd_scan_bwd(chunk, at_once, inputs, dy):
 _ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
 
 
+# --------------------------------------------------------------------------
+# the scan: which form runs
+# --------------------------------------------------------------------------
+def scan_form(seq: int, heads: int, width: int, groups: int, state: int,
+              chunk: int) -> str:
+    """Which form of the scan runs, from platform and shape:
+    ``"kernels"`` (``ops/ssm_kernels.py``) on a TPU (or under the
+    interpreter) where the sequence is a whole number of chunks, the
+    chunk and the state are multiples of 128, whole heads fill a lane
+    group that one group of B and C serves, and a chunk of all heads
+    fits VMEM; ``"xla"`` everywhere else."""
+    share = _LANES // width if _LANES % width == 0 else 0
+    fits = (seq % chunk == 0 and chunk % _LANES == 0
+            and state % _LANES == 0 and share > 0
+            and heads % groups == 0 and (heads // groups) % share == 0
+            and ssm_kernels.fits_vmem(heads, width, state, chunk))
+    return "kernels" if fits and pallas_ops._kernels_enabled() else "xla"
+
+
 def ssd_scan(x, dt, A, B, C, D, chunk: int):
     """``y [S, H, P]`` of one sequence: ``x [S, H, P]``, ``dt [S, H]``
     (positive: after its softplus), ``A [H]`` (negative), ``B`` and ``C
     [S, G, N]`` (G groups of H / G heads share them), ``D [H]``.  S is a
     whole number of chunks: anything else is refused, since what a
     caller pads with decides what the state sees."""
-    return _ssd_scan(x, dt, A, B, C, D, int(chunk), CHUNKS_AT_ONCE)
+    chunk = int(chunk)
+    if scan_form(*x.shape, *B.shape[1:], chunk) == "kernels":
+        return ssm_kernels.scan(x, dt, A, B, C, D, chunk)
+    return _ssd_scan(x, dt, A, B, C, D, chunk, CHUNKS_AT_ONCE)
 
 
 def scan_chunks(seq: int, heads: int, chunk: int) -> int:

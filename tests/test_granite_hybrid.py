@@ -221,6 +221,47 @@ def test_the_mixers_start_as_mamba2_starts(tiny):
     assert (published.d_inner, published.conv_dim) == (4096, 4352)
 
 
+def test_kernel_widths_give_the_xla_forms_loss_and_gradients(monkeypatch):
+    """At widths ``ssm.scan_form`` gives to the Mosaic kernels (two
+    heads of 64, state 128, chunks of 128; interpreted here), every layer
+    recomputed: the loss and every gradient are the XLA form's."""
+    from paddle_tpu.ops import ssm
+    config = granite_hybrid_tiny(
+        vocab_rows_held=VOCAB, mamba_n_heads=2, mamba_d_head=64,
+        mamba_d_state=128, mamba_chunk_size=128, recompute=True)
+    seq = 256
+    shape = (seq, 2, 64, 1, 128, 128)
+    ids = np.random.default_rng(8).integers(0, VOCAB, (1, seq),
+                                            dtype=np.int64)
+    net = seeded(config)
+    params = F.param_dict(net)
+
+    def loss_and_grads():
+        return jax.value_and_grad(lambda p: program_loss(
+            net, p, ids, np.roll(ids, -1, axis=1))[0])(params)
+
+    assert ssm.scan_form(*shape) == "xla"
+    want_loss, want = loss_and_grads()
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.scan_form(*shape) == "kernels"
+    before = _kernel_visits()
+    loss, grads = loss_and_grads()
+    # two Mamba layers x 2 chunks: forward and the forward again, back
+    assert _kernel_visits() - before == (2 * 2 * 2) + (2 * 2)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    for name in want:
+        scale = float(jnp.abs(want[name]).max())
+        np.testing.assert_allclose(grads[name], want[name], rtol=1e-3,
+                                   atol=1e-4 * scale, err_msg=name)
+
+
+def _kernel_visits():
+    from paddle_tpu.observability import metrics
+    return sum(metrics.registry().counter(
+        "ssm_scan_kernel_visits_total", labels={"kind": kind}).collect()
+        for kind in ("fwd", "bwd"))
+
+
 def test_the_scans_are_counted_as_they_are_traced(tiny):
     net, config, ids, labels = tiny
     from paddle_tpu.observability import metrics
