@@ -5,19 +5,31 @@ The layer is told which experts it holds (``first .. first + held`` of
 expert) pair whose expert lives here however uneven the routing, and
 returns its own experts' part of the layer's result:
 
-    p = softmax(y W_r) over all experts;  T_t = the k largest
-    g[t, e] = p[t, e] / sum of p over T_t      (normalised over all k,
-                                                held here or not)
     out[t]  = sum over e in T_t held here of g[t, e] * expert_e(y[t])
-    expert_e(y) = (silu(y W1_e) * (y W3_e)) W2_e
+
+with T_t the k experts token t chose and g its gates, normalised over
+all k, held here or not.  Two routers (:func:`route`,
+:func:`route_sigmoid`) and two expert forms, told apart by how many
+matrices an expert has:
+
+    softmax router   p = softmax(y W_r);  T_t = the k largest p
+                     g[t, e] = p[t, e] / sum of p over T_t
+    sigmoid router   s = sigmoid(y W_r);  T_t = the k largest s + b
+                     g[t, e] = scale * s[t, e] / (sum of s over T_t + 1e-20)
+                     (b chooses and does not weigh; no gradient reaches it)
+    SiLU-gated       expert_e(y) = (silu(y W1_e) * (y W3_e)) W2_e
+    squared ReLU     expert_e(y) = relu(y W1_e)^2 W2_e
 
 What the other ranks' experts would add is left out; summed over the
-ranks the parts give the whole layer (``tests/test_keye_lm.py``).  On
-one chip there is no exchange, and nothing here stands in for one.
+ranks the parts give the whole layer (``tests/test_keye_lm.py``,
+``tests/test_nemotron_h.py``).  A shared expert that every token visits
+is no part of this: it lies outside the plan, with the model, which
+counts it once.  On one chip there is no exchange, and nothing here
+stands in for one.
 
 How: the pairs are sorted by expert (pairs of absent experts last), the
-tokens of the pairs held here are gathered into that order, the three
-projections run as grouped matrix products over the uneven groups
+tokens of the pairs held here are gathered into that order, the
+projections (three or two) run as grouped matrix products over the uneven groups
 (``jax.lax.ragged_dot``, which XLA lowers to Mosaic kernels on a TPU),
 and each token gathers its results back and adds them up in float32.
 
@@ -32,7 +44,7 @@ ever dropped and the buffers stay a window large.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +72,19 @@ def route(logits, k: int):
     return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
+def route_sigmoid(logits, bias, k: int, scale: float):
+    """(experts ``[T, k]`` int32, gates ``[T, k]`` float32) from float32
+    router logits over all experts: the k largest of ``sigmoid(logits) +
+    bias`` are chosen, and weighed by the sigmoid alone, normalised over
+    the k chosen and multiplied by ``scale``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    return (experts.astype(jnp.int32),
+            scale * top / (top.sum(-1, keepdims=True) + 1e-20))
+
+
 def plan(experts, first: int, held: int) -> Plan:
     local = experts - first
     here = (local >= 0) & (local < held)
@@ -77,6 +102,20 @@ def _gated(gate, up):
     operands as they are stored and computes the rest again."""
     return (jax.nn.silu(gate.astype(jnp.float32))
             * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+@jax.checkpoint
+def _relu2(up):
+    """relu(up)^2 in float32; the backward pass keeps the operand as it
+    is stored."""
+    r = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return (r * r).astype(up.dtype)
+
+
+def _activation(inner: int):
+    """What stands between an expert's ``inner`` first matrices and its
+    last."""
+    return {1: _relu2, 2: _gated}[inner]
 
 
 def usual_rows(tokens: int, k: int, held: int, num_experts: int) -> int:
@@ -106,26 +145,31 @@ def _window(p: Plan, start, rows: int) -> _Window:
         (start + jnp.arange(rows) < p.count)[:, None])
 
 
-def _window_forward(w: _Window, y, gates, w1, w3, w2):
-    """(the window's part of the result ``[T, d]`` float32, the four
-    intermediates its backward pass reads)."""
+def _window_forward(w: _Window, y, gates, weights):
+    """(the window's part of the result ``[T, d]`` float32, what its
+    backward pass reads: the pairs' rows, their products with each of an
+    expert's first matrices, the experts' results).  ``weights`` are an
+    expert's matrices, stacked over the held: (w1, w3, w2) SiLU-gated,
+    (w1, w2) squared ReLU."""
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=w.sizes)
+    *inner, last = weights
     with jax.named_scope("dispatch"):
         x = y[w.pairs // gates.shape[1]]
     with jax.named_scope("experts"):
-        gate, up = dot(x, w1), dot(x, w3)
-        rows = dot(_gated(gate, up), w2)
+        pre = tuple(dot(x, m) for m in inner)
+        rows = dot(_activation(len(inner))(*pre), last)
     with jax.named_scope("combine"):
         picked = jnp.where(w.here[..., None], rows[w.dest], 0)
         out = (picked.astype(jnp.float32) * gates[..., None]).sum(1)
-    return out, (x, gate, up, rows)
+    return out, (x, pre, rows)
 
 
-def _window_backward(w: _Window, kept, gates, w1, w3, w2, g):
-    """Gradients for (y, gates, w1, w3, w2) of the window's part.  Every
+def _window_backward(w: _Window, kept, gates, weights, g):
+    """Gradients for (y, gates, weights) of the window's part.  Every
     gather of the forward pass has a gather as its transpose, because
     the sorted order is a permutation of the pairs."""
-    x, gate, up, rows = kept
+    x, pre, rows = kept
+    *inner, last = weights
     k = gates.shape[1]
 
     def dot(lhs, rhs):
@@ -140,15 +184,15 @@ def _window_backward(w: _Window, kept, gates, w1, w3, w2, g):
             gates.dtype)
     with jax.named_scope("experts"):
         # each product's forward result is not used again: XLA drops it
-        h, gated_back = jax.vjp(_gated, gate, up)
-        d_h, d_w2 = jax.vjp(dot, h, w2)[1](d_rows)
-        d_gate, d_up = gated_back(d_h)
-        d_x1, d_w1 = jax.vjp(dot, x, w1)[1](d_gate)
-        d_x3, d_w3 = jax.vjp(dot, x, w3)[1](d_up)
+        h, activation_back = jax.vjp(_activation(len(inner)), *pre)
+        d_h, d_last = jax.vjp(dot, h, last)[1](d_rows)
+        back = [jax.vjp(dot, x, m)[1](d)
+                for m, d in zip(inner, activation_back(d_h))]
     with jax.named_scope("dispatch"):
-        picked = jnp.where(w.here[..., None], (d_x1 + d_x3)[w.dest], 0)
+        picked = jnp.where(w.here[..., None],
+                           sum(d_x for d_x, _ in back)[w.dest], 0)
         d_y = picked.astype(jnp.float32).sum(1).astype(x.dtype)
-    return d_y, d_gates, d_w1, d_w3, d_w2
+    return d_y, d_gates, tuple(d_m for _, d_m in back) + (d_last,)
 
 
 def _later_windows(usual: int, p: Plan):
@@ -157,18 +201,18 @@ def _later_windows(usual: int, p: Plan):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(usual: int, y, gates, w1, w3, w2, p: Plan):
+def _experts(usual: int, y, gates, weights, p: Plan):
     """The layer's result, the sorted pairs taken ``usual`` rows at a
-    time.  The first window always runs and keeps its four intermediates
-    for the backward pass.  Where the pairs held here overflow it
+    time.  The first window always runs and keeps its intermediates for
+    the backward pass.  Where the pairs held here overflow it
     (``lax.cond`` on the count, forward and backward), the later windows
     run one after the other in the same buffers, and the backward pass
     computes theirs again."""
-    return _experts_fwd(usual, y, gates, w1, w3, w2, p)[0]
+    return _experts_fwd(usual, y, gates, weights, p)[0]
 
 
-def _experts_fwd(usual, y, gates, w1, w3, w2, p):
-    operands = (y, gates, w1, w3, w2)
+def _experts_fwd(usual, y, gates, weights, p):
+    operands = (y, gates, weights)
     out, kept = _window_forward(_window(p, 0, usual), *operands)
 
     def overflow(out_):
@@ -183,16 +227,15 @@ def _experts_fwd(usual, y, gates, w1, w3, w2, p):
 
 
 def _experts_bwd(usual, res, g):
-    y, gates, w1, w3, w2, p, kept = res
-    grads = _window_backward(_window(p, 0, usual), kept, gates, w1, w3, w2,
-                             g)
+    y, gates, weights, p, kept = res
+    grads = _window_backward(_window(p, 0, usual), kept, gates, weights, g)
 
     def overflow(grads_):
         def one(acc, start):
             w = _window(p, start, usual)
-            again = _window_forward(w, y, gates, w1, w3, w2)[1]
-            more = _window_backward(w, again, gates, w1, w3, w2, g)
-            return tuple(a + m for a, m in zip(acc, more)), None
+            again = _window_forward(w, y, gates, weights)[1]
+            more = _window_backward(w, again, gates, weights, g)
+            return jax.tree_util.tree_map(jnp.add, acc, more), None
         return jax.lax.scan(one, grads_, _later_windows(usual, p))[0]
 
     if usual < p.pairs.shape[0]:
@@ -203,30 +246,53 @@ def _experts_bwd(usual, res, g):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def experts_forward(y, experts, gates, w1, w3, w2, first: int,
+def experts_forward(y, experts, gates, weights, first: int,
                     num_experts: int):
     """(out float32 ``[T, d]``, pairs of each held expert ``[held]``)
-    for tokens ``y [T, d]`` routed to ``experts``/``gates`` ``[T, k]``."""
+    for tokens ``y [T, d]`` routed to ``experts``/``gates`` ``[T, k]``;
+    ``weights`` the held experts' matrices, (w1, w3, w2) or (w1, w2)."""
     tokens, k = experts.shape
-    held = w1.shape[0]
+    held = weights[0].shape[0]
     with jax.named_scope("dispatch"):
         p = plan(experts, first, held)
     usual = usual_rows(tokens, k, held, num_experts)
-    return _experts(usual, y, gates, w1, w3, w2, p), p.sizes
+    return _experts(usual, y, gates, tuple(weights), p), p.sizes
 
 
-class GroupedSwiGLUExperts(Layer):
-    """``held`` SiLU-gated experts of ``num_experts``, stacked:
-    ``w1``, ``w3`` ``[held, d_model, d_hidden]`` and ``w2`` ``[held,
-    d_hidden, d_model]``; expert e of the model is row ``e - first``."""
+class _GroupedExperts(Layer):
+    """``held`` experts of ``num_experts``, their matrices stacked;
+    expert e of the model is row ``e - first``."""
 
-    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
-                 first: int, held: int, initializer_range: float = 0.02):
+    def __init__(self, num_experts: int, first: int, held: int):
         super().__init__()
         if not 0 <= first <= first + held <= num_experts:
             raise ValueError(f"experts {first}..{first + held} of "
                              f"{num_experts}")
         self.num_experts, self.first, self.held = num_experts, first, held
+
+    def _matrices(self):
+        raise NotImplementedError
+
+    def forward(self, y, experts, gates):
+        """``y [T, d]``, ``experts``/``gates`` ``[T, k]`` over all
+        ``num_experts`` -> (float32 ``[T, d]``, int32 ``[held]``)."""
+        idx = experts._value
+
+        def closure(y_, gates_, *weights):
+            return experts_forward(y_, idx, gates_, weights, self.first,
+                                   self.num_experts)
+
+        return apply_closure(closure, [y, gates] + self._matrices(),
+                             name="grouped_experts")
+
+
+class GroupedSwiGLUExperts(_GroupedExperts):
+    """SiLU-gated: ``w1``, ``w3`` ``[held, d_model, d_hidden]`` and ``w2``
+    ``[held, d_hidden, d_model]``."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 first: int, held: int, initializer_range: float = 0.02):
+        super().__init__(num_experts, first, held)
         init = I.Normal(0.0, initializer_range)
         self.w1 = self.create_parameter(shape=[held, d_model, d_hidden],
                                         default_initializer=init)
@@ -235,14 +301,26 @@ class GroupedSwiGLUExperts(Layer):
         self.w2 = self.create_parameter(shape=[held, d_hidden, d_model],
                                         default_initializer=init)
 
-    def forward(self, y, experts, gates):
-        """``y [T, d]``, ``experts``/``gates`` ``[T, k]`` over all
-        ``num_experts`` -> (float32 ``[T, d]``, int32 ``[held]``)."""
-        idx = experts._value
+    def _matrices(self):
+        return [self.w1, self.w3, self.w2]
 
-        def closure(y_, gates_, w1, w3, w2):
-            return experts_forward(y_, idx, gates_, w1, w3, w2, self.first,
-                                   self.num_experts)
 
-        return apply_closure(closure, [y, gates, self.w1, self.w3, self.w2],
-                             name="grouped_swiglu_experts")
+class GroupedRelu2Experts(_GroupedExperts):
+    """Squared ReLU, no gate: ``w1 [held, d_model, d_hidden]`` and ``w2
+    [held, d_hidden, d_model]``; ``w2`` may start at a range of its
+    own."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 first: int, held: int, initializer_range: float = 0.02,
+                 w2_range: Optional[float] = None):
+        super().__init__(num_experts, first, held)
+        self.w1 = self.create_parameter(
+            shape=[held, d_model, d_hidden],
+            default_initializer=I.Normal(0.0, initializer_range))
+        self.w2 = self.create_parameter(
+            shape=[held, d_hidden, d_model],
+            default_initializer=I.Normal(
+                0.0, initializer_range if w2_range is None else w2_range))
+
+    def _matrices(self):
+        return [self.w1, self.w2]

@@ -19,3 +19,6 @@ from .keye_lm import (  # noqa
 from .granite_hybrid import (  # noqa
     GraniteHybridConfig, GraniteHybridModel, GraniteHybridForCausalLM,
     GraniteHybridPretrainingCriterion, granite_hybrid_tiny)
+from .nemotron_h import (  # noqa
+    NemotronHConfig, NemotronHModel, NemotronHForCausalLM,
+    NemotronHPretrainingCriterion, nemotron_h_tiny)

@@ -13,12 +13,14 @@ muP-style multipliers and an output head tied to the embedding.
     h = h + residual_multiplier * MLP(RMSNorm(h))
     logits = RMSNorm(h) E^T / logits_scaling
 
-Mamba-2 mixer: ``[z, xBC, dt] = x W_in``; ``xBC = silu(conv(xBC))`` (width
+Mamba-2 mixer (``models/mamba2.py``, built from this config's sizes):
+``[z, xBC, dt] = x W_in``; ``xBC = silu(conv(xBC))`` (width
 ``mamba_d_conv``, with bias); ``[x, B, C] = split(xBC)``; ``dt =
 softplus(dt + dt_bias)``, ``A = -exp(A_log)``, a head; ``y = scan(x, dt,
-A, B, C) + D x``; ``y = RMSNorm(y * silu(z)) w`` over all of d_inner;
-``out = y W_out``.  Attention: scores ``q . k * attention_multiplier``,
-causal, no rotation; the kernels' scale is ``1 / sqrt(head)``, so q is
+A, B, C) + D x``; ``y = RMSNorm(y * silu(z)) w``, the mean square taken
+over each of ``mamba_n_groups`` groups of channels (one group, the
+published count: over all of d_inner); ``out = y W_out``.  Attention:
+scores ``q . k * attention_multiplier``, causal, no rotation; the kernels' scale is ``1 / sqrt(head)``, so q is
 multiplied by what is left (1/8 at the published sizes, a power of two).
 
 The config holds the published keys under their published names, plus
@@ -37,12 +39,12 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn, ops
-from ..framework import dtype as dtypes, random as _random
 from ..nn import initializer as I
-from ..ops import pallas_ops, ssm
+from ..ops import pallas_ops
 from ..ops._primitive import apply_closure
 from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
-from .keye_lm import KeyeRMSNorm as RMSNorm, _linear, _rms
+from .keye_lm import KeyeRMSNorm as RMSNorm, _linear
+from .mamba2 import Mamba2Mixer, _silu_gate
 
 
 @dataclass
@@ -116,144 +118,17 @@ def granite_hybrid_tiny(**kw):
     return GraniteHybridConfig(**base)
 
 
-# --------------------------------------------------------------------------
-# pieces that keep their stored input and compute their float32 insides
-# again in the backward pass
-# --------------------------------------------------------------------------
-@jax.checkpoint
-def _silu_gate(a, b):
-    af = a.astype(jnp.float32)
-    return (af * jax.nn.sigmoid(af) * b.astype(jnp.float32)).astype(a.dtype)
+class GraniteMambaMixer(Mamba2Mixer):
+    """``models/mamba2.py``'s mixer at this config's sizes; ``out_proj``
+    starts as every other matrix does."""
 
-
-@jax.checkpoint
-def _conv_silu(x, weight, bias):
-    out = ssm.causal_conv1d(x, weight, bias).astype(jnp.float32)
-    return (out * jax.nn.sigmoid(out)).astype(x.dtype)
-
-
-@jax.checkpoint
-def _step_sizes(dt, dt_bias):
-    return jax.nn.softplus(dt.astype(jnp.float32)
-                           + dt_bias.astype(jnp.float32))
-
-
-def _count_scan(layer: int, seq: int, c: GraniteHybridConfig, calls: int):
-    """As a mixer's scans are traced: the chunks x heads they walk and
-    the states they pass from chunk to chunk."""
-    from ..observability import metrics
-    reg, labels = metrics.registry(), {"layer": str(layer)}
-    reg.counter("ssm_scan_chunks_total",
-                "chunks x heads of the state-space scans, counted a call "
-                "when the call is traced", labels=labels).inc(
-        calls * ssm.scan_chunks(seq, c.mamba_n_heads, c.mamba_chunk_size))
-    reg.gauge("ssm_scan_state_bytes",
-              "bytes of the float32 states one scan passes from chunk to "
-              "chunk", labels=labels).set(ssm.scan_state_bytes(
-                  seq, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
-                  c.mamba_chunk_size))
-
-
-# --------------------------------------------------------------------------
-# layers
-# --------------------------------------------------------------------------
-class _Conv1d(nn.Layer):
-    """Depthwise, causal: ``weight [channels, width]`` and a bias."""
-
-    def __init__(self, channels: int, width: int):
-        super().__init__()
-        bound = 1.0 / math.sqrt(width)     # torch's Conv1d, fan-in = width
-        self.weight = self.create_parameter(
-            shape=[channels, width],
-            default_initializer=I.Uniform(-bound, bound))
-        self.bias = self.create_parameter(
-            shape=[channels], default_initializer=I.Uniform(-bound, bound))
-
-
-class GraniteMambaMixer(nn.Layer):
     def __init__(self, config: GraniteHybridConfig, layer_idx: int):
-        super().__init__()
-        c, std = config, config.initializer_range
-        self.config, self.layer_idx = c, layer_idx
-        self.in_proj = _linear(c.hidden_size,
-                               c.d_inner + c.conv_dim + c.mamba_n_heads, std)
-        self.conv1d = _Conv1d(c.conv_dim, c.mamba_d_conv)
-        heads = c.mamba_n_heads
-        # a step drawn log-uniformly from [0.001, 0.1] and put through
-        # the inverse of softplus; A uniform in [1, 16]; D = 1: Mamba-2's
-        # own start
-        self.dt_bias = self.create_parameter(
-            shape=[heads], default_initializer=_InverseSoftplusOfSteps())
-        self.A_log = self.create_parameter(
-            shape=[heads], default_initializer=_LogOfUniform(1.0, 16.0))
-        self.D = self.create_parameter(
-            shape=[heads], default_initializer=I.Constant(1.0))
-        self.norm = RMSNorm(c.d_inner, c.rms_norm_eps)
-        self.out_proj = _linear(c.d_inner, c.hidden_size, std)
-
-    def _one_sequence(self, x, w_in, conv_w, conv_b, dt_bias, a_log, d,
-                      norm_w, w_out):
-        c = self.config
-        seq = x.shape[0]
-        with jax.named_scope("ssm_proj"):
-            z, xbc, dt = jnp.split(
-                x @ w_in, (c.d_inner, c.d_inner + c.conv_dim), axis=-1)
-        with jax.named_scope("ssm_conv"):
-            xbc = _conv_silu(xbc, conv_w, conv_b)
-            state = c.mamba_n_groups * c.mamba_d_state
-            xs, b, cc = jnp.split(xbc, (c.d_inner, c.d_inner + state), -1)
-        with jax.named_scope("ssm_scan"):
-            # [seq, heads, width] is a view of the projection's own
-            # [seq, d_inner]: the kernels read and write it as it lies
-            y = ssm.ssd_scan(
-                xs.reshape(seq, c.mamba_n_heads, c.mamba_d_head),
-                _step_sizes(dt, dt_bias), -jnp.exp(a_log.astype(jnp.float32)),
-                b.reshape(seq, c.mamba_n_groups, c.mamba_d_state),
-                cc.reshape(seq, c.mamba_n_groups, c.mamba_d_state),
-                d.astype(jnp.float32), c.mamba_chunk_size)
-        with jax.named_scope("ssm_norm"):
-            y = _rms(_silu_gate(z, y.reshape(seq, c.d_inner)), norm_w,
-                     c.rms_norm_eps)
-        with jax.named_scope("ssm_proj"):
-            return y @ w_out
-
-    @jax.named_scope("attn")
-    def forward(self, x):
-        """``x [B, S, hidden]`` -> the mixer's output."""
-        weights = [self.in_proj.weight, self.conv1d.weight, self.conv1d.bias,
-                   self.dt_bias, self.A_log, self.D, self.norm.weight,
-                   self.out_proj.weight]
-        _count_scan(self.layer_idx, x.shape[1], self.config, x.shape[0])
-
-        def closure(x_, *w):
-            return jnp.stack([self._one_sequence(x_[b], *w)
-                              for b in range(x_.shape[0])])
-
-        return apply_closure(closure, [x] + weights, name="granite_mamba")
-
-
-class _InverseSoftplusOfSteps(I.Initializer):
-    """``softplus(value)`` is a step drawn log-uniformly from [low, high]."""
-
-    def __init__(self, low: float = 0.001, high: float = 0.1):
-        self.low, self.high = math.log(low), math.log(high)
-
-    def __call__(self, shape, dtype):
-        step = jnp.exp(jax.random.uniform(
-            _random.next_key(), tuple(shape), jnp.float32, self.low,
-            self.high))
-        return (step + jnp.log(-jnp.expm1(-step))).astype(
-            dtypes.to_jax_dtype(dtype))
-
-
-class _LogOfUniform(I.Initializer):
-    def __init__(self, low: float, high: float):
-        self.low, self.high = low, high
-
-    def __call__(self, shape, dtype):
-        return jnp.log(jax.random.uniform(
-            _random.next_key(), tuple(shape), jnp.float32, self.low,
-            self.high)).astype(dtypes.to_jax_dtype(dtype))
+        c = config
+        super().__init__(
+            c.hidden_size, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+            c.mamba_n_groups, c.mamba_d_conv, c.mamba_chunk_size,
+            c.rms_norm_eps, c.initializer_range, c.initializer_range,
+            layer_idx)
 
 
 class GraniteAttention(nn.Layer):

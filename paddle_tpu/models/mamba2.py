@@ -1,0 +1,191 @@
+"""The Mamba-2 mixer (Dao and Gu 2024) as ``models/granite_hybrid.py`` and
+``models/nemotron_h.py`` build it, each from its own config's sizes:
+
+    [z, xBC, dt] = x W_in;  xBC = silu(conv(xBC))     depthwise, causal, with bias
+    [x, B, C] = split(xBC);  dt = softplus(dt + dt_bias);  A = -exp(A_log)   a head
+    y = scan(x, dt, A, B, C) + D x                    ``ops/ssm.py``, in chunks
+    y = RMSNorm(y * silu(z)) w;  out = y W_out
+
+B and C come in ``groups`` groups, head h reading group ``h // (heads /
+groups)``, and the gated norm takes its mean square over each group's
+channels: one group is a norm over all of ``d_inner``.  It opens the
+sub-scopes ``ssm_proj``, ``ssm_conv``, ``ssm_scan`` and ``ssm_norm`` inside
+``attn`` and counts its scans (``ssm_scan_chunks_total{layer}``,
+``ssm_scan_state_bytes{layer}``) as they are traced.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..framework import dtype as dtypes, random as _random
+from ..nn import initializer as I
+from ..ops import ssm
+from ..ops._primitive import apply_closure
+from .keye_lm import KeyeRMSNorm as RMSNorm, _linear, _rms
+
+
+# --------------------------------------------------------------------------
+# pieces that keep their stored input and compute their float32 insides
+# again in the backward pass
+# --------------------------------------------------------------------------
+@jax.checkpoint
+def _silu_gate(a, b):
+    af = a.astype(jnp.float32)
+    return (af * jax.nn.sigmoid(af) * b.astype(jnp.float32)).astype(a.dtype)
+
+
+@jax.checkpoint
+def _conv_silu(x, weight, bias):
+    out = ssm.causal_conv1d(x, weight, bias).astype(jnp.float32)
+    return (out * jax.nn.sigmoid(out)).astype(x.dtype)
+
+
+@jax.checkpoint
+def _step_sizes(dt, dt_bias):
+    return jax.nn.softplus(dt.astype(jnp.float32)
+                           + dt_bias.astype(jnp.float32))
+
+
+def _rms_by_group(x, weight, eps, groups: int):
+    """:func:`_rms` with the mean square over each of ``groups`` groups of
+    channels; one group is ``_rms`` itself."""
+    if groups == 1:
+        return _rms(x, weight, eps)
+    by_group = x.reshape(x.shape[:-1] + (groups, -1)).astype(jnp.float32)
+    out = by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
+    return (out.reshape(x.shape) * weight.astype(jnp.float32)).astype(
+        x.dtype)
+
+
+def _count_scan(layer: int, seq: int, calls: int, heads: int, width: int,
+                state: int, chunk: int):
+    """As a mixer's scans are traced: the chunks x heads they walk and
+    the states they pass from chunk to chunk."""
+    from ..observability import metrics
+    reg, labels = metrics.registry(), {"layer": str(layer)}
+    reg.counter("ssm_scan_chunks_total",
+                "chunks x heads of the state-space scans, counted a call "
+                "when the call is traced", labels=labels).inc(
+        calls * ssm.scan_chunks(seq, heads, chunk))
+    reg.gauge("ssm_scan_state_bytes",
+              "bytes of the float32 states one scan passes from chunk to "
+              "chunk", labels=labels).set(ssm.scan_state_bytes(
+                  seq, heads, width, state, chunk))
+
+
+# --------------------------------------------------------------------------
+# layers
+# --------------------------------------------------------------------------
+class _Conv1d(nn.Layer):
+    """Depthwise, causal: ``weight [channels, width]`` and a bias."""
+
+    def __init__(self, channels: int, width: int):
+        super().__init__()
+        bound = 1.0 / math.sqrt(width)     # torch's Conv1d, fan-in = width
+        self.weight = self.create_parameter(
+            shape=[channels, width],
+            default_initializer=I.Uniform(-bound, bound))
+        self.bias = self.create_parameter(
+            shape=[channels], default_initializer=I.Uniform(-bound, bound))
+
+
+class Mamba2Mixer(nn.Layer):
+    """``heads`` heads of width ``head_dim`` over ``groups`` groups of B
+    and C with ``state`` states each; ``out_proj`` starts at
+    ``out_range``, every other matrix at ``initializer_range``."""
+
+    def __init__(self, hidden_size: int, heads: int, head_dim: int,
+                 state: int, groups: int, conv_width: int, chunk: int,
+                 eps: float, initializer_range: float, out_range: float,
+                 layer_idx: int):
+        super().__init__()
+        self.heads, self.head_dim, self.state = heads, head_dim, state
+        self.groups, self.chunk, self.eps = groups, chunk, eps
+        self.layer_idx = layer_idx
+        self.d_inner = heads * head_dim
+        self.conv_dim = self.d_inner + 2 * groups * state
+        self.in_proj = _linear(hidden_size,
+                               self.d_inner + self.conv_dim + heads,
+                               initializer_range)
+        self.conv1d = _Conv1d(self.conv_dim, conv_width)
+        # a step drawn log-uniformly from [0.001, 0.1] and put through
+        # the inverse of softplus; A uniform in [1, 16]; D = 1: Mamba-2's
+        # own start
+        self.dt_bias = self.create_parameter(
+            shape=[heads], default_initializer=_InverseSoftplusOfSteps())
+        self.A_log = self.create_parameter(
+            shape=[heads], default_initializer=_LogOfUniform(1.0, 16.0))
+        self.D = self.create_parameter(
+            shape=[heads], default_initializer=I.Constant(1.0))
+        self.norm = RMSNorm(self.d_inner, eps)
+        self.out_proj = _linear(self.d_inner, hidden_size, out_range)
+
+    def _one_sequence(self, x, w_in, conv_w, conv_b, dt_bias, a_log, d,
+                      norm_w, w_out):
+        seq, inner = x.shape[0], self.d_inner
+        with jax.named_scope("ssm_proj"):
+            z, xbc, dt = jnp.split(
+                x @ w_in, (inner, inner + self.conv_dim), axis=-1)
+        with jax.named_scope("ssm_conv"):
+            xbc = _conv_silu(xbc, conv_w, conv_b)
+            xs, b, cc = jnp.split(
+                xbc, (inner, inner + self.groups * self.state), -1)
+        with jax.named_scope("ssm_scan"):
+            # [seq, heads, width] is a view of the projection's own
+            # [seq, d_inner]: the kernels read and write it as it lies
+            y = ssm.ssd_scan(
+                xs.reshape(seq, self.heads, self.head_dim),
+                _step_sizes(dt, dt_bias), -jnp.exp(a_log.astype(jnp.float32)),
+                b.reshape(seq, self.groups, self.state),
+                cc.reshape(seq, self.groups, self.state),
+                d.astype(jnp.float32), self.chunk)
+        with jax.named_scope("ssm_norm"):
+            y = _rms_by_group(_silu_gate(z, y.reshape(seq, inner)), norm_w,
+                              self.eps, self.groups)
+        with jax.named_scope("ssm_proj"):
+            return y @ w_out
+
+    @jax.named_scope("attn")
+    def forward(self, x):
+        """``x [B, S, hidden]`` -> the mixer's output."""
+        weights = [self.in_proj.weight, self.conv1d.weight, self.conv1d.bias,
+                   self.dt_bias, self.A_log, self.D, self.norm.weight,
+                   self.out_proj.weight]
+        _count_scan(self.layer_idx, x.shape[1], x.shape[0], self.heads,
+                    self.head_dim, self.state, self.chunk)
+
+        def closure(x_, *w):
+            return jnp.stack([self._one_sequence(x_[b], *w)
+                              for b in range(x_.shape[0])])
+
+        return apply_closure(closure, [x] + weights, name="mamba2_mixer")
+
+
+class _InverseSoftplusOfSteps(I.Initializer):
+    """``softplus(value)`` is a step drawn log-uniformly from [low, high]."""
+
+    def __init__(self, low: float = 0.001, high: float = 0.1):
+        self.low, self.high = math.log(low), math.log(high)
+
+    def __call__(self, shape, dtype):
+        step = jnp.exp(jax.random.uniform(
+            _random.next_key(), tuple(shape), jnp.float32, self.low,
+            self.high))
+        return (step + jnp.log(-jnp.expm1(-step))).astype(
+            dtypes.to_jax_dtype(dtype))
+
+
+class _LogOfUniform(I.Initializer):
+    def __init__(self, low: float, high: float):
+        self.low, self.high = low, high
+
+    def __call__(self, shape, dtype):
+        return jnp.log(jax.random.uniform(
+            _random.next_key(), tuple(shape), jnp.float32, self.low,
+            self.high)).astype(dtypes.to_jax_dtype(dtype))

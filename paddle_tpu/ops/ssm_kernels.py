@@ -36,6 +36,14 @@ H]`` arrays.
 
 Product inputs are x's dtype (bf16 under O2); decays, sums and the state
 float32.  Which shapes take these kernels is ``ssm.scan_form``'s to say.
+Two of them have run on the chip, both ``[8192, 64 heads, 64]`` at state
+128: one group of B and C at chunk 256 (32 visits a call; the cell
+``granite4h-micro-stage0-s8192``, PR 33) and eight groups at chunk 128
+(64 visits of half the rows, ``C . B^T`` made eight times a chunk, at
+each group's first of four lane groups; the cell
+``nemotron3-nano-ep16stage0-s8192``, PR 34), each against the recurrence
+a position at a time (``PERF.md`` section 2); the other shapes
+``scan_form`` admits have run under the interpreter only.
 """
 
 from __future__ import annotations

@@ -177,3 +177,31 @@ def _reset_state():
     tape.reset_tape()
     tape.set_grad_enabled(True)
     collective.set_mesh(None)
+
+
+# ``tests/benchmarks/test_granite_cell.py`` pins the number of cells the
+# benchmark has (``len(bench["workloads"]) == 5``) inside its test of
+# what its own cell declares.  A PR that adds a cell may edit no file the
+# benchmark already has, a ``model_config`` PR has to add its cell, and
+# tier-1 may not get worse: so that one test is expected to fail, on that
+# line, from the sixth cell on (PR 34).  ``strict``: the day a
+# ``benchmark`` PR takes the count out of it the test passes, this mark
+# fails, and this hook goes (ROADMAP D16).  Until then
+# ``test_nemotron_cell.py`` runs that test's whole body on the benchmark
+# less what PR 34 appended
+# (``test_the_benchmark_gained_entries_at_the_end_and_kept_the_rest``),
+# so nothing the test held is let go.  (Not a ``conftest.py`` beside the
+# test: ``tests/`` has no packages, so a second module named ``conftest``
+# hides this one from ``from conftest import ...``.)
+_PINNED_CELL_COUNT = (
+    "test_granite_cell.py::"
+    "test_the_cell_declares_its_metrics_and_reads_the_block_metrics")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(_PINNED_CELL_COUNT):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins len(workloads) == 5; the benchmark has six "
+                       "cells since PR 34, which may not edit the file",
+                raises=AssertionError, strict=True))

@@ -229,7 +229,7 @@ def _dropless_experts(topo, monkeypatch):
 
     def loss(y, logits, w1, w3, w2):
         experts, gates = grouped.route(logits, k)
-        out, _ = grouped.experts_forward(y, experts, gates, w1, w3, w2,
+        out, _ = grouped.experts_forward(y, experts, gates, (w1, w3, w2),
                                          0, 128)
         return out.sum()
 
@@ -271,6 +271,55 @@ def _scan_bwd(topo, monkeypatch):
                        x, dt, A, B, C, D)[1](dy)
 
     return grads, args + (args[0],)
+
+
+def _scan_operands_8_groups(topo, monkeypatch):
+    """``ssd_scan`` at the shape of the cell nemotron3-nano-ep16stage0-s8192:
+    x ``[8192, 64 heads, 64]``, eight groups of B and C, state 128, chunk
+    128: a visit is a chunk of 128 for all heads, ``C . B^T`` made at a
+    group's first of four lane groups, its gradient gathered at its last."""
+    from paddle_tpu.ops import ssm
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    seq, heads, width, groups, state = 8192, 64, 64, 8, 128
+    assert ssm.scan_form(seq, heads, width, groups, state, 128) == "kernels"
+    by_head = spec((heads,), jnp.float32)
+    return (spec((seq, heads, width)), spec((seq, heads), jnp.float32),
+            by_head, spec((seq, groups, state)), spec((seq, groups, state)),
+            by_head)
+
+
+def _scan_fwd_8_groups(topo, monkeypatch):
+    from paddle_tpu.ops import ssm
+    return (lambda *a: ssm.ssd_scan(*a, 128)), _scan_operands_8_groups(
+        topo, monkeypatch)
+
+
+def _scan_bwd_8_groups(topo, monkeypatch):
+    from paddle_tpu.ops import ssm
+    args = _scan_operands_8_groups(topo, monkeypatch)
+
+    def grads(x, dt, A, B, C, D, dy):
+        return jax.vjp(lambda *a: ssm.ssd_scan(*a, 128),
+                       x, dt, A, B, C, D)[1](dy)
+
+    return grads, args + (args[0],)
+
+
+def _flash_32_on_2(topo, monkeypatch):
+    """The same cell's attention block: 32 query heads on 2 key/value
+    heads of width 128 at s8192, forward and backward, through the public
+    ``flash_attention`` (its GQA branch repeats K and V 16-fold)."""
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    q, kv = spec((1, 8192, 32, 128)), spec((1, 8192, 2, 128))
+
+    def grads(q_, k_, v_, w_):
+        return jax.grad(lambda *a: (pallas_ops.flash_attention.raw(
+            *a, causal=True) * w_).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q_, k_, v_)
+
+    return grads, (q, kv, kv, q)
 
 
 def _paged_decode(topo, monkeypatch):
@@ -318,6 +367,11 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
     pytest.param(_scan_fwd, 1, None, id="ssd_scan_fwd_s8192"),
     pytest.param(_scan_bwd, 2, None, id="ssd_scan_bwd_s8192"),
+    pytest.param(_scan_fwd_8_groups, 1, None,
+                 id="ssd_scan_fwd_s8192_8_groups_chunk_128"),
+    pytest.param(_scan_bwd_8_groups, 2, None,
+                 id="ssd_scan_bwd_s8192_8_groups_chunk_128"),
+    pytest.param(_flash_32_on_2, 3, None, id="flash_32_on_2_heads_of_128"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
              r"\"#tpu\.dot_dimension_numbers",
@@ -389,3 +443,59 @@ def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
     # layer's scan: forward, the forward again, the walk back
     assert compiled.as_text().count("tpu_custom_call") == \
         4 * kinds.count("attention") + 3 * kinds.count("mamba") == 31
+
+
+def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell nemotron3-nano-ep16stage0-s8192
+    (nine blocks MEMEM*EME at published widths, 8 of 128 experts, b1 x
+    s8192, bf16 O2 with float32 master weights, the blocks the
+    configuration names recomputed), as ``DistributedRunner`` builds it,
+    compiled for one described v5e chip: what it needs on the device stays
+    under the configuration's limit, and its own kernels are in it: a
+    Mamba-2 block's scan forward and the walk back, the attention block's
+    forward, dq and dkv, the forward once more where a block is
+    recomputed; the rest are the experts' grouped products."""
+    import numpy as np
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_nemotron_lm as driver
+    from benchmarks.families import nemotron_h as family
+    from benchmarks.harness import cells
+    config = cells.load_json(os.path.join(
+        root, "benchmarks", "configs", "nemotron-3-nano-30b-a3b.json"))
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    prev_mesh = collective.get_mesh()
+    try:
+        with paddle_tpu.LazyGuard():
+            runner = driver.build_runner(config, 0, topo.devices[:1])
+        monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
+        ids = np.zeros((1, 8192), np.int64)
+        data = sum(runner._prep_step_args([ids], [ids]), [])
+        on_chip = NamedSharding(runner.mesh, P())
+
+        def shapes(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=on_chip), tree)
+
+        compiled = runner._step_fn.lower(
+            *shapes(runner._sync_val_cache()), shapes(runner._opt_state),
+            *shapes([jnp.float32(0), jnp.uint32(1)] + data)).compile()
+    finally:
+        collective.set_mesh(prev_mesh)
+    memory = compiled.memory_analysis()
+    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    # 666 962 944 parameters at 14 bytes, and the batch
+    assert memory.argument_size_in_bytes == approx(9.3375e9, rel=1e-3)
+    assert step < config["step_bytes_limit"] == 15.6e9
+    print(f"compiled step: {step} bytes a device")
+    text = compiled.as_text()
+    own = driver.kernel_sites(family.kinds(config), set(config["recompute"]))
+    assert own == 4 * 2 + 3 + len(config["recompute"])
+    sites = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    products = [line for line in sites if "ragged-dot" in line]
+    print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
+          "experts' grouped products")
+    assert products and len(sites) - len(products) == own

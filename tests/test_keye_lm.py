@@ -449,8 +449,8 @@ def _expert_layer(seed, tokens=128, d=32, f=16, experts=64, k=8):
 def _share(y, router, w1, w3, w2, k, first, held):
     chosen, gates = grouped.route(y @ router, k)
     return grouped.experts_forward(
-        y, chosen, gates, w1[first:first + held], w3[first:first + held],
-        w2[first:first + held], first, w1.shape[0])
+        y, chosen, gates, (w1[first:first + held], w3[first:first + held],
+                           w2[first:first + held]), first, w1.shape[0])
 
 
 def _reference_layer(y, router, w1, w3, w2, k, first, held):
