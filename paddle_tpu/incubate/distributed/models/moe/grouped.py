@@ -29,9 +29,15 @@ stands in for one.
 
 How: the pairs are sorted by expert (pairs of absent experts last), the
 tokens of the pairs held here are gathered into that order, the
-projections (three or two) run as grouped matrix products over the uneven groups
-(``jax.lax.ragged_dot``, which XLA lowers to Mosaic kernels on a TPU),
-and each token gathers its results back and adds them up in float32.
+projections (three or two) run as grouped matrix products over the uneven
+groups, and each token gathers its results back and adds them up in
+float32.  The products and their two gradients have two forms, and
+``ops/grouped_matmul.form`` names the one that runs from platform, shape
+and dtype (:func:`_dot`, :func:`_dot_back`): on a TPU, for windows of
+whole row tiles, the Mosaic kernels of ``ops/grouped_matmul.py``, which
+visit only the row tiles that hold pairs; everywhere else (the CPU, odd
+shapes) ``jax.lax.ragged_dot`` and its ``jax.vjp``, which the kernels
+are checked against.  Both leave the rows past the count 0.
 
 Shapes must be static and the number of pairs held here is not.  The
 sorted pairs are taken a window of rows at a time, a window being twice
@@ -51,6 +57,7 @@ import jax.numpy as jnp
 
 from .....nn.layer import Layer
 from .....nn import initializer as I
+from .....ops import grouped_matmul
 from .....ops._primitive import apply_closure
 
 
@@ -124,6 +131,24 @@ def usual_rows(tokens: int, k: int, held: int, num_experts: int) -> int:
     return min(tokens * k, -(-share // 512) * 512)
 
 
+def _dot(lhs, rhs, sizes):
+    """``out[r] = lhs[r] . rhs[group(r)]`` for rows sorted by group,
+    ``sizes`` rows a group; rows past the groups are 0."""
+    if grouped_matmul.form(lhs, rhs) == "kernels":
+        return grouped_matmul.dot(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes=sizes)
+
+
+def _dot_back(lhs, rhs, sizes, d_out):
+    """Gradients of :func:`_dot` for (lhs, rhs), in the form it ran."""
+    if grouped_matmul.form(lhs, rhs) == "kernels":
+        return (grouped_matmul.dot(d_out, rhs, sizes, transposed=True),
+                grouped_matmul.dot_weights(lhs, d_out, sizes))
+    # the product's forward result is not used again: XLA drops it
+    return jax.vjp(functools.partial(jax.lax.ragged_dot, group_sizes=sizes),
+                   lhs, rhs)[1](d_out)
+
+
 class _Window(NamedTuple):
     """The rows ``start .. start + rows`` of the sorted order."""
     pairs: jax.Array     # [rows] pair index of each row
@@ -151,7 +176,7 @@ def _window_forward(w: _Window, y, gates, weights):
     expert's first matrices, the experts' results).  ``weights`` are an
     expert's matrices, stacked over the held: (w1, w3, w2) SiLU-gated,
     (w1, w2) squared ReLU."""
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=w.sizes)
+    dot = functools.partial(_dot, sizes=w.sizes)
     *inner, last = weights
     with jax.named_scope("dispatch"):
         x = y[w.pairs // gates.shape[1]]
@@ -171,10 +196,6 @@ def _window_backward(w: _Window, kept, gates, weights, g):
     x, pre, rows = kept
     *inner, last = weights
     k = gates.shape[1]
-
-    def dot(lhs, rhs):
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes=w.sizes)
-
     with jax.named_scope("combine"):
         g_rows = g[w.pairs // k]                                # [rows, d]
         d_rows = jnp.where(w.in_use, g_rows * gates.reshape(-1)[w.pairs][
@@ -183,10 +204,9 @@ def _window_backward(w: _Window, kept, gates, weights, g):
         d_gates = jnp.where(w.here, d_gate_rows[w.dest], 0).astype(
             gates.dtype)
     with jax.named_scope("experts"):
-        # each product's forward result is not used again: XLA drops it
         h, activation_back = jax.vjp(_activation(len(inner)), *pre)
-        d_h, d_last = jax.vjp(dot, h, last)[1](d_rows)
-        back = [jax.vjp(dot, x, m)[1](d)
+        d_h, d_last = _dot_back(h, last, w.sizes, d_rows)
+        back = [_dot_back(x, m, w.sizes, d)
                 for m, d in zip(inner, activation_back(d_h))]
     with jax.named_scope("dispatch"):
         picked = jnp.where(w.here[..., None],

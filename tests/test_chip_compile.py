@@ -218,24 +218,39 @@ def _indexer_scores(topo, monkeypatch):
                      spec((seq, heads)), spec((seq, seq), jnp.float32))
 
 
-def _dropless_experts(topo, monkeypatch):
+def _dropless_experts(topo, monkeypatch, tokens=8192, d=2048, f=768,
+                      held=16, k=8, matrices=3):
     """The same cell's expert layer, 16 held experts of 128 at width 768
-    on 8192 tokens: XLA lowers ``jax.lax.ragged_dot`` and its two
-    gradients to Mosaic kernels, in both sizes of the row buffer
+    on 8192 tokens, 8 a token, three matrices an expert: the grouped
+    products and their two gradients as the Mosaic kernels of
+    ``ops/grouped_matmul.py``, which ``grouped_matmul.form`` gives these
+    shapes, in the first window and in the overflow branch's scan
     (``incubate/distributed/models/moe/grouped.py``)."""
     from paddle_tpu.incubate.distributed.models.moe import grouped
+    from paddle_tpu.ops import grouped_matmul
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
     spec = _one_chip_spec(topo)
-    tokens, d, f, held, k = 8192, 2048, 768, 16, 8
+    inner = [spec((held, d, f))] * (matrices - 1)
+    assert grouped_matmul.form(
+        spec((grouped.usual_rows(tokens, k, held, 128), d)),
+        inner[0]) == "kernels"
 
-    def loss(y, logits, w1, w3, w2):
+    def loss(y, logits, *weights):
         experts, gates = grouped.route(logits, k)
-        out, _ = grouped.experts_forward(y, experts, gates, (w1, w3, w2),
-                                         0, 128)
+        out, _ = grouped.experts_forward(y, experts, gates, weights, 0, 128)
         return out.sum()
 
-    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), (
-        spec((tokens, d)), spec((tokens, 128), jnp.float32),
-        spec((held, d, f)), spec((held, d, f)), spec((held, f, d)))
+    return jax.grad(loss, argnums=tuple(range(2 + matrices))), (
+        spec((tokens, d)), spec((tokens, 128), jnp.float32), *inner,
+        spec((held, f, d)))
+
+
+def _dropless_experts_of_1856(topo, monkeypatch):
+    """... and at the shape of the cell nemotron3-nano-ep16stage0-s8192:
+    8 held of 128, 2688 x 1856, two matrices an expert, 6 a token; 1856
+    is fourteen and a half lane groups, taken as it is."""
+    return _dropless_experts(topo, monkeypatch, d=2688, f=1856, held=8,
+                             k=6, matrices=2)
 
 
 def _scan_operands(topo, monkeypatch):
@@ -365,6 +380,8 @@ def _refused(build, case_id, pattern, why):
                  id="sparse_core_group_of_one_s2048"),
     pytest.param(_indexer_scores, 2, None, id="indexer_scores_s8192"),
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
+    pytest.param(_dropless_experts_of_1856, 12, None,
+                 id="dropless_experts_8_held_2688_by_1856"),
     pytest.param(_scan_fwd, 1, None, id="ssd_scan_fwd_s8192"),
     pytest.param(_scan_bwd, 2, None, id="ssd_scan_bwd_s8192"),
     pytest.param(_scan_fwd_8_groups, 1, None,
@@ -454,7 +471,10 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     under the configuration's limit, and its own kernels are in it: a
     Mamba-2 block's scan forward and the walk back, the attention block's
     forward, dq and dkv, the forward once more where a block is
-    recomputed; the rest are the experts' grouped products."""
+    recomputed; the rest are the experts' grouped products, the kernels
+    of ``ops/grouped_matmul.py`` by their names: fourteen an expert
+    block, six of them the first window's (two products forward, their
+    four gradients) and eight the overflow branch's."""
     import numpy as np
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
@@ -464,6 +484,14 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     config = cells.load_json(os.path.join(
         root, "benchmarks", "configs", "nemotron-3-nano-30b-a3b.json"))
     monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    from paddle_tpu.observability import metrics
+
+    def product_calls():
+        return [metrics.registry().counter(
+            "moe_grouped_kernel_calls_total", labels={"kind": kind}
+        ).collect() for kind in ("fwd", "dlhs", "drhs")]
+
+    before = product_calls()
     prev_mesh = collective.get_mesh()
     try:
         with paddle_tpu.LazyGuard():
@@ -495,7 +523,15 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     assert own == 4 * 2 + 3 + len(config["recompute"])
     sites = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    products = [line for line in sites if "ragged-dot" in line]
+    products = [line for line in sites if "grouped_dot" in line]
     print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
           "experts' grouped products")
-    assert products and len(sites) - len(products) == own
+    blocks = family.kinds(config).count("moe")
+    assert len(products) == 14 * blocks
+    # as the step was traced, an expert block: two products forward, in
+    # the overflow branch and there again for its backward pass; their
+    # four gradients in the first window and in the branch
+    assert [now - was for now, was in zip(product_calls(), before)] == [
+        6 * blocks, 4 * blocks, 4 * blocks]
+    assert len(sites) - len(products) == own
+    assert "ragged-dot" not in text
