@@ -40,6 +40,8 @@ from .resilience import elastic_rank as _elastic
 from .resilience import faults as _faults
 from .resilience import watchdog as _watchdog
 from ..framework import env_knobs
+from ..observability import events as _obs_events
+from ..observability import host_events as _host_events
 from ..observability import metrics as _obs_metrics
 from ..observability import trace as _obs_trace
 
@@ -64,6 +66,11 @@ def _observe_mesh_steps(n_steps: int, wall_s: float):
 
 
 _data_axes = coll.data_axes
+
+# what jax builds for these functions is counted under their own names
+# (jax_compile_*_total{fun}): the jitted step of _build, the folded
+# entry of framework/dispatch.build_folded_step, the inference steps
+_host_events.register_fun("step", "program", "eval_step", "predict_step")
 
 #: env overrides for the dp gradient-path knobs (DESIGN-DCN.md): a set
 #: env var WINS over the constructor/strategy value, so a bench or an
@@ -147,6 +154,9 @@ class DistributedRunner:
         # DistributedStrategy.recompute wiring (trade FLOPs for HBM)
         self.remat = remat
         self._step_fn = None
+        # the argument signature of the executable _step_fn built last
+        # (_count_step_program): why a further one is built
+        self._step_signature = None
         self._opt_state = None
         self._placed = False
         # folded dispatch (the unified engine, framework/dispatch.py):
@@ -799,7 +809,11 @@ class DistributedRunner:
         coll.set_mesh(self.mesh)
         try:
             t0 = time.perf_counter()
-            with _obs_trace.span("mesh.dispatch"):
+            # step=: what the phases of one step share (mesh.stage,
+            # .scalars, .val_cache, .launch, .commit lie inside it)
+            with _obs_trace.span(
+                    "mesh.dispatch",
+                    {"step": getattr(self, "_step_ctr", 0) + 1}):
                 out = self._train_step_inner(inputs, labels)
             _observe_mesh_steps(1, time.perf_counter() - t0)
             self._observe_dp_comm(1)
@@ -814,10 +828,13 @@ class DistributedRunner:
             self._step_fn = self._build()
         # the shared staging path (io/staging.py): Tensors and jax
         # arrays pass through, host leaves take one batched async put
-        inputs_v = to_device_values(
-            inputs if isinstance(inputs, (list, tuple)) else [inputs])
-        labels_v = to_device_values(
-            labels if isinstance(labels, (list, tuple)) else [labels])
+        with _obs_trace.span("mesh.stage"):
+            inputs_v = to_device_values(
+                inputs if isinstance(inputs, (list, tuple))
+                else [inputs])
+            labels_v = to_device_values(
+                labels if isinstance(labels, (list, tuple))
+                else [labels])
         if getattr(self, "_n_inputs", None) is None:
             self._n_inputs = len(inputs_v)
         elif self._n_inputs != len(inputs_v):
@@ -853,13 +870,50 @@ class DistributedRunner:
 
     def _train_step_inner(self, inputs, labels) -> float:
         inputs_v, labels_v = self._prep_step_args(inputs, labels)
-        lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
-        self._step_ctr = getattr(self, "_step_ctr", 0) + 1
-        ctr = jnp.uint32(self._step_ctr)
-        params, frozen, bufs = self._sync_val_cache()
-        loss, new_p, new_s, new_buf, out_vals = self._step_fn(
-            params, frozen, bufs,
-            self._opt_state, lr, ctr, *inputs_v, *labels_v)
+        with _obs_trace.span("mesh.scalars"):
+            lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
+            self._step_ctr = getattr(self, "_step_ctr", 0) + 1
+            ctr = jnp.uint32(self._step_ctr)
+        with _obs_trace.span("mesh.val_cache"):
+            params, frozen, bufs = self._sync_val_cache()
+        step_fn = self._step_fn
+        held = step_fn._cache_size()
+        with _obs_trace.span("mesh.launch"):
+            loss, new_p, new_s, new_buf, out_vals = step_fn(
+                params, frozen, bufs,
+                self._opt_state, lr, ctr, *inputs_v, *labels_v)
+        with _obs_trace.span("mesh.commit"):
+            if step_fn._cache_size() != held:
+                self._count_step_program(
+                    (params, frozen, bufs, self._opt_state, lr, ctr,
+                     *inputs_v, *labels_v))
+            self._commit_step(params, bufs, new_p, new_s, new_buf, 1)
+        if self.capture_outputs:
+            return loss, out_vals
+        return loss
+
+    def _count_step_program(self, args):
+        """The jitted step gained an executable: count it under the
+        reason, and say once which arguments differ from those of the
+        executable before it.  Walks the leaves, so it is called only
+        when ``_step_fn._cache_size()`` grew; donated leaves still say
+        all but their layout."""
+        now = _host_events.argument_signature(args)
+        before, self._step_signature = self._step_signature, now
+        reason, differing = _host_events.signature_change(before, now)
+        _obs_metrics.registry().counter(
+            "mesh_step_programs_total",
+            "executables the jitted train step built, by what differed "
+            "in its arguments from the executable before",
+            labels={"reason": reason}).inc()
+        _obs_events.record("step_program", reason=reason,
+                           step=self._step_ctr, differing=differing)
+
+    def _commit_step(self, params, bufs, new_p, new_s, new_buf,
+                     n_steps: int):
+        """Everything after the launch, for the per-step and the folded
+        entry alike: rebind parameters, optimizer state and buffers to
+        what the program returned, then the resilience hooks."""
         if self._defer_wrapper_sync:
             # hot-loop mode (hapi fit): the cached value dicts are the
             # canonical copy; wrapper ._value rebinds wait for the
@@ -876,7 +930,7 @@ class DistributedRunner:
         # keep the optimizer's canonical slots in sync for checkpointing
         self.optimizer._opt_state_tree = new_s
         if hasattr(self.optimizer, "_global_step"):
-            self.optimizer._global_step += 1
+            self.optimizer._global_step += n_steps
         for n, v in new_buf.items():
             b = self._name_to_buf.get(n)
             if b is None:
@@ -889,13 +943,11 @@ class DistributedRunner:
                 self._buf_snap[n] = v
         # resilience hooks: the committed step feeds the hang watchdog
         # (progress proof) and the chaos layer (kill-at-step-N plans);
-        # both are no-ops unless installed
+        # both are no-ops unless installed.  A folded dispatch ticks
+        # them ONCE, with the step count advanced by its K.
         _watchdog.notify_step(self._step_ctr)
         _elastic.notify_step(self._step_ctr)
         _faults.fault_point("train.step", step=self._step_ctr)
-        if self.capture_outputs:
-            return loss, out_vals
-        return loss
 
     def _sync_val_cache(self):
         """Return (params, frozen, buffers) value dicts, kept coherent.
@@ -1113,43 +1165,20 @@ class DistributedRunner:
         if fn is None:
             fn = self._fold_cache[sig] = self._build_fold(
                 fold, n_in, metric_fns)
-        params, frozen, bufs = self._sync_val_cache()
-        lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
-        ctr0 = getattr(self, "_step_ctr", 0) + 1
-        macc = tuple(metric_acc) if metric_acc is not None else ()
-        losses, mstacks, new_acc, new_p, new_st, new_buf = fn(
-            params, frozen, bufs, self._opt_state, macc, lr,
-            self._ensure_base_key(), np.uint32(ctr0), *stacked)
-        if self._defer_wrapper_sync:
-            # hot-loop mode (hapi fit): the cached value dicts are the
-            # canonical copy; wrapper rebinds wait for the boundary
-            params.update(new_p)
-            self._wrappers_dirty = True
-        else:
-            for n, v in new_p.items():
-                self._name_to_param[n]._value = v
-                params[n] = v
-                self._wrapper_snap[n] = v
-        self._opt_state = new_st
-        self.optimizer._opt_state_tree = new_st
-        if hasattr(self.optimizer, "_global_step"):
-            self.optimizer._global_step += fold
-        for n, v in new_buf.items():
-            b = self._name_to_buf.get(n)
-            if b is None:
-                continue
-            bufs[n] = v
-            if self._defer_wrapper_sync:
-                self._wrappers_dirty = True
-            else:
-                b._value = v
-                self._buf_snap[n] = v
-        # resilience hooks tick ONCE per dispatch, with the logical
-        # step count advanced by the fold factor K
-        self._step_ctr = ctr0 + fold - 1
-        _watchdog.notify_step(self._step_ctr)
-        _elastic.notify_step(self._step_ctr)
-        _faults.fault_point("train.step", step=self._step_ctr)
+        with _obs_trace.span("mesh.val_cache"):
+            params, frozen, bufs = self._sync_val_cache()
+        with _obs_trace.span("mesh.scalars"):
+            lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
+            ctr0 = getattr(self, "_step_ctr", 0) + 1
+            macc = tuple(metric_acc) if metric_acc is not None else ()
+            base_key = self._ensure_base_key()
+        with _obs_trace.span("mesh.launch"):
+            losses, mstacks, new_acc, new_p, new_st, new_buf = fn(
+                params, frozen, bufs, self._opt_state, macc, lr,
+                base_key, np.uint32(ctr0), *stacked)
+        with _obs_trace.span("mesh.commit"):
+            self._step_ctr = ctr0 + fold - 1
+            self._commit_step(params, bufs, new_p, new_st, new_buf, fold)
         from ..framework.lazy import LazyStack
         return (LazyStack(losses), [LazyStack(s) for s in mstacks],
                 tuple(new_acc))
@@ -1201,6 +1230,8 @@ class DistributedRunner:
                         payload = out._value
             return payload, holder.get("buffers", {})
 
+        # the name jax.monitoring reports what it builds under
+        run.__name__ = "eval_step" if with_loss else "predict_step"
         return jax.jit(run, donate_argnums=(2,))  # lint: allow(donation-safety): eval forward never enters the explicit-dp shard_map collectives — the donated buffers alias a plain SPMD program only, outside the DESIGN-DCN.md corruption mode
 
     def _eval_values(self):

@@ -12,6 +12,9 @@ a live system:
   histograms whose hot-path instruments accept lazy device scalars
   and defer the device→host sync to scrape time.
 - :mod:`.export`  — JSON snapshot + Prometheus text dump.
+- :mod:`.host_events` — what jax traces, lowers, compiles and reads
+  from its cache, by function, and the collector's pauses: always-on
+  counters, and spans beside the program's own.
 
 Quickstart::
 
@@ -36,10 +39,12 @@ from . import export  # noqa: F401
 from . import events  # noqa: F401
 from . import aggregate  # noqa: F401
 from . import http  # noqa: F401
+from . import host_events  # noqa: F401
 from .metrics import registry  # noqa: F401
 
 __all__ = ["trace", "metrics", "export", "events", "aggregate",
-           "http", "registry", "scrape", "scrape_prometheus"]
+           "http", "host_events", "registry", "scrape",
+           "scrape_prometheus"]
 
 
 def scrape(materialize: bool = True):
@@ -64,6 +69,10 @@ if _env_knobs.get_bool("PADDLE_TPU_TRACE"):
     # nonpositive values (unset, 0, or e.g. -1) keep the default ring
     trace.enable(capacity=_cap if _cap > 0 else None)
     del _cap
+
+# what jax builds and the collector's pauses: counted from here on,
+# always (host_events.py: nothing on a steady step)
+host_events.install()
 
 # PADDLE_TPU_METRICS_PORT=<base> arms the per-process HTTP scrape
 # endpoint the same way (DESIGN-OBSERVABILITY.md §Distributed plane):

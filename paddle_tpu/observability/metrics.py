@@ -83,7 +83,9 @@ class _Instrument:
         self.name = name
         self.help = help
         self.labels = labels
-        self._lock = threading.Lock()
+        # reentrant: the collector's hook (host_events.py) observes from
+        # inside whatever its thread was doing, this class included
+        self._lock = threading.RLock()
         # deferred (lazy device) values, materialized at scrape
         self._pending: List[Any] = []
         self.pending_dropped = 0
@@ -303,7 +305,7 @@ class MetricsRegistry:
     kind raises — a name means one thing process-wide."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()     # see _Instrument.__init__
         self._instruments: Dict[Tuple[str, Tuple], _Instrument] = {}
 
     @staticmethod

@@ -9,15 +9,26 @@ lifecycles, checkpoint IO and user ``RecordEvent`` annotations all
 record into one process-wide monotonic-clock ring buffer, so one
 export answers "where did this step/request spend its time".
 
+The one rule (PR 36): **a program span is a ring record when armed
+and a profiler annotation always.**  ``span(name, args)`` opens a
+``jax.profiler.TraceAnnotation`` of the same name, so every span of
+the program (``mesh.*``, ``pp.*``, ``dispatch.group``, serving,
+checkpoint IO, ``host.gc``, user ``RecordEvent``s) stands in any
+``jax.profiler`` trace on the profiler's clock, beside the device's
+planes, with no arming and no second system.  The ring is the
+operator's export (``PADDLE_TPU_TRACE=1``); the annotation is what a
+device trace is read against.
+
 Design constraints (the fold=8 microbench is the referee):
 
-- **~zero cost when disabled.**  ``span(name)`` returns a shared
-  no-op singleton without allocating; the only disabled-path work is
-  one global check.  Arm with ``PADDLE_TPU_TRACE=1`` (read when
-  ``paddle_tpu.observability`` imports) or :func:`enable`.
-- **No host↔device syncs.**  The recorder touches ``time`` and a
-  deque — never a device value.  ``scripts/check_host_sync.py``
-  guards this module like the hot loops it instruments.
+- **~zero cost when disabled.**  ``span(name)`` returns the bare
+  annotation: a flag test in C++ while no profiler session runs, no
+  ring record, nothing kept.  Arm the ring with ``PADDLE_TPU_TRACE=1``
+  (read when ``paddle_tpu.observability`` imports) or :func:`enable`.
+- **No host↔device syncs.**  The recorder touches ``time``, a deque
+  and the profiler's host-side annotation — never a device value.
+  ``scripts/check_host_sync.py`` guards this module like the hot
+  loops it instruments.
 - **Bounded memory.**  Events land in a ``deque(maxlen=capacity)``
   ring (default 64K events, ``PADDLE_TPU_TRACE_CAPACITY``): a
   week-long serving process keeps the most recent window instead of
@@ -46,8 +57,10 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 __all__ = [
-    "enable", "disable", "enabled", "span", "instant", "counter",
+    "enable", "disable", "enabled", "span", "instant",
     "add_span", "live_spans", "events", "clear", "to_chrome_trace",
     "dump_chrome_trace", "summary", "set_track_name",
 ]
@@ -74,19 +87,22 @@ _lock = threading.Lock()
 # -- record shapes ----------------------------------------------------------
 # ("X", name, tid, t0_ns, dur_ns, args)     complete span
 # ("i", name, tid, t_ns, None, args)        instant event
-# ("C", name, tid, t_ns, value, None)       counter sample
 
 
 class _Span:
-    """A live span: records on ``__exit__``.  Only allocated while
-    tracing is enabled — the disabled path returns :data:`_NULL_SPAN`.
+    """A live span: records on ``__exit__``, with the profiler's
+    annotation of the same name open beside it.  Only allocated while
+    tracing is enabled — the disabled path returns the bare annotation.
     """
 
-    __slots__ = ("_name", "_args", "_tid", "_t0", "_stack", "_entry")
+    __slots__ = ("_name", "_args", "_tid", "_t0", "_stack", "_entry",
+                 "_annotation")
 
     def __init__(self, name: str, args):
         self._name = name
         self._args = args
+        self._annotation = _Annotation(name, **args) if args \
+            else _Annotation(name)
         self._tid = threading.get_ident()
         stack = _live.get(self._tid)
         if stack is None:
@@ -97,9 +113,11 @@ class _Span:
         stack.append(self._entry)
 
     def __enter__(self):
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(None, None, None)
         t1 = time.monotonic_ns()
         stack = self._stack
         if stack and stack[-1] is self._entry:
@@ -117,32 +135,20 @@ class _Span:
         return False
 
 
-class _NullSpan:
-    """Shared disabled-mode span: entering/exiting allocates nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 # -- recording API ----------------------------------------------------------
 
 
 def span(name: str, args: Optional[Dict[str, Any]] = None):
-    """Context manager recording one complete span.  When tracing is
-    disabled this returns a shared no-op object — the hot loops call
-    it unconditionally and pay only the enabled check.  ``args``
-    (optional dict) rides into the Chrome trace event; hot sites that
-    build an args dict should do so per *dispatch*, not per step."""
+    """Context manager of one complete span: a profiler annotation
+    always, a ring record too when armed.  Disarmed this returns the
+    bare ``TraceAnnotation`` — the hot loops call it unconditionally
+    and pay a flag test in C++ while no profiler session runs.
+    ``args`` (optional dict of plain values) rides into the Chrome
+    trace event and into the annotation's stats (``step=12``); hot
+    sites that build an args dict should do so per *dispatch*, not
+    per step."""
     if not _enabled:
-        return _NULL_SPAN
+        return _Annotation(name, **args) if args else _Annotation(name)
     return _Span(name, args)
 
 
@@ -154,16 +160,6 @@ def instant(name: str, args: Optional[Dict[str, Any]] = None):
                   time.monotonic_ns(), None, args))
 
 
-def counter(name: str, value: float):
-    """Timeline counter sample (Chrome ``C`` event) — e.g. queue depth
-    over time.  For scrape-able process metrics use the metrics
-    registry instead; this feeds the *timeline* view."""
-    if not _enabled:
-        return
-    _ring.append(("C", name, threading.get_ident(),
-                  time.monotonic_ns(), float(value), None))
-
-
 def add_span(name: str, t0_s: float, t1_s: float,
              tid: Optional[int] = None,
              args: Optional[Dict[str, Any]] = None):
@@ -172,7 +168,9 @@ def add_span(name: str, t0_s: float, t1_s: float,
     queued→prefill→decode lifecycle from its ``RequestStats``
     milestones at finalize time, on a synthetic per-slot track
     (``tid``).  Same clock as ``span()``, so both interleave correctly
-    on one timeline."""
+    on one timeline.  The ring only: a span that is already over
+    cannot be bridged to the profiler, whose annotations are taken as
+    they open and close."""
     if not _enabled or t1_s < t0_s:
         return
     _ring.append(("X", name,
@@ -260,14 +258,11 @@ def to_chrome_trace() -> Dict[str, Any]:
             ev["dur"] = extra / 1e3
             if args:
                 ev["args"] = args
-        elif kind == "i":
+        else:                                     # "i"
             ev["ph"] = "i"
             ev["s"] = "t"
             if args:
                 ev["args"] = args
-        else:                                     # "C"
-            ev["ph"] = "C"
-            ev["args"] = {"value": extra}
         trace_events.append(ev)
     thread_names = {t.ident: t.name for t in threading.enumerate()}
     with _lock:

@@ -215,34 +215,29 @@ def host_span_stats():
 
 
 class RecordEvent:
-    """Host-side trace annotation: spans go to the unified
-    observability recorder (the ONE timeline, when armed), the native
-    host tracer (when enabled), and jax.profiler.TraceAnnotation
-    (XPlane correlation)."""
+    """Host-side trace annotation: one span of the unified
+    observability recorder, which is a ``jax.profiler`` annotation
+    always (XPlane correlation) and a record of the ONE timeline when
+    armed (``observability/trace.py``), and the native host tracer
+    when enabled."""
 
     def __init__(self, name: str, event_type=None):
         self._name = name
-        self._ctx = None
         self._native = False
         self._uspan = None
 
     def begin(self):
         # begin() twice without end() would overwrite (and leak) the
-        # previous span/annotation window — close it first
-        if self._uspan is not None or self._ctx is not None:
+        # previous span — close it first
+        if self._uspan is not None:
             self.end()
         self._uspan = _obs_trace.span(self._name)
         self._uspan.__enter__()
         if _host_tracer.enabled():
             _host_tracer.begin(self._name)
             self._native = True
-        self._ctx = jax.profiler.TraceAnnotation(self._name)
-        self._ctx.__enter__()
 
     def end(self):
-        if self._ctx is not None:
-            self._ctx.__exit__(None, None, None)
-            self._ctx = None
         if self._native:
             _host_tracer.end()
             self._native = False

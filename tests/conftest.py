@@ -196,12 +196,34 @@ def _reset_state():
 _PINNED_CELL_COUNT = (
     "test_granite_cell.py::"
     "test_the_cell_declares_its_metrics_and_reads_the_block_metrics")
+# The test that stands in for it pins the benchmark in turn:
+# ``test_nemotron_cell.py:178`` holds ``BENCHMARK.json`` less PR 34's
+# entries to (4, 5, 4, 29) configurations, cells, end-to-end and
+# per-layer metrics, and holds that nothing stands after PR 34's
+# entries.  PR 36 (``tracing``) appends eight per-layer metrics of the
+# host half, may edit no file under the benchmark's ``paths`` either,
+# and takes the same way, openly: that test is expected to fail from the
+# first entry after PR 34's on, and ``tests/benchmarks/test_host_half.py``
+# sees it fail as it stands and runs its whole body, the Granite test's
+# inside it, on the benchmark less PR 36's eight.  ROADMAP D16's
+# ``benchmark`` PR deletes both pins and this hook.
+_PINNED_SIZE = (
+    "test_nemotron_cell.py::"
+    "test_the_benchmark_gained_entries_at_the_end_and_kept_the_rest")
+_EXPECTED_TO_FAIL = {
+    _PINNED_CELL_COUNT:
+        "pins len(workloads) == 5; the benchmark has six cells since "
+        "PR 34, which may not edit the file",
+    _PINNED_SIZE:
+        "pins the benchmark less PR 34's entries at 29 per-layer metrics "
+        "with nothing after them; PR 36 appended eight and may not edit "
+        "the file",
+}
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.endswith(_PINNED_CELL_COUNT):
-            item.add_marker(pytest.mark.xfail(
-                reason="pins len(workloads) == 5; the benchmark has six "
-                       "cells since PR 34, which may not edit the file",
-                raises=AssertionError, strict=True))
+        for pinned, reason in _EXPECTED_TO_FAIL.items():
+            if item.nodeid.endswith(pinned):
+                item.add_marker(pytest.mark.xfail(
+                    reason=reason, raises=AssertionError, strict=True))

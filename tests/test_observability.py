@@ -9,6 +9,7 @@ returns dispatch, serving and checkpoint metrics from the same
 process-wide registry.
 """
 
+import gc
 import json
 import os
 import threading
@@ -26,10 +27,14 @@ from paddle_tpu.observability import trace
 @pytest.fixture(autouse=True)
 def _trace_reset():
     """Tracing is process-global: every test starts and ends disarmed
-    with an empty ring so suites can run in any order."""
+    with an empty ring so suites can run in any order.  The collector
+    is held still meanwhile: armed, its collections are spans of the
+    ring too (``host.gc``), and tests here pin the ring's contents."""
     trace.disable()
     trace.clear()
+    gc.disable()
     yield
+    gc.enable()
     trace.disable()
     trace.clear()
 
@@ -42,7 +47,7 @@ def _validate_chrome(obj):
         obj.get("traceEvents"), list)
     for ev in obj["traceEvents"]:
         assert isinstance(ev["name"], str)
-        assert ev["ph"] in ("X", "i", "C", "M")
+        assert ev["ph"] in ("X", "i", "M")
         assert isinstance(ev["pid"], int)
         assert isinstance(ev["tid"], int)
         if ev["ph"] == "X":
@@ -52,8 +57,6 @@ def _validate_chrome(obj):
         elif ev["ph"] == "i":
             assert isinstance(ev["ts"], (int, float))
             assert ev["s"] in ("t", "p", "g")
-        elif ev["ph"] == "C":
-            assert isinstance(ev["args"]["value"], (int, float))
         else:                                   # M metadata
             assert ev["name"] == "thread_name"
             assert isinstance(ev["args"]["name"], str)
@@ -64,19 +67,20 @@ def _validate_chrome(obj):
 # span recorder
 # ---------------------------------------------------------------------------
 def test_disabled_mode_zero_allocation_pin():
-    """THE overhead pin: when tracing is off, span() returns one
-    shared singleton — no object allocation, nothing recorded — so
-    the unconditional call sites in the hot loops cost one global
-    check."""
+    """THE overhead pin: when tracing is off, span() returns the bare
+    profiler annotation — no recorder object, nothing recorded, no
+    live stack — so the unconditional call sites in the hot loops cost
+    a flag test in C++ while no profiler session runs."""
+    import jax
     assert not trace.enabled()
     s1 = trace.span("dispatch.group")
     s2 = trace.span("anything", args={"k": 1})
-    assert s1 is s2                 # the shared no-op singleton
+    for s in (s1, s2):              # the annotation and nothing of ours
+        assert type(s) is jax.profiler.TraceAnnotation
     with s1:
         with trace.span("nested"):
             pass
     trace.instant("marker")
-    trace.counter("depth", 3)
     assert trace.events() == []     # ring untouched
     assert trace.live_spans() == {}
 
@@ -138,7 +142,6 @@ def test_chrome_trace_json_validates(tmp_path):
     trace.enable()
     with trace.span("phase", args={"n": 3}):
         trace.instant("tick")
-        trace.counter("queue_depth", 2)
     trace.add_span("retro", 1.0, 1.5, tid=999, args={"id": "r0"})
     trace.set_track_name(999, "slot-lane")
     path = trace.dump_chrome_trace(str(tmp_path / "t.json"))
