@@ -8,10 +8,17 @@
 
 B and C come in ``groups`` groups, head h reading group ``h // (heads /
 groups)``, and the gated norm takes its mean square over each group's
-channels: one group is a norm over all of ``d_inner``.  It opens the
-sub-scopes ``ssm_proj``, ``ssm_conv``, ``ssm_scan`` and ``ssm_norm`` inside
-``attn`` and counts its scans (``ssm_scan_chunks_total{layer}``,
-``ssm_scan_state_bytes{layer}``) as they are traced.
+channels: one group is a norm over all of ``d_inner``.  The second line
+is one call, ``ssm.conv_silu_split``, which is told where xBC lies in
+the projection's result and hands x, B and C to the scan apart: on a TPU the
+Mosaic kernels of ``ops/ssm_conv_kernels.py``, elsewhere
+``ssm.causal_conv1d``, SiLU and a split (``ssm.conv_form`` says which,
+as ``ssm.scan_form`` does of the scan).  The mixer opens the sub-scopes
+``ssm_proj``, ``ssm_conv``, ``ssm_scan`` and ``ssm_norm`` inside ``attn``
+and counts its scans (``ssm_scan_chunks_total{layer}``,
+``ssm_scan_state_bytes{layer}``) as they are traced; the kernels count
+their own calls (``ssm_scan_kernel_visits_total{kind}``,
+``ssm_conv_kernel_calls_total{kind}``).
 """
 
 from __future__ import annotations
@@ -40,27 +47,31 @@ def _silu_gate(a, b):
 
 
 @jax.checkpoint
-def _conv_silu(x, weight, bias):
-    out = ssm.causal_conv1d(x, weight, bias).astype(jnp.float32)
-    return (out * jax.nn.sigmoid(out)).astype(x.dtype)
-
-
-@jax.checkpoint
 def _step_sizes(dt, dt_bias):
     return jax.nn.softplus(dt.astype(jnp.float32)
                            + dt_bias.astype(jnp.float32))
 
 
+# jitted: the groups of a norm, and every norm of a model, share one trace
+# and one lowering of it (a step of four blocks of eight groups traced
+# and lowered 680 equations more without)
+_rms_of_a_group = jax.jit(_rms)
+
+
 def _rms_by_group(x, weight, eps, groups: int):
     """:func:`_rms` with the mean square over each of ``groups`` groups of
-    channels; one group is ``_rms`` itself."""
+    channels; one group is ``_rms`` itself.  A group at a time, each a
+    block of whole columns, which the chip takes as it lies: rows-major,
+    as the mixer's arrays lie since its convolution is a kernel, it lays
+    a float32 ``[S, groups, n]`` out anew, a copy each way (8.3 ms a step
+    in the Nemotron cell: ``PERF.md`` section 6, PR 37)."""
     if groups == 1:
         return _rms(x, weight, eps)
-    by_group = x.reshape(x.shape[:-1] + (groups, -1)).astype(jnp.float32)
-    out = by_group * jax.lax.rsqrt(
-        jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
-    return (out.reshape(x.shape) * weight.astype(jnp.float32)).astype(
-        x.dtype)
+    size = x.shape[-1] // groups
+    return jnp.concatenate(
+        [_rms_of_a_group(x[..., g * size:(g + 1) * size],
+                         weight[g * size:(g + 1) * size], eps)
+         for g in range(groups)], axis=-1)
 
 
 def _count_scan(layer: int, seq: int, calls: int, heads: int, width: int,
@@ -130,12 +141,13 @@ class Mamba2Mixer(nn.Layer):
                       norm_w, w_out):
         seq, inner = x.shape[0], self.d_inner
         with jax.named_scope("ssm_proj"):
+            proj = x @ w_in
             z, xbc, dt = jnp.split(
-                x @ w_in, (inner, inner + self.conv_dim), axis=-1)
+                proj, (inner, inner + self.conv_dim), axis=-1)
         with jax.named_scope("ssm_conv"):
-            xbc = _conv_silu(xbc, conv_w, conv_b)
-            xs, b, cc = jnp.split(
-                xbc, (inner, inner + self.groups * self.state), -1)
+            xs, b, cc = ssm.conv_silu_split(
+                xbc, conv_w, conv_b, inner, self.groups, self.state,
+                lies_in=(proj, inner))
         with jax.named_scope("ssm_scan"):
             # [seq, heads, width] is a view of the projection's own
             # [seq, d_inner]: the kernels read and write it as it lies

@@ -1,12 +1,15 @@
 """State-space layers: the selective scan of Mamba-2 in its chunked
 (state-space duality) form, and the causal depthwise convolution in
-front of it; a sequence at a time.  The convolution is plain XLA
-operations.  The scan has two forms and :func:`scan_form` names the one
-that runs, from platform and shape: on a TPU, for shapes that fill lane
-groups and whole chunks, the Mosaic kernels of ``ops/ssm_kernels.py``
-(a chunk's matrices and the running state in VMEM only); everywhere
-else (the CPU, odd shapes) the plain XLA operations below, which are
-also what the kernels are checked against.
+front of it; a sequence at a time.  Each has two forms, and
+:func:`scan_form` and :func:`conv_form` name the one that runs, from
+platform and shape: on a TPU, for shapes that fill lane groups and whole
+chunks or tiles, the Mosaic kernels of ``ops/ssm_kernels.py`` (a chunk's
+matrices and the running state in VMEM only) and of
+``ops/ssm_conv_kernels.py`` (the taps, the bias, the SiLU and the split
+into x, B and C in one call, read where the in-projection wrote them;
+the backward pass's shifted terms in VMEM only); everywhere else (the
+CPU, odd shapes) the plain XLA operations below, which are also what the
+kernels are checked against.
 
 The recurrence, for head h with state ``H [P, N]`` (P the head's width,
 N the state's), ``a_t = dt_t A`` (A < 0):
@@ -38,7 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import pallas_ops, ssm_kernels
+from . import pallas_ops, ssm_conv_kernels, ssm_kernels
 
 _LANES = 128
 # the XLA form's: chunks whose [heads, Q, Q] matrices are alive together,
@@ -62,6 +65,52 @@ def causal_conv1d(x, weight, bias=None):
     if bias is not None:
         out = out + bias.astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+@jax.checkpoint
+def _conv_silu(x, weight, bias):
+    """Keeps its input and makes its float32 insides again in the
+    backward pass."""
+    out = causal_conv1d(x, weight, bias).astype(jnp.float32)
+    return (out * jax.nn.sigmoid(out)).astype(x.dtype)
+
+
+def conv_form(seq: int, channels: int, inner: int, groups: int, state: int,
+              width: int) -> str:
+    """Which form of the mixer's convolution runs, from platform and
+    shape: ``"kernels"`` (``ops/ssm_conv_kernels.py``) on a TPU (or under
+    the interpreter) where x and B and C are whole lane groups, ``inner``
+    a whole number of B's blocks, the sequence a whole number of sublane
+    tiles, the taps reach no further back than the eight rows a visit is
+    given, and a visit's blocks fit the VMEM the call asks for; ``"xla"``
+    (:func:`causal_conv1d`, SiLU, ``jnp.split``) everywhere else."""
+    bc = groups * state
+    fits = (channels == inner + 2 * bc and inner % _LANES == 0
+            and bc % _LANES == 0 and inner % bc == 0 and 1 <= width <= 9
+            and ssm_conv_kernels.fits_vmem(seq, channels, width, 4))
+    return "kernels" if fits and pallas_ops._kernels_enabled() else "xla"
+
+
+def conv_silu_split(xbc, weight, bias, inner: int, groups: int, state: int,
+                    lies_in=None):
+    """x ``[S, inner]``, B and C ``[S, groups * state]`` of one sequence:
+    ``silu(conv(xbc) + bias)`` split, ``xbc [S, C]``, ``weight [C, W]``,
+    ``bias [C]``.  ``lies_in = (proj, start)`` says that xbc is the
+    columns ``[start, start + C)`` of ``proj [S, P]`` (the in-projection's
+    result): the kernels then read them where they lie and the slice is
+    read by nothing, where ``start`` is a whole number of x's blocks.
+    The backward pass of either form is given its inputs and makes the
+    convolution again."""
+    channels, bc = weight.shape[0], groups * state
+    if conv_form(xbc.shape[0], channels, inner, groups, state,
+                 weight.shape[1]) == "kernels":
+        source, start = lies_in or (xbc, 0)
+        if start % inner:
+            source, start = xbc, 0
+        return ssm_conv_kernels.conv_silu_split(xbc, weight, bias, source,
+                                                start, inner, bc)
+    return tuple(jnp.split(_conv_silu(xbc, weight, bias),
+                           (inner, inner + bc), -1))
 
 
 # --------------------------------------------------------------------------

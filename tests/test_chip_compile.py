@@ -321,6 +321,41 @@ def _scan_bwd_8_groups(topo, monkeypatch):
     return grads, args + (args[0],)
 
 
+def _conv_operands(topo, monkeypatch, groups):
+    """The mixers' convolution at s8192: the in-projection's result ``[8192,
+    2 * 4096 + 2 * groups * 128 + 64]``, four taps over its 4096 + 2 *
+    groups * 128 channels of xBC."""
+    from paddle_tpu.ops import ssm
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    seq, inner, bc = 8192, 4096, groups * 128
+    channels = inner + 2 * bc
+    assert ssm.conv_form(seq, channels, inner, groups, 128, 4) == "kernels"
+
+    def split(proj, weight, bias):
+        return ssm.conv_silu_split(
+            proj[:, inner:inner + channels], weight, bias, inner, groups,
+            128, lies_in=(proj, inner))
+
+    return split, (spec((seq, inner + channels + 64)),
+                   spec((channels, 4), jnp.float32),
+                   spec((channels,), jnp.float32)), (
+        spec((seq, inner)), spec((seq, bc)), spec((seq, bc)))
+
+
+def _conv_fwd(groups):
+    def build(topo, monkeypatch):
+        return _conv_operands(topo, monkeypatch, groups)[:2]
+    return build
+
+
+def _conv_bwd(groups):
+    def build(topo, monkeypatch):
+        split, args, dys = _conv_operands(topo, monkeypatch, groups)
+        return (lambda p, w, b, *d: jax.vjp(split, p, w, b)[1](d)), args + dys
+    return build
+
+
 def _flash_32_on_2(topo, monkeypatch):
     """The same cell's attention block: 32 query heads on 2 key/value
     heads of width 128 at s8192, forward and backward, through the public
@@ -389,6 +424,10 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_scan_bwd_8_groups, 2, None,
                  id="ssd_scan_bwd_s8192_8_groups_chunk_128"),
     pytest.param(_flash_32_on_2, 3, None, id="flash_32_on_2_heads_of_128"),
+    pytest.param(_conv_fwd(1), 1, None, id="ssm_conv_fwd_s8192_4352_channels"),
+    pytest.param(_conv_bwd(1), 1, None, id="ssm_conv_bwd_s8192_4352_channels"),
+    pytest.param(_conv_fwd(8), 1, None, id="ssm_conv_fwd_s8192_6144_channels"),
+    pytest.param(_conv_bwd(8), 1, None, id="ssm_conv_bwd_s8192_6144_channels"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
              r"\"#tpu\.dot_dimension_numbers",
@@ -406,14 +445,27 @@ def test_compiles_for_v5e(topo, monkeypatch, build, n_calls, refused):
     assert text.count("tpu_custom_call") >= n_calls
 
 
+def _shifted_terms_in_hbm(text: str, channels: int):
+    """The fusions under ``ssm_conv`` whose result holds four ``bf16[8192,
+    channels]`` arrays: the XLA form's backward pass wrote the four
+    shifted, weighted copies of the convolution's gradient so, one
+    fusion a Mamba-2 layer, and read them back to add them (ISSUE 37's
+    compile rehearsal; nine in Granite's step at PR 36)."""
+    shape = f"bf16[8192,{channels}]"
+    return [line for line in text.splitlines()
+            if "ssm_conv" in line and " fusion(" in line
+            and line.split(" fusion(")[0].count(shape) >= 4]
+
+
 def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
     """The whole training step of the cell granite4h-micro-stage0-s8192
     (ten layers at published widths, b1 x s8192, bf16 O2 with float32
     master weights, every layer recomputed), as ``DistributedRunner``
     builds it, compiled for one described v5e chip: what it needs on the
     device stays under the configuration's limit, and the attention
-    layer's kernels (forward, the forward again, dq, dkv) and the nine
-    scans' (as many a layer) are in it.
+    layer's kernels (forward, the forward again, dq, dkv), the nine
+    scans' and the nine convolutions' (three each a layer) are in it,
+    and the convolution's four shifted gradient terms are not.
     The parameters are zeros placeholders (``LazyGuard``) and nothing is
     put on a device: the step is lowered on shapes."""
     import numpy as np
@@ -456,10 +508,14 @@ def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
     assert step <= recorded["step"] * 1.02
     print(f"compiled step: {step} bytes a device")
     kinds = config["layer_types"]
+    text = compiled.as_text()
     # the attention layer: forward, the forward again, dq, dkv; a Mamba
-    # layer's scan: forward, the forward again, the walk back
-    assert compiled.as_text().count("tpu_custom_call") == \
-        4 * kinds.count("attention") + 3 * kinds.count("mamba") == 31
+    # layer's scan: forward, the forward again, the walk back; and, new
+    # with PR 37, its convolution (taps, bias, SiLU and split): forward,
+    # the forward again, the walk back
+    assert text.count("tpu_custom_call") == \
+        4 * kinds.count("attention") + (3 + 3) * kinds.count("mamba") == 58
+    assert _shifted_terms_in_hbm(text, 4096 + 2 * 128) == []
 
 
 def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
@@ -469,9 +525,9 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     configuration names recomputed), as ``DistributedRunner`` builds it,
     compiled for one described v5e chip: what it needs on the device stays
     under the configuration's limit, and its own kernels are in it: a
-    Mamba-2 block's scan forward and the walk back, the attention block's
-    forward, dq and dkv, the forward once more where a block is
-    recomputed; the rest are the experts' grouped products, the kernels
+    Mamba-2 block's scan and its convolution, each forward and the walk
+    back, the attention block's forward, dq and dkv, the forward once more
+    where a block is recomputed; the rest are the experts' grouped products, the kernels
     of ``ops/grouped_matmul.py`` by their names: fourteen an expert
     block, six of them the first window's (two products forward, their
     four gradients) and eight the overflow branch's."""
@@ -519,19 +575,27 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     assert step < config["step_bytes_limit"] == 15.6e9
     print(f"compiled step: {step} bytes a device")
     text = compiled.as_text()
-    own = driver.kernel_sites(family.kinds(config), set(config["recompute"]))
+    kinds = family.kinds(config)
+    own = driver.kernel_sites(kinds, set(config["recompute"]))
     assert own == 4 * 2 + 3 + len(config["recompute"])
+    # a Mamba-2 block's convolution: forward and the walk back, the
+    # forward once more where the block is recomputed (the driver's count
+    # is the benchmark's and does not know them)
+    convolutions = sum(2 + (i in config["recompute"])
+                       for i, kind in enumerate(kinds) if kind == "mamba")
+    assert convolutions == 10
     sites = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     products = [line for line in sites if "grouped_dot" in line]
     print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
           "experts' grouped products")
-    blocks = family.kinds(config).count("moe")
+    blocks = kinds.count("moe")
     assert len(products) == 14 * blocks
     # as the step was traced, an expert block: two products forward, in
     # the overflow branch and there again for its backward pass; their
     # four gradients in the first window and in the branch
     assert [now - was for now, was in zip(product_calls(), before)] == [
         6 * blocks, 4 * blocks, 4 * blocks]
-    assert len(sites) - len(products) == own
+    assert len(sites) - len(products) == own + convolutions
     assert "ragged-dot" not in text
+    assert _shifted_terms_in_hbm(text, 4096 + 2 * 8 * 128) == []

@@ -5,7 +5,10 @@ values and every gradient, over one chunk, many, a chunk the length of
 the sequence, one group of heads and several; the causal convolution
 against its definition; what a sequence that is no whole number of
 chunks gets (it is refused); and that the backward pass is given the
-inputs and nothing else.
+inputs and nothing else.  Then the Mosaic kernels of both, interpreted:
+the scan's against the XLA form and the recurrence, the convolution's
+(taps, bias, SiLU and split in one call) against ``causal_conv1d`` +
+SiLU + split, alone and through ``Mamba2Mixer``.
 """
 
 import os
@@ -325,3 +328,242 @@ def test_the_kernels_backward_pass_is_given_inputs_and_states(monkeypatch):
     assert starts.shape == (2 * 128, 2 * 64) and starts.dtype == jnp.float32
     assert not np.asarray(starts[:128]).any()       # the first: nothing
     np.testing.assert_array_equal(y, ssm_kernels.scan(*inputs, 128))
+
+
+# --------------------------------------------------------------------------
+# the convolution's Mosaic kernels (ops/ssm_conv_kernels.py), interpreted
+# --------------------------------------------------------------------------
+def _conv_calls(kind):
+    from paddle_tpu.observability import metrics
+    return metrics.registry().counter(
+        "ssm_conv_kernel_calls_total", labels={"kind": kind}).collect()
+
+
+def conv_inputs(seq, inner, bc, start, tail, dtype=jnp.float32, seed=5):
+    """The in-projection's result ``[S, start + C + tail]``, the taps
+    ``[C, 4]``, the bias, and a weight for each of x, B and C."""
+    rng = np.random.default_rng(seed)
+    channels = inner + 2 * bc
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)  # noqa
+    return (normal(seq, start + channels + tail),
+            jnp.asarray(rng.uniform(-.5, .5, (channels, 4)), jnp.float32),
+            jnp.asarray(rng.uniform(-.5, .5, (channels,)), jnp.float32),
+            (normal(seq, inner), normal(seq, bc), normal(seq, bc)))
+
+
+def _conv_of_columns(proj, start, weight, bias, inner, groups, state):
+    """As the mixer calls it: xBC a slice of the projection's result, and
+    where it lies there."""
+    xbc = proj[:, start:start + weight.shape[0]]
+    return ssm.conv_silu_split(xbc, weight, bias, inner, groups, state,
+                               lies_in=(proj, start))
+
+
+def _conv_value_and_grads(proj, weight, bias, ws, start, inner, groups,
+                          state):
+    def weighted(p, w, b):
+        outs = _conv_of_columns(p, start, w, b, inner, groups, state)
+        return sum((o * g).astype(jnp.float32).sum()
+                   for o, g in zip(outs, ws))
+    return jax.value_and_grad(weighted, argnums=(0, 1, 2))(proj, weight,
+                                                           bias)
+
+
+@pytest.mark.parametrize("seq, inner, groups, state, start, dtype", [
+    pytest.param(512, 4096, 1, 128, 4096, jnp.float32,
+                 id="granites_widths-two_tiles"),
+    pytest.param(256, 4096, 8, 128, 4096, jnp.float32,
+                 id="nemotrons_widths"),
+    pytest.param(768, 256, 2, 128, 256, jnp.float32,
+                 id="three_tiles-a_halo_over_each_edge"),
+    pytest.param(48, 256, 1, 128, 256, jnp.float32,
+                 id="three_tiles_of_sixteen_rows"),
+    pytest.param(512, 256, 1, 128, 100, jnp.float32,
+                 id="an_offset_of_no_whole_block-a_slice_in"),
+    pytest.param(512, 256, 1, 256, 0, jnp.float32,
+                 id="b_as_wide_as_x-no_offset"),
+    pytest.param(512, 256, 1, 128, 256, jnp.bfloat16, id="bf16"),
+])
+def test_conv_kernels_are_causal_conv1d_silu_and_split(
+        monkeypatch, seq, inner, groups, state, start, dtype):
+    """x, B and C and the three gradients (of xBC where it lies in the
+    projection's result, of the taps, of the bias) through the kernels,
+    against the XLA form; the first three rows, which read zeros before
+    the sequence, against the definition."""
+    bc = groups * state
+    channels = inner + 2 * bc
+    proj, weight, bias, ws = conv_inputs(seq, inner, bc, start, 64, dtype)
+    args = (proj, weight, bias, ws, start, inner, groups, state)
+    assert ssm.conv_form(seq, channels, inner, groups, state, 4) == "xla"
+    want = _conv_value_and_grads(*args)
+    want_outs = _conv_of_columns(proj, start, weight, bias, inner, groups,
+                                 state)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.conv_form(seq, channels, inner, groups, state,
+                         4) == "kernels"
+    before = _conv_calls("fwd"), _conv_calls("bwd")
+    outs = _conv_of_columns(proj, start, weight, bias, inner, groups, state)
+    got = _conv_value_and_grads(*args)
+    assert (_conv_calls("fwd") - before[0],
+            _conv_calls("bwd") - before[1]) == (2, 1)
+    # bf16: the XLA form rounds the convolution before its SiLU and the
+    # kernels do not: a rounding apart
+    close = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
+        rtol=1e-5, atol=1e-5)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))           # noqa: E731
+    for a, b, width in zip(outs, want_outs, (inner, bc, bc)):
+        assert a.shape == (seq, width) and a.dtype == b.dtype == dtype
+        np.testing.assert_allclose(f32(a), f32(b), **close)
+    xbc = f32(proj)[:, start:start + channels]
+    first = np.asarray(bias) + sum(
+        np.asarray(weight)[:, k] * np.pad(xbc, ((3, 0), (0, 0)))[k:k + 3]
+        for k in range(4))
+    np.testing.assert_allclose(
+        np.concatenate([f32(o)[:3] for o in outs], 1),
+        first / (1 + np.exp(-first)), **close)
+    if dtype != jnp.bfloat16:       # a sum of 4e5 rounded terms otherwise
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-4)
+    for name, a, b in zip(("d_proj", "d_weight", "d_bias"), got[1],
+                          want[1]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        scale = float(jnp.abs(f32(b)).max())
+        np.testing.assert_allclose(
+            f32(a), f32(b), rtol=1e-3,
+            atol=(2e-2 if dtype == jnp.bfloat16 else 2e-5) * scale,
+            err_msg=name)
+    # nothing outside xBC's columns is given a gradient
+    d_proj = f32(got[1][0])
+    assert not d_proj[:, :start].any()
+    assert not d_proj[:, start + channels:].any()
+
+
+@pytest.mark.parametrize("interpreted, shape, form", [
+    # (seq, channels, inner, groups, state, width)
+    pytest.param(True, (8192, 4352, 4096, 1, 128, 4), "kernels",
+                 id="granites_shape"),
+    pytest.param(True, (8192, 6144, 4096, 8, 128, 4), "kernels",
+                 id="nemotrons_shape"),
+    pytest.param(False, (8192, 4352, 4096, 1, 128, 4), "xla",
+                 id="granites_shape_on_the_cpu"),
+    pytest.param(True, (8192, 4096 + 2 * 96, 4096, 1, 96, 4), "xla",
+                 id="b_and_c_no_whole_lane_group"),
+    pytest.param(True, (8192, 4032 + 256, 4032, 1, 128, 4), "xla",
+                 id="x_no_whole_lane_group"),
+    pytest.param(True, (8192, 384 + 512, 384, 2, 128, 4), "xla",
+                 id="x_no_whole_number_of_bs_blocks"),
+    pytest.param(True, (8192, 4352 + 128, 4096, 1, 128, 4), "xla",
+                 id="channels_that_are_not_x_b_and_c"),
+    pytest.param(True, (8192 + 8, 4352, 4096, 1, 128, 4), "xla",
+                 id="no_whole_number_of_sublane_tiles"),
+    pytest.param(True, (8192, 4352, 4096, 1, 128, 10), "xla",
+                 id="taps_further_back_than_eight_rows"),
+    pytest.param(True, (8192, 8 * 4352, 8 * 4096, 8, 128, 4), "xla",
+                 id="a_tile_of_all_channels_fills_vmem"),
+    pytest.param(True, (64, 80, 64, 1, 8, 4), "xla", id="the_tests_sizes"),
+])
+def test_conv_form_reads_platform_and_shape(monkeypatch, interpreted, shape,
+                                            form):
+    if interpreted:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.conv_form(*shape) == form
+
+
+def test_conv_kernel_calls_are_counted_as_they_are_traced(monkeypatch):
+    """``ssm_conv_kernel_calls_total{kind}``: a recomputed layer's trace
+    at the cell's shape reads two forward calls (the forward pass, and
+    the forward again for the backward pass) and one backward; the XLA
+    form adds 0."""
+    proj = jax.ShapeDtypeStruct((8192, 8512), jnp.bfloat16)
+    weight = jax.ShapeDtypeStruct((4352, 4), jnp.float32)
+    bias = jax.ShapeDtypeStruct((4352,), jnp.float32)
+
+    def traced():
+        @jax.checkpoint         # anew: a trace that is cached counts nothing
+        def layer(p, w, b):
+            return sum(o.astype(jnp.float32).sum() for o in
+                       _conv_of_columns(p, 4096, w, b, 4096, 1, 128))
+
+        before = _conv_calls("fwd"), _conv_calls("bwd")
+        jax.eval_shape(jax.grad(layer, argnums=(0, 1, 2)), proj, weight,
+                       bias)
+        return _conv_calls("fwd") - before[0], _conv_calls("bwd") - before[1]
+
+    assert traced() == (0, 0)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert traced() == (2, 1)
+
+
+def test_the_conv_kernels_backward_pass_is_given_its_inputs(monkeypatch):
+    """The projection's result, the taps and the bias: neither the slice
+    the gradient is taken by nor the convolution's own result is kept, as
+    the XLA form's ``jax.checkpoint`` keeps none."""
+    from paddle_tpu.ops import ssm_conv_kernels
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    proj, weight, bias, _ = conv_inputs(32, 128, 128, 128, 0)
+    outs, kept = ssm_conv_kernels._conv_fwd(proj[:, 128:], weight, bias,
+                                            proj, 128, 128, 128)
+    assert len(kept) == 3
+    for residual, given in zip(kept, (proj, weight, bias)):
+        assert residual is given
+    assert [o.shape for o in outs] == [(32, 128)] * 3
+
+
+def _through_the_mixer(mixer, data, w):
+    """The mixer's output and the gradients of a weighted sum of it by
+    the input and by every parameter."""
+    from paddle_tpu.nn import functional_call as F
+
+    def weighted(x, params):
+        out, _ = F.functional_call(mixer, params, F.buffer_dict(mixer),
+                                   (paddle_tpu.to_tensor(x),))
+        return (out._value * w).sum(), out._value
+
+    (_, out), grads = jax.value_and_grad(weighted, argnums=(0, 1),
+                                         has_aux=True)(
+        jnp.asarray(data), dict(F.param_dict(mixer)))
+    return out, grads[0], grads[1]
+
+
+def test_a_batch_of_two_sequences_through_the_mixer(monkeypatch):
+    """``Mamba2Mixer`` on ``[2, 256, 64]`` with the convolution's kernels
+    (and the scan's) against the same mixer's XLA forms: the output and
+    every gradient; under the XLA form the output is, to the bit, what
+    the mixer gave when it ran ``causal_conv1d`` + SiLU + split on the
+    slice itself."""
+    from paddle_tpu.models import mamba2
+    paddle_tpu.seed(11)
+    # d_inner 256, one group of 128 states: 512 channels under the taps
+    mixer = mamba2.Mamba2Mixer(64, 4, 64, 128, 1, 4, 128, 1e-5, 0.02, 0.02,
+                               0)
+    rng = np.random.default_rng(0)
+    data = rng.standard_normal((2, 256, 64)).astype(np.float32)
+    w = jnp.asarray(rng.standard_normal((2, 256, 64)), jnp.float32)
+    assert ssm.conv_form(256, 512, 256, 1, 128, 4) == "xla"
+    xla = _through_the_mixer(mixer, data, w)
+
+    def as_the_parent(xbc, weight, bias, inner, groups, state, lies_in):
+        return tuple(jnp.split(ssm._conv_silu(xbc, weight, bias),
+                               (inner, inner + groups * state), -1))
+
+    with monkeypatch.context() as m:
+        m.setattr(ssm, "conv_silu_split", as_the_parent)
+        parent = _through_the_mixer(mixer, data, w)
+    np.testing.assert_array_equal(xla[0], parent[0])
+    np.testing.assert_array_equal(xla[1], parent[1])
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.conv_form(256, 512, 256, 1, 128, 4) == "kernels"
+    before = _conv_calls("fwd"), _conv_calls("bwd")
+    got = _through_the_mixer(mixer, data, w)
+    # a call a sequence, forward and backward
+    assert (_conv_calls("fwd") - before[0],
+            _conv_calls("bwd") - before[1]) == (2, 2)
+    np.testing.assert_allclose(got[0], xla[0], rtol=1e-3,
+                               atol=1e-4 * float(jnp.abs(xla[0]).max()))
+    np.testing.assert_allclose(got[1], xla[1], rtol=1e-3,
+                               atol=1e-4 * float(jnp.abs(xla[1]).max()))
+    assert got[2].keys() == xla[2].keys() and len(got[2]) == 8
+    for name, b in xla[2].items():
+        np.testing.assert_allclose(got[2][name], b, rtol=1e-3,
+                                   atol=1e-4 * float(jnp.abs(b).max()),
+                                   err_msg=name)
