@@ -22,3 +22,6 @@ from .granite_hybrid import (  # noqa
 from .nemotron_h import (  # noqa
     NemotronHConfig, NemotronHModel, NemotronHForCausalLM,
     NemotronHPretrainingCriterion, nemotron_h_tiny)
+from .sambay import (  # noqa
+    SambaYConfig, SambaYModel, SambaYForCausalLM,
+    SambaYPretrainingCriterion, sambay_tiny)

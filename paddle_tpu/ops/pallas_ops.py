@@ -510,6 +510,15 @@ def _pallas_flash_bwd(q, k, v, out, lse, do, q_seg=None, k_seg=None, *,
 # Without a mask every tile is computed bare; with segment ids every
 # tile is visited and masked.  The grid-level ``pl.when`` still skips a
 # resident block that lies wholly above the diagonal.
+#
+# With a ``window`` (causal: a query sees the ``window`` keys up to its
+# own, ``0 <= t - s < window``) the band is all there is.  The grid's
+# last dimension then walks only the resident blocks the band of its
+# block touches (``_band``: as many steps as the widest block needs, the
+# index maps held at the last block of the band so that a step beyond it
+# fetches nothing), and inside a block the tiles wholly below the band
+# are never visited either; a tile the band's lower edge crosses is
+# masked as one the diagonal crosses is.
 # ---------------------------------------------------------------------------
 def _packed_geometry(h: int, d: int):
     """lane-block width, heads per block, and group count — or None
@@ -549,11 +558,20 @@ def _compute_tile(block_q: int, block_k: int, causal: bool,
 
 
 def _tile_counts(sq: int, sk: int, tile_q: int, tile_k: int,
-                 causal: bool, has_seg: bool):
+                 causal: bool, has_seg: bool, window: Optional[int] = None):
     """Compute tiles of one head's score square: (all, visited,
     masked), by the rule ``_walk_tiles`` follows."""
     nq, nk = sq // tile_q, sk // tile_k
     square = nq * nk
+    if window is not None:
+        # off: a tile's first query position less its first key
+        off = (np.arange(nq)[:, None] * tile_q
+               - np.arange(nk)[None, :] * tile_k)
+        seen = (off >= -(tile_q - 1)) & (off <= window + tile_k - 2)
+        bare = (off >= tile_k - 1) & (off <= window - tile_q)
+        visited = int(seen.sum())
+        return square, visited, visited if has_seg else visited - int(
+            (seen & bare).sum())
     if has_seg:
         return square, square, square
     if not causal:
@@ -565,12 +583,13 @@ def _tile_counts(sq: int, sk: int, tile_q: int, tile_k: int,
 
 
 def _note_tiles(heads: int, sq: int, sk: int, tile_q: int, tile_k: int,
-                causal: bool, has_seg: bool) -> None:
+                causal: bool, has_seg: bool,
+                window: Optional[int] = None) -> None:
     """Counts, when a kernel call is traced, the compute tiles it will
     walk: ``flash_tiles_total{kind=square|visited|masked}``."""
     from ..observability import metrics as _obs_metrics
     reg = _obs_metrics.registry()
-    counts = _tile_counts(sq, sk, tile_q, tile_k, causal, has_seg)
+    counts = _tile_counts(sq, sk, tile_q, tile_k, causal, has_seg, window)
     for kind, n in zip(("square", "visited", "masked"), counts):
         reg.counter("flash_tiles_total",
                     "compute tiles of the packed flash kernels' score "
@@ -579,8 +598,55 @@ def _note_tiles(heads: int, sq: int, sk: int, tile_q: int, tile_k: int,
                     labels={"kind": kind}).inc(heads * n)
 
 
+def _band(by_key: bool, block_q: int, block_k: int, window: int,
+          n_q: int = 0):
+    """The resident blocks a window's band touches: ``(first, last)``,
+    each from a block's index to the first and the last block of the
+    other side that holds a pair of its band, for the key blocks of a
+    query block, or ``by_key`` the query blocks of a key block (of
+    ``n_q``, which nothing else reads).  They take an int32 of a kernel
+    or an index map, or a numpy array of indices."""
+    i32 = np.int32
+    bq, bk = i32(block_q), i32(block_k)
+
+    def div(a, b):      # never negative: truncation is the floor
+        return a // b if isinstance(a, np.ndarray) else jax.lax.div(a, b)
+
+    def least(a, b):
+        return np.minimum(a, b) if isinstance(a, np.ndarray) \
+            else jax.lax.min(a, b)
+
+    if by_key:
+        return (lambda kv: div(kv * bk, bq),
+                lambda kv: least(div(kv * bk + i32(block_k + window - 2),
+                                     bq), i32(n_q - 1)))
+    return (lambda q: div(q * bq - least(q * bq, i32(window - 1)), bk),
+            lambda q: div(q * bq + i32(block_q - 1), bk))
+
+
+def _band_steps(by_key: bool, block_q: int, block_k: int, window: int,
+                n_q: int, n_k: int) -> int:
+    """Grid steps the widest band of a block takes."""
+    first, last = _band(by_key, block_q, block_k, window, n_q)
+    idx = np.arange(n_k if by_key else n_q, dtype=np.int32)
+    return int((last(idx) - first(idx)).max()) + 1
+
+
+def _band_block(step, outer, by_key: bool, block_q: int, block_k: int,
+                window: Optional[int]):
+    """The block of the other side a kernel's grid step stands at: the
+    step itself with no window, else that many past the first block of
+    ``outer``'s band (it may lie past the band's last: ``_walk_tiles``
+    then runs nothing)."""
+    if window is None:
+        return step
+    return _band(by_key, block_q, block_k, window)[0](outer) + step
+
+
 def _walk_tiles(q_idx, kv_idx, q_rows, *, block_q: int, block_k: int,
-                tile_q: int, tile_k: int, causal: bool, has_seg: bool):
+                tile_q: int, tile_k: int, causal: bool, has_seg: bool,
+                window: Optional[int] = None,
+                q_blocks: Optional[int] = None):
     """Runs the resident block (``q_idx``, ``kv_idx``) as compute
     tiles.  ``q_rows(qs)`` opens the query rows ``qs .. qs + tile_q``
     of the block and returns ``k_cols(ks, off, masked)``, which
@@ -620,22 +686,50 @@ def _walk_tiles(q_idx, kv_idx, q_rows, *, block_q: int, block_k: int,
                 k_cols(ks, rel - ks, masked)
             loop(lo, hi, k_tile)
 
-        if has_seg:
+        # every row of the tile sees the keys before rel + 1, none
+        # sees those from rel + tile_q on
+        def tiles_before(pos):
+            return jax.lax.div(jax.lax.clamp(
+                i32(0), pos, i32(block_k)), i32(tile_k))
+
+        if window is not None:
+            # the first row's first key is rel - window + 1; every row
+            # sees the keys from rel + tile_q - window on
+            first = tiles_before(rel - i32(window - 1))
+            n_seen = tiles_before(rel + i32(tile_q + tile_k - 1))
+            if has_seg:
+                run(first, n_seen, True)
+            else:
+                n_bare = tiles_before(rel + i32(1))
+                bare_from = jax.lax.min(n_bare, tiles_before(
+                    rel + i32(tile_q + tile_k - 1 - window)))
+                run(first, bare_from, True)
+                run(bare_from, n_bare, False)
+                run(n_bare, n_seen, True)
+        elif has_seg:
             run(0, nk, True)
         elif not causal:
             run(0, nk, False)
         else:
-            # every row of the tile sees the keys before rel + 1, none
-            # sees those from rel + tile_q on
-            def tiles_before(pos):
-                return jax.lax.div(jax.lax.clamp(
-                    i32(0), pos, i32(block_k)), i32(tile_k))
             n_bare = tiles_before(rel + i32(1))
             n_seen = tiles_before(rel + i32(tile_q + tile_k - 1))
             run(i32(0), n_bare, False)
             run(n_bare, n_seen, True)
 
-    if causal and not has_seg:
+    if window is not None:
+        # a resident block wholly above the diagonal or wholly below
+        # the band holds no tile; a step past the last block neither
+        q_first = q_idx * i32(block_q)
+        k_first = kv_idx * i32(block_k)
+        inside = (k_first <= q_first + i32(block_q - 1)) & (
+            q_first - k_first < i32(window + block_k - 1))
+        if q_blocks is not None:
+            inside = inside & (q_idx < i32(q_blocks))
+
+        @pl.when(inside)
+        def _run_band():
+            loop(0, nq, q_tile)
+    elif causal and not has_seg:
         # a resident block wholly above the diagonal holds no tile
         @pl.when(kv_idx * block_k <= q_idx * block_q + block_q - 1)
         def _run():
@@ -645,7 +739,7 @@ def _walk_tiles(q_idx, kv_idx, q_rows, *, block_q: int, block_k: int,
 
 
 def _tile_keep(off, tile_q: int, tile_k: int, causal: bool, q_ids,
-               k_ids, by_key: bool = False):
+               k_ids, by_key: bool = False, window: Optional[int] = None):
     """What a masked compute tile keeps: [tile_q, tile_k] bool, or
     [tile_k, tile_q] ``by_key`` (then ``q_ids`` is a row and ``k_ids``
     a column)."""
@@ -655,6 +749,8 @@ def _tile_keep(off, tile_q: int, tile_k: int, causal: bool, q_ids,
         q_pos = jax.lax.broadcasted_iota(jnp.int32, shape, int(by_key))
         k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, int(not by_key))
         keep = q_pos + off >= k_pos
+        if window is not None:
+            keep = keep & (q_pos + off < k_pos + np.int32(window))
     if q_ids is not None:
         same = q_ids == k_ids
         keep = same if keep is None else keep & same
@@ -680,7 +776,8 @@ def _lane_sums(x):
 def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
                              block_q: int, block_k: int, tile_q: int,
                              tile_k: int, seq_k: int, d: int, hpb: int,
-                             has_seg: bool):
+                             has_seg: bool, window: Optional[int] = None,
+                             steps: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -691,9 +788,10 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
         qs_ref = ks_ref = None
 
     q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
+    step = pl.program_id(2)
+    kv_idx = _band_block(step, q_idx, False, block_q, block_k, window)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr[...], -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr[...])
@@ -710,8 +808,8 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
             v_blk = v_ref[0, cols, :]
             keep = _tile_keep(
                 off, tile_q, tile_k, causal, q_ids,
-                ks_ref[0, :, cols][:1, :] if has_seg else None) \
-                if masked else None
+                ks_ref[0, :, cols][:1, :] if has_seg else None,
+                window=window) if masked else None
             for hh in range(hpb):
                 dsl = slice(hh * d, (hh + 1) * d)
                 lsl = slice(hh * _LANES, (hh + 1) * _LANES)
@@ -741,11 +839,11 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
 
     _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
                 tile_q=tile_q, tile_k=tile_k, causal=causal,
-                has_seg=has_seg)
+                has_seg=has_seg, window=window)
 
-    n_kv = seq_k // block_k
+    n_kv = steps or seq_k // block_k
 
-    @pl.when(kv_idx == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _finish():
         # assemble the full lane-block then write each ref ONCE —
         # ref[0][:, sl] = x is a chained setitem on a VALUE, not a ref
@@ -767,7 +865,9 @@ def _flash_packed_fwd_kernel(*refs, scale: float, causal: bool,
 def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
                                 block_q: int, block_k: int, tile_q: int,
                                 tile_k: int, seq_k: int, d: int,
-                                hpb: int, has_seg: bool):
+                                hpb: int, has_seg: bool,
+                                window: Optional[int] = None,
+                                steps: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -779,9 +879,10 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
         qs_ref = ks_ref = None
 
     q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
+    step = pl.program_id(2)
+    kv_idx = _band_block(step, q_idx, False, block_q, block_k, window)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
         for hh in range(hpb):
@@ -806,8 +907,8 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
             v_blk = v_ref[0, cols, :]
             keep = _tile_keep(
                 off, tile_q, tile_k, causal, q_ids,
-                ks_ref[0, :, cols][:1, :] if has_seg else None) \
-                if masked else None
+                ks_ref[0, :, cols][:1, :] if has_seg else None,
+                window=window) if masked else None
             for hh in range(hpb):
                 dsl = slice(hh * d, (hh + 1) * d)
                 lsl = slice(hh * _LANES, (hh + 1) * _LANES)
@@ -832,11 +933,11 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
 
     _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
                 tile_q=tile_q, tile_k=tile_k, causal=causal,
-                has_seg=has_seg)
+                has_seg=has_seg, window=window)
 
-    n_kv = seq_k // block_k
+    n_kv = steps or seq_k // block_k
 
-    @pl.when(kv_idx == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -844,7 +945,9 @@ def _flash_packed_bwd_dq_kernel(*refs, scale: float, causal: bool,
 def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
                                  block_q: int, block_k: int,
                                  tile_q: int, tile_k: int, seq_q: int,
-                                 d: int, hpb: int, has_seg: bool):
+                                 d: int, hpb: int, has_seg: bool,
+                                 window: Optional[int] = None,
+                                 steps: Optional[int] = None):
     """Scores are computed by key, [tile_k, tile_q]: p and ds then stand
     as the left operands of the dv and dk products with nothing to
     transpose, which took a call at [8, 1024, 16 x 64] from 738 to 675
@@ -863,9 +966,10 @@ def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
         qs_ref = ks_ref = None
 
     kv_idx = pl.program_id(1)
-    q_idx = pl.program_id(2)
+    step = pl.program_id(2)
+    q_idx = _band_block(step, kv_idx, True, block_q, block_k, window)
 
-    @pl.when(q_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
@@ -891,7 +995,7 @@ def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
             keep = _tile_keep(
                 off, tile_q, tile_k, causal, q_ids,
                 ks_ref[0, cols, :][:, :1] if has_seg else None,
-                by_key=True) if masked else None
+                by_key=True, window=window) if masked else None
             for hh in range(hpb):
                 dsl = slice(hh * d, (hh + 1) * d)
                 q = q_blk[:, dsl]
@@ -917,11 +1021,12 @@ def _flash_packed_bwd_dkv_kernel(*refs, scale: float, causal: bool,
 
     _walk_tiles(q_idx, kv_idx, q_rows, block_q=block_q, block_k=block_k,
                 tile_q=tile_q, tile_k=tile_k, causal=causal,
-                has_seg=has_seg)
+                has_seg=has_seg, window=window,
+                q_blocks=seq_q // block_q)
 
-    n_q = seq_q // block_q
+    n_q = steps or seq_q // block_q
 
-    @pl.when(q_idx == n_q - 1)
+    @pl.when(step == n_q - 1)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -936,7 +1041,11 @@ def _packed_index_maps(g: int):
     negative, so truncating division is floor division here).
 
     ``at`` is the grid position (1 or 2) whose index walks the sequence
-    dim.  ``block(at)`` is the index map of a [B, S, H*D] tensor.
+    dim.  ``block(at)`` is the index map of a [B, S, H*D] tensor;
+    ``block(at, band)`` that of the side a window's band grid walks:
+    ``band = (outer_at, first, last)`` (``_band``), the block at
+    position ``at`` counted from the first of the outer block's band and
+    held at its last.
     ``seg_down(ids, n, at)`` and ``seg_along(ids, n, at)`` lay [B, S]
     segment ids down the sublanes, [B, S, LANES], or along the lanes,
     [B, SUBLANES, S], ``n`` of them a grid step: the array and its block
@@ -944,21 +1053,32 @@ def _packed_index_maps(g: int):
     from jax.experimental import pallas as pl
     g32 = np.int32(g)
 
-    def block(at):
-        return lambda *idx: (jax.lax.div(idx[0], g32), idx[at],
+    def along(at, band):
+        """The sequence's block a grid position stands at."""
+        if band is None:
+            return lambda idx: idx[at]
+        outer_at, first, last = band
+        return lambda idx: jax.lax.min(first(idx[outer_at]) + idx[at],
+                                       last(idx[outer_at]))
+
+    def block(at, band=None):
+        at_ = along(at, band)
+        return lambda *idx: (jax.lax.div(idx[0], g32), at_(idx),
                              jax.lax.rem(idx[0], g32))
 
-    def seg_down(ids, n, at):
+    def seg_down(ids, n, at, band=None):
+        at_ = along(at, band)
         return (jax.lax.broadcast_in_dim(ids, ids.shape + (_LANES,),
                                          (0, 1)),
                 pl.BlockSpec((1, n, _LANES), lambda *idx: (
-                    jax.lax.div(idx[0], g32), idx[at], idx[0] * 0)))
+                    jax.lax.div(idx[0], g32), at_(idx), idx[0] * 0)))
 
-    def seg_along(ids, n, at):
+    def seg_along(ids, n, at, band=None):
+        at_ = along(at, band)
         return (jax.lax.broadcast_in_dim(
             ids, (ids.shape[0], _SUBLANES, ids.shape[1]), (0, 2)),
             pl.BlockSpec((1, _SUBLANES, n), lambda *idx: (
-                jax.lax.div(idx[0], g32), idx[0] * 0, idx[at])))
+                jax.lax.div(idx[0], g32), idx[0] * 0, at_(idx))))
 
     return block, seg_down, seg_along
 
@@ -974,6 +1094,7 @@ class _PackedPlan(NamedTuple):
     block_k: int
     tile_q: int
     tile_k: int
+    window: Optional[int] = None
 
     def kernel_args(self) -> dict:
         return dict(scale=1.0 / math.sqrt(self.d), causal=self.causal,
@@ -982,9 +1103,19 @@ class _PackedPlan(NamedTuple):
                     hpb=_packed_geometry(self.heads, self.d)[1],
                     has_seg=self.has_seg)
 
+    def band(self, by_key: bool, n_q: int, n_k: int) -> dict:
+        """With a window, what a kernel of the band grid is given beside
+        ``kernel_args``: the window and the steps of its last grid
+        dimension."""
+        if self.window is None:
+            return {}
+        return dict(window=self.window, steps=_band_steps(
+            by_key, self.block_q, self.block_k, self.window, n_q, n_k))
+
 
 def _packed_plan(b, sq, sk, h, d, causal, has_seg, block_q, block_k, tile,
-                 default_block_k: int = _BLOCK_K) -> _PackedPlan:
+                 default_block_k: int = _BLOCK_K,
+                 window: Optional[int] = None) -> _PackedPlan:
     """Resident block and compute tile of one kernel call at this
     shape.  ``block_q``/``block_k``/``tile`` override the defaults
     (tests, sweeps).  Counts the call's tiles.  A block is at least 128
@@ -993,28 +1124,30 @@ def _packed_plan(b, sq, sk, h, d, causal, has_seg, block_q, block_k, tile,
     lanes, the other two the keys."""
     block_q = _fit_block(sq, max(_LANES, block_q or _BLOCK_Q))
     block_k = _fit_block(sk, max(_LANES, block_k or default_block_k))
-    tile_q, tile_k = tile or _compute_tile(block_q, block_k, causal,
-                                           has_seg)
+    tile_q, tile_k = tile or _compute_tile(
+        block_q, block_k, causal, has_seg and window is None)
     if block_q % tile_q or block_k % tile_k or tile_q % _LANES \
             or tile_k % _LANES:
         raise ValueError(
             f"compute tile {tile_q} x {tile_k} must divide the resident "
             f"block {block_q} x {block_k} (block_q x block_k, fitted to "
             f"the sequence) in whole groups of {_LANES} rows")
-    _note_tiles(b * h, sq, sk, tile_q, tile_k, causal, has_seg)
+    _note_tiles(b * h, sq, sk, tile_q, tile_k, causal, has_seg, window)
     return _PackedPlan(h, d, causal, has_seg, block_q, block_k, tile_q,
-                       tile_k)
+                       tile_k, window)
 
 
 def _pallas_flash_packed(q, k, v, h, d, q_seg=None, k_seg=None, *,
                          causal: bool, block_q: Optional[int] = None,
-                         block_k: Optional[int] = None, tile=None):
+                         block_k: Optional[int] = None, tile=None,
+                         window: Optional[int] = None):
     """q [B, Sq, H*D]; k/v [B, Sk, H*D] → (out [B, Sq, H*D],
     lse [B, Sq, H*D] f32, per-head value broadcast over its d lanes).
     Segment ids are [B, S*] (NOT per-head — the packed grid reuses one
     mask per lane-group)."""
     plan = _packed_plan(q.shape[0], q.shape[1], k.shape[1], h, d, causal,
-                        q_seg is not None, block_q, block_k, tile)
+                        q_seg is not None, block_q, block_k, tile,
+                        window=window)
     return _flash_packed_fwd_call(q, k, v, q_seg, k_seg, plan=plan,
                                   interpret=_interpret())
 
@@ -1037,15 +1170,19 @@ def _flash_packed_fwd_call(q, k, v, q_seg, k_seg, *, plan: _PackedPlan,
     block_q, block_k = plan.block_q, plan.block_k
 
     block, seg_down, seg_along = _packed_index_maps(g)
-    # grid (b*g, q, kv) — kv minor
+    # grid (b*g, q, kv) — kv minor; with a window the kv blocks of the
+    # query block's band
+    band = plan.band(False, sq // block_q, sk // block_k)
+    keys = (1,) + _band(False, block_q, block_k, plan.window) \
+        if band else None
     qspec = pl.BlockSpec((1, block_q, lb), block(1))
-    kspec = pl.BlockSpec((1, block_k, lb), block(2))
+    kspec = pl.BlockSpec((1, block_k, lb), block(2, keys))
     segs = [seg_down(q_seg, block_q, 1),
-            seg_along(k_seg, block_k, 2)] if plan.has_seg else []
+            seg_along(k_seg, block_k, 2, keys)] if plan.has_seg else []
     out, lse = pl.pallas_call(
         functools.partial(_flash_packed_fwd_kernel, seq_k=sk,
-                          **plan.kernel_args()),
-        grid=(b * g, sq // block_q, sk // block_k),
+                          **plan.kernel_args(), **band),
+        grid=(b * g, sq // block_q, band.get("steps", sk // block_k)),
         in_specs=[qspec, kspec, kspec] + [spec for _, spec in segs],
         out_specs=[qspec, qspec],
         out_shape=[jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
@@ -1063,7 +1200,8 @@ def _flash_packed_fwd_call(q, k, v, q_seg, k_seg, *, plan: _PackedPlan,
 def _pallas_flash_packed_bwd(q, k, v, out, lse, do, h, d, q_seg=None,
                              k_seg=None, *, causal: bool,
                              block_q: Optional[int] = None,
-                             block_k: Optional[int] = None, tile=None):
+                             block_k: Optional[int] = None, tile=None,
+                             window: Optional[int] = None):
     shape = (q.shape[0], q.shape[1], k.shape[1], h, d, causal,
              q_seg is not None, block_q, block_k, tile)
     # dq keeps up to 2048 keys resident: with one key block the K/V of
@@ -1073,8 +1211,9 @@ def _pallas_flash_packed_bwd(q, k, v, out, lse, do, h, d, q_seg=None,
     # compute tile, not the resident block, bounds the temporaries.
     return _flash_packed_bwd_call(
         q, k, v, out, lse, do, q_seg, k_seg,
-        dq_plan=_packed_plan(*shape, default_block_k=2048),
-        dkv_plan=_packed_plan(*shape), interpret=_interpret())
+        dq_plan=_packed_plan(*shape, default_block_k=2048, window=window),
+        dkv_plan=_packed_plan(*shape, window=window),
+        interpret=_interpret())
 
 
 @functools.partial(jax.jit,
@@ -1093,19 +1232,30 @@ def _flash_packed_bwd_call(q, k, v, out, lse, do, q_seg, k_seg, *,
 
     def specs(plan, q_at, k_at):
         """Block specs of the six tensors for a grid whose position
-        ``q_at`` walks the queries and ``k_at`` the keys."""
-        qspec = pl.BlockSpec((1, plan.block_q, lb), block(q_at))
-        kspec = pl.BlockSpec((1, plan.block_k, lb), block(k_at))
-        return qspec, kspec, [qspec, kspec, kspec, qspec, qspec, qspec]
+        ``q_at`` walks the queries and ``k_at`` the keys, the band grid's
+        steps and what its last position walks (None: no window)."""
+        by_key = k_at == 1
+        band = plan.band(by_key, sq // plan.block_q, sk // plan.block_k)
+        walked = (1,) + _band(by_key, plan.block_q, plan.block_k,
+                              plan.window, sq // plan.block_q) \
+            if band else None
+        qspec = pl.BlockSpec((1, plan.block_q, lb),
+                             block(q_at, walked if by_key else None))
+        kspec = pl.BlockSpec((1, plan.block_k, lb),
+                             block(k_at, None if by_key else walked))
+        return (qspec, kspec, [qspec, kspec, kspec, qspec, qspec, qspec],
+                band, walked)
 
     # dq pass: grid (b*g, q, kv) — kv minor
-    qspec, _, in_specs = specs(dq_plan, 1, 2)
+    qspec, _, in_specs, band, walked = specs(dq_plan, 1, 2)
     segs = [seg_down(q_seg, dq_plan.block_q, 1),
-            seg_along(k_seg, dq_plan.block_k, 2)] if dq_plan.has_seg else []
+            seg_along(k_seg, dq_plan.block_k, 2, walked)] \
+        if dq_plan.has_seg else []
     dq = pl.pallas_call(
         functools.partial(_flash_packed_bwd_dq_kernel, seq_k=sk,
-                          **dq_plan.kernel_args()),
-        grid=(b * g, sq // dq_plan.block_q, sk // dq_plan.block_k),
+                          **dq_plan.kernel_args(), **band),
+        grid=(b * g, sq // dq_plan.block_q,
+              band.get("steps", sk // dq_plan.block_k)),
         in_specs=in_specs + [spec for _, spec in segs],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
@@ -1118,13 +1268,15 @@ def _flash_packed_bwd_call(q, k, v, out, lse, do, q_seg, k_seg, *,
 
     # dkv pass: grid (b*g, kv, q) — q minor; scores by key, so the
     # keys' ids go down the sublanes and the queries' along the lanes
-    _, kspec, in_specs = specs(dkv_plan, 2, 1)
+    _, kspec, in_specs, band, walked = specs(dkv_plan, 2, 1)
     segs = [seg_down(k_seg, dkv_plan.block_k, 1),
-            seg_along(q_seg, dkv_plan.block_q, 2)] if dkv_plan.has_seg else []
+            seg_along(q_seg, dkv_plan.block_q, 2, walked)] \
+        if dkv_plan.has_seg else []
     dk, dv = pl.pallas_call(
         functools.partial(_flash_packed_bwd_dkv_kernel, seq_q=sq,
-                          **dkv_plan.kernel_args()),
-        grid=(b * g, sk // dkv_plan.block_k, sq // dkv_plan.block_q),
+                          **dkv_plan.kernel_args(), **band),
+        grid=(b * g, sk // dkv_plan.block_k,
+              band.get("steps", sq // dkv_plan.block_q)),
         in_specs=in_specs + [spec for _, spec in segs],
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((b, sk, hd), k.dtype),
@@ -1136,24 +1288,24 @@ def _flash_packed_bwd_call(q, k, v, out, lse, do, q_seg, k_seg, *,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_core_packed(q, k, v, q_seg, k_seg, causal, h, d):
-    out, _ = _flash_packed_fwd(q, k, v, q_seg, k_seg, causal, h, d)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_core_packed(q, k, v, q_seg, k_seg, causal, h, d, window=None):
+    out, _ = _flash_packed_fwd(q, k, v, q_seg, k_seg, causal, h, d, window)
     return out
 
 
-def _flash_packed_fwd(q, k, v, q_seg, k_seg, causal, h, d):
+def _flash_packed_fwd(q, k, v, q_seg, k_seg, causal, h, d, window):
     out, lse = _pallas_flash_packed(
         q, k, v, h, d, _seg_or_none(q_seg), _seg_or_none(k_seg),
-        causal=causal)
+        causal=causal, window=window)
     return out, (q, k, v, out, lse, q_seg, k_seg)
 
 
-def _flash_packed_bwd(causal, h, d, res, g):
+def _flash_packed_bwd(causal, h, d, window, res, g):
     q, k, v, out, lse, q_seg, k_seg = res
     dq, dk, dv = _pallas_flash_packed_bwd(
         q, k, v, out, lse, g, h, d, _seg_or_none(q_seg),
-        _seg_or_none(k_seg), causal=causal)
+        _seg_or_none(k_seg), causal=causal, window=window)
     return dq, dk, dv, _int_zero_ct(q_seg), _int_zero_ct(k_seg)
 
 
@@ -1198,14 +1350,18 @@ def _attention_form(h: int, d: int, sq: int, sk: int) -> Optional[str]:
 # where no kernel is eligible (non-TPU backends, unaligned shapes)
 # ---------------------------------------------------------------------------
 def _flash_reference(q, k, v, causal, q_seg=None, k_seg=None,
-                     dropout_key=None, dropout_p=0.0):
-    """Composed attention on [BH,Sq,D]/[BH,Sk,D]."""
+                     dropout_key=None, dropout_p=0.0, window=None):
+    """Composed attention on [BH,Sq,D]/[BH,Sk,D]; with a ``window``
+    (causal) a query sees the ``window`` keys up to its own."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                                    -int(window))
         s = jnp.where(mask, s, -jnp.inf)
     if q_seg is not None:
         s = jnp.where(q_seg[:, :, None] == k_seg[:, None, :], s, -jnp.inf)
@@ -1225,21 +1381,22 @@ def _seg_or_none(seg):
     return seg if seg is not None and seg.size else None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash_core(q, k, v, q_seg, k_seg, causal, kernel):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_core(q, k, v, q_seg, k_seg, causal, kernel, window=None):
     """Attention on [BH, S, D] by the kernels (``kernel``, the caller's
     ``_attention_form``) or in the composed form, whose backward
-    recomputes the probabilities rather than keep them."""
-    out, _ = _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel)
+    recomputes the probabilities rather than keep them.  A ``window`` is
+    the composed form's: the [BH, S, D] kernels have none."""
+    out, _ = _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel, window)
     return out
 
 
-def _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel):
+def _flash_fwd(q, k, v, q_seg, k_seg, causal, kernel, window):
     qs, ks = _seg_or_none(q_seg), _seg_or_none(k_seg)
     if kernel:
         out, lse = _pallas_flash_bh(q, k, v, qs, ks, causal=causal)
     else:
-        out = _flash_reference(q, k, v, causal, qs, ks)
+        out = _flash_reference(q, k, v, causal, qs, ks, window=window)
         # the composed backward recomputes: it is handed no lse
         lse = jnp.zeros((0,), jnp.float32)
     return out, (q, k, v, out, lse, q_seg, k_seg)
@@ -1250,7 +1407,7 @@ def _int_zero_ct(x):
     return np.zeros(np.shape(x), dtype=jax.dtypes.float0)
 
 
-def _flash_bwd(causal, kernel, res, g):
+def _flash_bwd(causal, kernel, window, res, g):
     q, k, v, out, lse, q_seg, k_seg = res
     qs, ks = _seg_or_none(q_seg), _seg_or_none(k_seg)
     if kernel:    # block-streaming backward, no [S,S] in HBM
@@ -1259,7 +1416,7 @@ def _flash_bwd(causal, kernel, res, g):
     else:         # composed forward: differentiate the composed form
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _flash_reference(q_, k_, v_, causal,
-                                                qs, ks),
+                                                qs, ks, window=window),
             q, k, v)
         dq, dk, dv = vjp(g)
     return (dq, dk, dv, _int_zero_ct(q_seg), _int_zero_ct(k_seg))
@@ -1280,26 +1437,30 @@ def _batch_to_heads(x, b):
     return jnp.moveaxis(x.reshape(b, bh // b, s, d), 1, 2)
 
 
-def _flash_local(query, key, value, qseg, kseg, *, causal):
+def _flash_local(query, key, value, qseg, kseg, *, causal, window=None):
     """Dropout-free attention on the arrays one device holds:
     [b, S, h, D] in and out, ``qseg``/``kseg`` [b, S*] int32 or the
     0-sized 'none' sentinel.  Picks the kernel layout from the local
-    shape."""
+    shape; a ``window`` is walked by the packed kernels and masked by
+    the composed form, and by nothing else."""
     b, sq, h, d = query.shape
     sk = key.shape[1]
     form = _attention_form(h, d, sq, sk)
+    if window is not None and form == "bh":
+        form = None
     if form == "packed":
         # transpose-free path: [B,S,H,D] → [B,S,H*D] is a free reshape;
         # segment ids stay [B, S] (one mask per lane-group)
         out = _flash_core_packed(
             query.reshape(b, sq, h * d), key.reshape(b, sk, h * d),
-            value.reshape(b, sk, h * d), qseg, kseg, causal, h, d)
+            value.reshape(b, sk, h * d), qseg, kseg, causal, h, d,
+            window)
         return out.reshape(b, sq, h, d)
     if qseg.size:
         qseg, kseg = jnp.repeat(qseg, h, axis=0), jnp.repeat(kseg, h, axis=0)
     out = _flash_core(_heads_to_batch(query), _heads_to_batch(key),
                       _heads_to_batch(value), qseg, kseg, causal,
-                      form == "bh")
+                      form == "bh", window)
     return _batch_to_heads(out, b)
 
 
@@ -1332,8 +1493,13 @@ def _per_device(fn, b: int, h: int, has_seg: bool):
 
 @primitive(name="flash_attention")
 def flash_attention(query, key, value, causal=False, dropout=0.0,
-                    training=True, segment_ids=None, kv_segment_ids=None):
+                    training=True, segment_ids=None, kv_segment_ids=None,
+                    window=None):
     """[B, S, H, D] in/out, paddle flash_attention convention.
+
+    ``window`` (with ``causal``): a query sees the ``window`` keys up to
+    and with its own, ``0 <= t - s < window``.  The packed kernels then
+    visit only the tiles the band touches; every other form masks.
 
     ``key``/``value`` may have fewer heads (GQA/MQA).  ``segment_ids``
     [B, Sq] / ``kv_segment_ids`` [B, Sk] mask attention across packed
@@ -1348,6 +1514,12 @@ def flash_attention(query, key, value, causal=False, dropout=0.0,
     if causal and sq != sk:
         raise ValueError(
             f"causal flash_attention requires Sq == Sk, got {sq} vs {sk}")
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError(
+                "flash_attention: a window is a causal band of at least "
+                f"one key, got causal={causal}, window={window}")
     if hq != hkv:
         if hq % hkv != 0:
             raise ValueError(
@@ -1383,10 +1555,11 @@ def flash_attention(query, key, value, causal=False, dropout=0.0,
         out = _flash_reference(
             _heads_to_batch(query), _heads_to_batch(key),
             _heads_to_batch(value), causal, qs, ks,
-            dropout_key=_random.next_key(), dropout_p=float(dropout))
+            dropout_key=_random.next_key(), dropout_p=float(dropout),
+            window=window)
         return _batch_to_heads(out, b)
 
-    local = functools.partial(_flash_local, causal=causal)
+    local = functools.partial(_flash_local, causal=causal, window=window)
     if _kernels_enabled():
         # the form follows from the shape a device holds, so the split
         # comes first (_flash_local asks _attention_form inside it)

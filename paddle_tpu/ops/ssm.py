@@ -1,6 +1,8 @@
 """State-space layers: the selective scan of Mamba-2 in its chunked
-(state-space duality) form, and the causal depthwise convolution in
-front of it; a sequence at a time.  Each has two forms, and
+(state-space duality) form, the selective scan of Mamba-1 (a step size a
+channel and a decay a channel and state: :func:`selective_scan`, at the
+end of this file), and the causal depthwise convolution in front of
+either; a sequence at a time.  Each has two forms, and
 :func:`scan_form` and :func:`conv_form` name the one that runs, from
 platform and shape: on a TPU, for shapes that fill lane groups and whole
 chunks or tiles, the Mosaic kernels of ``ops/ssm_kernels.py`` (a chunk's
@@ -37,6 +39,8 @@ VMEM, forward and backward.
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -300,3 +304,240 @@ def scan_state_bytes(seq: int, heads: int, width: int, state: int,
                      chunk: int) -> int:
     """Bytes of the float32 states a call passes from chunk to chunk."""
     return seq // chunk * heads * width * state * 4
+
+
+# --------------------------------------------------------------------------
+# the scan of Mamba-1: a step size a channel, a decay a channel and state
+# --------------------------------------------------------------------------
+# For channel c with state ``s [N]``, ``a_t = dt_t[c] A[c]`` (A < 0):
+#
+#     s_t = exp(a_t) s_{t-1} + dt_t[c] x_t[c] B_t        y_t = s_t . C_t + D[c] x_t[c]
+#
+# B and C are shared by all channels, every channel has its own N decays,
+# and so no product of matrices computes it (the state-space duality
+# needs one decay a head).  In chunks of Q positions, all chunks side by
+# side: Q steps of the recurrence from nothing, each over ``[chunks, N,
+# channels]``; the state each chunk starts from, chunk to chunk; and what
+# that state adds to y, ``exp(A cumsum(dt))`` of it, which needs no
+# step.  The backward pass is given the inputs and the chunks' starting
+# states and nothing else: the adjoint recurrence from nothing and chunk
+# to chunk likewise, then some chunks at a time (``SELECTIVE_BYTES_AT_ONCE``
+# of states) their states again and, walking back through them, the
+# gradients.  Everything is float32 but what is read and written.
+SELECTIVE_BYTES_AT_ONCE = 1 << 28
+
+
+def selective_chunk(seq: int) -> int:
+    """Q, from the sequence's length: the power of two at or under its
+    square root.  A call takes Q steps inside the chunks and ``seq / Q``
+    from chunk to chunk one after another, fewest at the root (64 at 8192
+    positions, where the chip read the same time from 32 to 128: PERF.md
+    section 6, PR 38), and keeps ``seq / Q`` states for the walk back."""
+    return 1 << (math.isqrt(max(int(seq), 1)).bit_length() - 1)
+
+
+def selective_scan_form(seq: int, chunk: int) -> str:
+    """Which form of :func:`selective_scan` runs, from the shape:
+    ``"chunked"`` where the sequence is more than one whole chunk,
+    ``"sequential"`` (the recurrence a position at a time, and jax's own
+    derivative of it) for anything else.  Both are XLA operations on
+    every platform: there is no kernel yet."""
+    return "chunked" if seq % chunk == 0 and seq > chunk else "sequential"
+
+
+def _by_chunk(a, chunk):
+    """``[S, ...] -> [Q, chunks, ...]``: a step of the recurrence takes
+    one position of every chunk."""
+    return a.reshape((a.shape[0] // chunk, chunk) + a.shape[1:]).swapaxes(
+        0, 1)
+
+
+def _from_chunks(a):
+    """``[Q, chunks, ...] -> [S, ...]``."""
+    a = a.swapaxes(0, 1)
+    return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+
+def _outer(row, col):
+    """``[n, C]`` and ``[n, N] -> [n, N, C]``."""
+    return col[:, :, None] * row[:, None, :]
+
+
+def _selective_step(At, s, dt, x, B):
+    """One position of every chunk: ``s [n, N, C]`` float32, ``At [N,
+    C]``, ``dt`` and ``x [n, C]``, ``B [n, N]``."""
+    return jnp.exp(At * dt[:, None, :]) * s + _outer(dt * x, B)
+
+
+def _carry_states(totals, ends, reverse=False):
+    """The state each chunk starts from (or, ``reverse``, the adjoint
+    each chunk ends with): ``h' = totals_k h + ends_k``, from nothing."""
+    def step(h, args):
+        total, end = args
+        return total * h + end, h
+
+    return jax.lax.scan(step, jnp.zeros_like(ends[0]), (totals, ends),
+                        reverse=reverse)[1]
+
+
+def _selective_sequential(x, dt, A, B, C, D):
+    f32 = jnp.float32
+    At = A.astype(f32).T
+
+    def step(s, args):
+        dt_t, x_t, b_t, c_t = args
+        s = _selective_step(At, s, dt_t[None], x_t[None], b_t[None])
+        return s, (s[0] * c_t[:, None]).sum(0)
+
+    xf = x.astype(f32)
+    _, y = jax.lax.scan(step, jnp.zeros((1,) + At.shape, f32), (
+        dt.astype(f32), xf, B.astype(f32), C.astype(f32)))
+    return (y + D.astype(f32) * xf).astype(x.dtype)
+
+
+def _selective_forward(x, dt, A, B, C, D, chunk):
+    """(y, the state each chunk starts from ``[chunks, N, C]``)."""
+    f32 = jnp.float32
+    At = A.astype(f32).T                                   # [N, C]
+    xc, dtc, Bc, Cc = (_by_chunk(a.astype(f32), chunk)
+                       for a in (x, dt, B, C))
+
+    def step(s, args):
+        dt_l, x_l, b_l, c_l = args
+        s = _selective_step(At, s, dt_l, x_l, b_l)
+        return s, (s * c_l[:, :, None]).sum(1)
+
+    n = xc.shape[1]
+    ends, y = jax.lax.scan(step, jnp.zeros((n,) + At.shape, f32),
+                           (dtc, xc, Bc, Cc))
+    cum = jnp.cumsum(dtc, axis=0)                          # [Q, n, C]
+    starts = _carry_states(jnp.exp(At * cum[-1][:, None, :]), ends)
+
+    def from_start(args):
+        cum_l, c_l = args
+        return (jnp.exp(At * cum_l[:, None, :]) * starts
+                * c_l[:, :, None]).sum(1)
+
+    y = y + jax.lax.map(from_start, (cum, Cc))
+    y = _from_chunks(y) + D.astype(f32) * x.astype(f32)
+    return y.astype(x.dtype), starts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _selective_scan(x, dt, A, B, C, D, chunk):
+    return _selective_forward(x, dt, A, B, C, D, chunk)[0]
+
+
+def _selective_scan_fwd(x, dt, A, B, C, D, chunk):
+    y, starts = _selective_forward(x, dt, A, B, C, D, chunk)
+    return y, (x, dt, A, B, C, D, starts)
+
+
+def _selective_scan_bwd(chunk, res, dy):
+    """With ``g_t`` the gradient by ``s_t``: ``g_t = C_t dy_t + exp(a_{t+1})
+    g_{t+1}``, the same recurrence walked back.  From nothing in every
+    chunk, then chunk to chunk, it gives what each chunk's last state is
+    owed by the chunks after it; then, some chunks at a time, the states
+    again from the kept starts, and back through them ``g_t`` itself and
+    every gradient: ``da_t = g_t (s_t - u_t)`` with ``u_t = dt_t x_t
+    B_t``, since ``exp(a_t) s_{t-1} = s_t - u_t``."""
+    x, dt, A, B, C, D, starts = res
+    f32 = jnp.float32
+    At = A.astype(f32).T
+    xf, dyf = x.astype(f32), dy.astype(f32)
+    xc, dtc, Bc, Cc, dyc = (_by_chunk(a.astype(f32), chunk)
+                            for a in (x, dt, B, C, dy))
+    n = xc.shape[1]
+
+    def back_from_nothing(g, args):
+        dt_l, c_l, dy_l = args
+        return jnp.exp(At * dt_l[:, None, :]) * (g + _outer(dy_l, c_l)), None
+
+    owed_inside, _ = jax.lax.scan(
+        back_from_nothing, jnp.zeros((n,) + At.shape, f32), (dtc, Cc, dyc),
+        reverse=True)
+    totals = jnp.exp(At * dtc.sum(0)[:, None, :])
+    owed = _carry_states(totals, owed_inside, reverse=True)
+
+    k = _at_once(n, max(1, SELECTIVE_BYTES_AT_ONCE
+                        // (chunk * At.size * 4)))
+
+    def some_chunks(args):
+        x_, dt_, B_, C_, dy_, starts_, owed_ = args        # [Q, k, ...]
+
+        def ahead(s, a):
+            s = _selective_step(At, s, *a)
+            return s, s
+
+        _, states = jax.lax.scan(ahead, starts_, (dt_, x_, B_))
+
+        def back(carry, a):
+            g, dA = carry
+            dt_l, x_l, b_l, c_l, dy_l, s_l = a
+            g = g + _outer(dy_l, c_l)
+            to_x = (g * b_l[:, :, None]).sum(1)             # [k, C]
+            da = g * (s_l - _outer(dt_l * x_l, b_l))
+            out = (dt_l * to_x, (da * At).sum(1) + x_l * to_x,
+                   (g * (dt_l * x_l)[:, None, :]).sum(2),
+                   (s_l * dy_l[:, None, :]).sum(2))
+            return (jnp.exp(At * dt_l[:, None, :]) * g,
+                    dA + da * dt_l[:, None, :]), out
+
+        (_, dA), grads = jax.lax.scan(
+            back, (owed_, jnp.zeros_like(owed_)),
+            (dt_, x_, B_, C_, dy_, states), reverse=True)
+        return grads + (dA.sum(0),)
+
+    def grouped(a, axis):
+        """``[..., n, ...] -> [n / k, ..., k, ...]``."""
+        shape = a.shape[:axis] + (n // k, k) + a.shape[axis + 1:]
+        return jnp.moveaxis(a.reshape(shape), axis, 0)
+
+    dx, ddt, dB, dC, dA = jax.lax.map(some_chunks, tuple(
+        grouped(a, 1) for a in (xc, dtc, Bc, Cc, dyc)) + (
+        grouped(starts, 0), grouped(owed, 0)))
+
+    def whole(a):
+        """``[n / k, Q, k, ...] -> [S, ...]``."""
+        a = jnp.moveaxis(a, 0, 1)                           # [Q, n/k, k, ...]
+        return _from_chunks(a.reshape((a.shape[0], n) + a.shape[3:]))
+
+    return ((whole(dx) + D.astype(f32) * dyf).astype(x.dtype),
+            whole(ddt).astype(dt.dtype), dA.sum(0).T.astype(A.dtype),
+            whole(dB).astype(B.dtype), whole(dC).astype(C.dtype),
+            (dyf * xf).sum(0).astype(D.dtype))
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+
+def selective_scan(x, dt, A, B, C, D, chunk: Optional[int] = None):
+    """``y [S, C]`` of one sequence: ``x [S, C]``, ``dt [S, C]`` (positive:
+    after its softplus), ``A [C, N]`` (negative), ``B`` and ``C [S, N]``
+    (all channels share them), ``D [C]``.  y in x's dtype; the state and
+    every sum float32.  ``chunk`` is :func:`selective_chunk`'s unless a
+    test gives another: it changes how y is computed and not y."""
+    chunk = selective_chunk(x.shape[0]) if chunk is None else int(chunk)
+    if selective_scan_form(x.shape[0], chunk) == "chunked":
+        return _selective_scan(x, dt, A, B, C, D, chunk)
+    return _selective_sequential(x, dt, A, B, C, D)
+
+
+def selective_scan_chunks(seq: int) -> int:
+    """Chunks a call of :func:`selective_scan` walks (1: sequential)."""
+    chunk = selective_chunk(seq)
+    return seq // chunk if selective_scan_form(seq, chunk) == "chunked" \
+        else 1
+
+
+def selective_scan_state_bytes(seq: int, channels: int, state: int) -> int:
+    """Bytes of the float32 states a call keeps for its backward pass:
+    the one each chunk starts from."""
+    return selective_scan_chunks(seq) * channels * state * 4
+
+
+def causal_conv_silu(x, weight, bias):
+    """``silu(conv(x) + bias)`` of ``x [S, C]`` alone (Mamba-1: B and C
+    come from a projection after it): :func:`causal_conv1d` and SiLU,
+    keeping their input only."""
+    return _conv_silu(x, weight, bias)

@@ -210,6 +210,18 @@ _PINNED_CELL_COUNT = (
 _PINNED_SIZE = (
     "test_nemotron_cell.py::"
     "test_the_benchmark_gained_entries_at_the_end_and_kept_the_rest")
+# PR 38 (``model_config``) appends a configuration, a cell and nine
+# per-layer metrics, and the test that stood in for the second pin pins
+# the benchmark in turn: ``test_host_half.py:279-306`` holds
+# ``BENCHMARK.json`` less PR 36's eight to (5, 6, 4, 39) with nothing
+# after those eight, and the metric directory's listing to what is
+# declared.  Same way, openly, and now three deep:
+# ``tests/benchmarks/test_sambay_cell.py`` sees it fail as it stands and
+# runs its whole body, the two older pins inside it, on the benchmark
+# less PR 38's entries and files.
+_PINNED_HOST_HALF = (
+    "test_host_half.py::"
+    "test_the_benchmark_gained_eight_metrics_at_its_end_and_kept_the_rest")
 _EXPECTED_TO_FAIL = {
     _PINNED_CELL_COUNT:
         "pins len(workloads) == 5; the benchmark has six cells since "
@@ -218,6 +230,10 @@ _EXPECTED_TO_FAIL = {
         "pins the benchmark less PR 34's entries at 29 per-layer metrics "
         "with nothing after them; PR 36 appended eight and may not edit "
         "the file",
+    _PINNED_HOST_HALF:
+        "pins the benchmark less PR 36's eight at (5, 6, 4, 39) with "
+        "nothing after them; PR 38 appended a configuration, a cell and "
+        "nine metrics and may not edit the file",
 }
 
 

@@ -372,6 +372,45 @@ def _flash_32_on_2(topo, monkeypatch):
     return grads, (q, kv, kv, q)
 
 
+def _flash_window_20_on_10(topo, monkeypatch):
+    """The cell phi4flash-depth6-s8192's window layer: one of its four
+    calls, 20 query heads on 10 key/value heads of 64 at s8192 inside a
+    window of 512, forward and backward through the public
+    ``flash_attention``: the band grid (2 of 8 key blocks a query block
+    forward, 2 of 4 in dq, 3 of 16 query blocks a key block in dkv) and
+    its clamped index maps."""
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    q, kv = spec((1, 8192, 20, 64)), spec((1, 8192, 10, 64))
+
+    def grads(q_, k_, v_, w_):
+        return jax.grad(lambda *a: (pallas_ops.flash_attention.raw(
+            *a, causal=True, window=512) * w_).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q_, k_, v_)
+
+    return grads, (q, kv, kv, q)
+
+
+def _selective_scan(topo, monkeypatch):
+    """The same cell's selective scan at ``[8192, 5120]``, state 16,
+    chunks of 64, forward and the walk back: XLA operations (no kernel
+    yet), compiled so that what the chip's compiler makes of the
+    chunked form is held: it fits."""
+    from paddle_tpu.ops import ssm
+    spec = _one_chip_spec(topo)
+    seq, channels, state = 8192, 5120, 16
+    args = (spec((seq, channels)), spec((seq, channels), jnp.float32),
+            spec((channels, state), jnp.float32), spec((seq, state)),
+            spec((seq, state)), spec((channels,), jnp.float32),
+            spec((seq, channels)))
+
+    def grads(x, dt, A, B, C, D, w):
+        return jax.grad(lambda *a: (ssm.selective_scan(*a, 64) * w).astype(
+            jnp.float32).sum(), argnums=tuple(range(6)))(x, dt, A, B, C, D)
+
+    return grads, args
+
+
 def _paged_decode(topo, monkeypatch):
     from paddle_tpu.inference.serving.paged_attention_kernel import \
         paged_ragged_attention
@@ -428,6 +467,10 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_conv_bwd(1), 1, None, id="ssm_conv_bwd_s8192_4352_channels"),
     pytest.param(_conv_fwd(8), 1, None, id="ssm_conv_fwd_s8192_6144_channels"),
     pytest.param(_conv_bwd(8), 1, None, id="ssm_conv_bwd_s8192_6144_channels"),
+    pytest.param(_flash_window_20_on_10, 3, None,
+                 id="flash_window_512_20_on_10_heads_of_64_s8192"),
+    pytest.param(_selective_scan, 0, None,
+                 id="selective_scan_s8192_5120_channels_state_16"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
              r"\"#tpu\.dot_dimension_numbers",
@@ -599,3 +642,58 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     assert len(sites) - len(products) == own + convolutions
     assert "ragged-dot" not in text
     assert _shifted_terms_in_hbm(text, 4096 + 2 * 8 * 128) == []
+
+
+def test_phi4flash_depth6_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell phi4flash-depth6-s8192 (six
+    layers, one of each kind of SambaY's, at published widths, b1 x s8192,
+    bf16 O2 with float32 master weights, the layers the configuration
+    names recomputed), as ``DistributedRunner`` builds it, compiled for
+    one described v5e chip: what it needs on the device stays a tenth
+    under the configuration's limit (the rule its ``recompute`` was chosen
+    by), and its kernels are in it: an attention-kind layer's four calls
+    of ``flash_attention``, each forward, dq and dkv, and the forward once
+    more where the layer is recomputed.  The selective scans are XLA
+    operations and hold no site."""
+    import numpy as np
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_sambay_lm as driver
+    from benchmarks.families import sambay as family
+    from benchmarks.harness import cells
+    config = cells.load_cell("phi4flash-depth6-s8192", root).config
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    prev_mesh = collective.get_mesh()
+    try:
+        with paddle_tpu.LazyGuard():
+            runner = driver.build_runner(config, 0, topo.devices[:1])
+        monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
+        ids = np.zeros((1, 8192), np.int64)
+        data = sum(runner._prep_step_args([ids], [ids]), [])
+        on_chip = NamedSharding(runner.mesh, P())
+
+        def shapes(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=on_chip), tree)
+
+        compiled = runner._step_fn.lower(
+            *shapes(runner._sync_val_cache()), shapes(runner._opt_state),
+            *shapes([jnp.float32(0), jnp.uint32(1)] + data)).compile()
+    finally:
+        collective.set_mesh(prev_mesh)
+    memory = compiled.memory_analysis()
+    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    # 697 094 272 parameters at 14 bytes (the LayerNorms' 66 560 hold no
+    # bf16 copy), and the batch
+    assert memory.argument_size_in_bytes == approx(9.76e9, rel=1e-3)
+    assert step < 0.9 * config["step_bytes_limit"] == 0.9 * 15.6e9
+    print(f"compiled step: {step} bytes a device")
+    kinds = family.kinds(config)
+    sites = driver.kernel_sites(kinds, set(config["recompute"]))
+    # layers 0, 1, 2 and 4 are recomputed: of the attention kinds the
+    # window layer alone
+    assert config["recompute"] == [0, 1, 2, 4]
+    assert compiled.as_text().count("tpu_custom_call") == sites == \
+        3 * 12 + 4

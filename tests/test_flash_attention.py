@@ -717,3 +717,228 @@ def test_flash_tile_counts_by_brute_force():
         (16, 16, 0)
     assert pallas_ops._tile_counts(512, 512, 128, 128, True, True) == \
         (16, 16, 16)
+
+
+# ---------------------------------------------------------------------------
+# the window: a causal band the tile walk keeps to
+# ---------------------------------------------------------------------------
+def _banded_oracle(q, k, v, window, seg=None):
+    """Plain float32 attention [B, S, H, D] under ``0 <= t - s <
+    window`` (and inside a segment)."""
+    s = q.shape[1]
+    t, u = np.arange(s)[:, None], np.arange(s)[None, :]
+    keep = jnp.asarray((u <= t) & (t - u < window))[None, None]
+    if seg is not None:
+        keep = keep & (seg[:, None, :, None] == seg[:, None, None, :])
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * np.float32(
+        1 / np.sqrt(q.shape[-1]))
+    probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _window_case(case_id, **kw):
+    case = dict(s=512, h=2, d=64, window=128, seg=False, block_q=None,
+                block_k=None, tile=None)
+    case.update(kw)
+    return pytest.param(case, id=case_id)
+
+
+# Each case names what of the band it reaches: tiles the lower edge
+# crosses, tiles wholly inside (bare), resident blocks wholly below the
+# band (never stood at: the band grid is shorter than the sequence's),
+# steps past a band's last block (nothing runs).
+_WINDOW_CASES = [
+    _window_case("w128-t128-one-block", tile=(128, 128)),
+    _window_case("w200-t128-edge-inside-a-tile", window=200,
+                 tile=(128, 128)),
+    _window_case("w512-s2048-default-rule", s=2048, window=512),
+    _window_case("w300-bare-tiles-between-the-edges", s=1024, window=300,
+                 tile=(128, 128)),
+    _window_case("w128-four-key-blocks-band-grid", s=1024, block_q=128,
+                 block_k=256, tile=(128, 128)),
+    _window_case("w130-key-blocks-narrower-than-query-blocks", s=1024,
+                 window=130, block_q=512, block_k=128, tile=(128, 128)),
+    _window_case("w1-the-diagonal-alone", window=1, tile=(128, 128)),
+    _window_case("w-wider-than-the-sequence", window=4096),
+    _window_case("w256-d128-hpb1", d=128, window=256, s=1024,
+                 block_q=256, block_k=256),
+    _window_case("w128-segments", seg=True, s=1024, block_q=256,
+                 block_k=256, tile=(128, 128)),
+]
+
+
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+def test_pallas_packed_window_matches_banded_attention(
+        _interpret_mode, _no_fallback, case):
+    """Forward and all three gradients of the packed kernels with a
+    window against plain banded attention, and the band grid's length."""
+    s, h, d, window = case["s"], case["h"], case["d"], case["window"]
+    rng = np.random.RandomState(23)
+    q, k, v, do = (jnp.asarray(rng.randn(1, s, h * d).astype(np.float32)
+                               * 0.5) for _ in range(4))
+    seg = None
+    if case["seg"]:
+        cuts = np.sort(rng.choice(np.arange(1, s), 3, replace=False))
+        seg = jnp.asarray(np.searchsorted(cuts, np.arange(s),
+                                          side="right")[None].astype(
+                                              np.int32))
+    over = dict(causal=True, block_q=case["block_q"],
+                block_k=case["block_k"], tile=case["tile"], window=window)
+    out, lse = pallas_ops._pallas_flash_packed(q, k, v, h, d, seg, seg,
+                                               **over)
+    grads = pallas_ops._pallas_flash_packed_bwd(
+        q, k, v, out, lse, do, h, d, seg, seg, **over)
+    heads = lambda a: a.reshape(1, s, h, d)                 # noqa: E731
+    ref, vjp = jax.vjp(
+        lambda q_, k_, v_: _banded_oracle(
+            heads(q_), heads(k_), heads(v_), window, seg).reshape(
+                1, s, h * d), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    for got, want in zip(grads, vjp(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("sq, bq, bk, window, by_key, want", [
+    (8192, 512, 1024, 512, False, 2),    # the cell's forward: 2 of 8
+    (8192, 512, 2048, 512, False, 2),    # its dq: 2 of 4
+    (8192, 512, 1024, 512, True, 3),     # its dkv: 3 of 16 query blocks
+    (1024, 128, 256, 128, False, 2), (1024, 128, 256, 128, True, 3),
+    (1024, 512, 128, 130, False, 6), (1024, 128, 128, 1, False, 1),
+    (512, 128, 128, 4096, False, 4), (512, 128, 128, 4096, True, 4)])
+def test_the_band_grid_walks_the_band_and_no_more(sq, bq, bk, window,
+                                                  by_key, want):
+    """``_band_steps`` against a walk over every pair of blocks, and the
+    first and last block of every band."""
+    n_q, n_k = sq // bq, sq // bk
+    assert pallas_ops._band_steps(by_key, bq, bk, window, n_q, n_k) == want
+    first, last = pallas_ops._band(by_key, bq, bk, window, n_q)
+    touch = np.zeros((n_q, n_k), bool)
+    for i in range(n_q):
+        for j in range(n_k):
+            t = np.arange(i * bq, (i + 1) * bq)[:, None]
+            u = np.arange(j * bk, (j + 1) * bk)[None, :]
+            touch[i, j] = ((u <= t) & (t - u < window)).any()
+    for o in range(n_k if by_key else n_q):
+        line = np.flatnonzero(touch[:, o] if by_key else touch[o])
+        assert (int(first(np.arange(o, o + 1, dtype=np.int32))[0]),
+                int(last(np.arange(o, o + 1, dtype=np.int32))[0])) == (
+                    line[0], line[-1])
+
+
+@pytest.mark.parametrize("s, tile, window", [
+    (8192, None, 512), (2048, 128, 512), (1024, 128, 200), (1024, 256, 1),
+    (512, 128, 4096)])
+def test_flash_tile_counter_counts_the_band(_interpret_mode, s, tile,
+                                            window):
+    """With a window ``flash_tiles_total`` counts the band's tiles: by a
+    walk over every tile's corners here.  At the cell's shape (8192, the
+    shape's own 512 x 512 tiles, window 512) a head's forward call
+    visits 31 of the triangle's 136, every one masked."""
+    b, h, d = 1, 2, 64
+    t = tile or 512
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32)
+    kinds = ("square", "visited", "masked")
+    before = [_tiles(kind) for kind in kinds]
+    jax.eval_shape(
+        lambda q, k, v: pallas_ops._pallas_flash_packed(
+            q, k, v, h, d, causal=True, tile=tile and (tile, tile),
+            window=window), x, x, x)
+    visited = masked = 0
+    for q0 in range(0, s, t):
+        for k0 in range(0, s, t):
+            rows = np.arange(q0, q0 + t)[:, None]
+            cols = np.arange(k0, k0 + t)[None, :]
+            keep = (cols <= rows) & (rows - cols < window)
+            visited += keep.any()
+            masked += keep.any() and not keep.all()
+    n = s // t
+    assert [_tiles(kind) - was for kind, was in zip(kinds, before)] == \
+        [b * h * w for w in (n * n, visited, masked)]
+    if (s, tile, window) == (8192, None, 512):
+        assert (visited, masked, n * (n + 1) // 2) == (31, 31, 136)
+
+
+def test_flash_attention_window_in_every_form(_interpret_mode, monkeypatch):
+    """The public op with a window: the packed kernels (GQA 4 on 2, as
+    one of the differential calls), the composed form where no kernel
+    takes the shape (24 heads of 48 go to the [BH, S, D] kernels without
+    a window and to the composed form with one; the CPU without the
+    interpreter), and with dropout; a window needs the causal mask."""
+    rng = np.random.RandomState(29)
+
+    def draw(s, h, hkv, d):
+        return tuple(jnp.asarray(rng.randn(1, s, n, d).astype(np.float32)
+                                 * 0.5) for n in (h, hkv, hkv))
+
+    def check(q, k, v, window):
+        rep = q.shape[2] // k.shape[2]
+        f = lambda *a: pallas_ops.flash_attention.raw(      # noqa: E731
+            *a, causal=True, window=window)
+        g = lambda q_, k_, v_: _banded_oracle(              # noqa: E731
+            q_, jnp.repeat(k_, rep, 2), jnp.repeat(v_, rep, 2), window)
+        (out, vjp), (ref, ref_vjp) = jax.vjp(f, q, k, v), jax.vjp(g, q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-5)
+        w = jnp.asarray(rng.randn(*out.shape).astype(np.float32))
+        for got, want in zip(vjp(w), ref_vjp(w)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=5e-4, atol=5e-5)
+
+    visited = _tiles("visited")
+    check(*draw(512, 4, 2, 64), 100)
+    assert _tiles("visited") > visited           # the packed kernels ran
+    assert pallas_ops._attention_form(3, 48, 256, 256) == "bh"
+    visited = _tiles("visited")
+    check(*draw(256, 3, 3, 48), 100)
+    assert _tiles("visited") == visited
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+    check(*draw(192, 4, 2, 16), 50)              # the composed form
+    q, k, v = draw(128, 2, 2, 16)
+    out = pallas_ops.flash_attention.raw(q, k, v, causal=True, window=128)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(pallas_ops.flash_attention.raw(
+            q, k, v, causal=True)), rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="causal band"):
+        pallas_ops.flash_attention.raw(q, k, v, window=16)
+    with pytest.raises(ValueError, match="causal band"):
+        pallas_ops.flash_attention.raw(q, k, v, causal=True, window=0)
+
+
+# sha256 of the lowered text (``jax.jit(...).lower(...).as_text()``, the
+# kernels interpreted, which spells every kernel out as HLO) of the
+# public op's forward and backward pass without a window, at the two
+# rehearsal shapes of the cells that share the packed kernels, as the
+# commit before the window (PR 37) lowered them: ``window=None`` is that
+# program byte for byte.  A PR that changes the kernels themselves pins
+# its own.
+_PARENTS_TEXT = {
+    (4, 128, 4, 64):
+        "1cf1afb983465a4e4d84f292f20983de464d142eda26693bbadbc3e032b6b787",
+    (2, 128, 4, 16, 2):
+        "b8062db8d47745568a401a06a1645cc2eef8518ba1ca9e3e5e860a2491299de1",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_PARENTS_TEXT), ids=str)
+def test_no_window_lowers_to_the_parents_text(_interpret_mode, shape):
+    import hashlib
+    b, s, h, d = shape[:4]
+    hkv = shape[4] if len(shape) > 4 else h
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)
+
+    def grads(window):
+        kw = {} if window == "absent" else {"window": window}
+        return lambda q_, k_, v_, w_: jax.grad(
+            lambda *a: (pallas_ops.flash_attention.raw(
+                *a, causal=True, **kw) * w_).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q_, k_, v_)
+
+    texts = [jax.jit(grads(window)).lower(q, kv, kv, q).as_text()
+             for window in ("absent", None)]
+    assert texts[0] == texts[1]
+    assert hashlib.sha256(texts[0].encode()).hexdigest() == \
+        _PARENTS_TEXT[shape]
+    assert jax.jit(grads(64)).lower(q, kv, kv, q).as_text() != texts[0]

@@ -567,3 +567,136 @@ def test_a_batch_of_two_sequences_through_the_mixer(monkeypatch):
         np.testing.assert_allclose(got[2][name], b, rtol=1e-3,
                                    atol=1e-4 * float(jnp.abs(b).max()),
                                    err_msg=name)
+
+
+# --------------------------------------------------------------------------
+# the scan of Mamba-1: a step size a channel, a decay a channel and state
+# --------------------------------------------------------------------------
+from benchmarks.families import sambay as s6_family           # noqa: E402
+
+
+def s6_inputs(seq, channels, state, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)  # noqa
+    return (normal(seq, channels),
+            jnp.exp(jnp.asarray(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                            (seq, channels)), jnp.float32)),
+            -jnp.asarray(rng.uniform(1.0, 16.0, (channels, state)),
+                         jnp.float32),
+            normal(seq, state), normal(seq, state),
+            jnp.asarray(rng.standard_normal((channels,)), jnp.float32),
+            normal(seq, channels))
+
+
+@pytest.mark.parametrize("seq, chunk, bytes_at_once, form", [
+    pytest.param(64, 64, 1 << 28, "sequential", id="chunk_is_the_sequence"),
+    pytest.param(64, 16, 1 << 28, "chunked", id="four_chunks-all_at_once"),
+    pytest.param(96, 8, 1, "chunked", id="twelve_chunks-one_at_once"),
+    pytest.param(96, 8, 5 * 8 * 24 * 4 * 4, "chunked",
+                 id="twelve_chunks-at_once_no_divisor"),
+    pytest.param(64, 1, 1 << 28, "chunked", id="a_position_a_chunk"),
+    pytest.param(60, 16, 1 << 28, "sequential", id="no_whole_chunks"),
+])
+def test_selective_scan_is_the_recurrence_values_and_six_gradients(
+        monkeypatch, seq, chunk, bytes_at_once, form):
+    """``selective_scan`` against the benchmark's reference, the
+    recurrence a position at a time: y and the gradients by x, dt, A, B,
+    C and D, over several chunks, some at a time in the walk back."""
+    monkeypatch.setattr(ssm, "SELECTIVE_BYTES_AT_ONCE", bytes_at_once)
+    channels, state = 24, 4
+    assert ssm.selective_scan_form(seq, chunk) == form
+    *inputs, w = s6_inputs(seq, channels, state)
+
+    def weighted(scan):
+        return jax.value_and_grad(
+            lambda *a: (scan(*a) * w).sum(), argnums=tuple(range(6)))(*inputs)
+
+    got_y, got = weighted(lambda *a: ssm.selective_scan(*a, chunk))
+    want_y, want = weighted(s6_family.selective_scan)
+    assert float(got_y) == pytest.approx(float(want_y), rel=1e-5)
+    for name, a, b in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max()),
+                                   err_msg=name)
+    np.testing.assert_allclose(
+        ssm.selective_scan(*inputs, chunk), s6_family.selective_scan(*inputs),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_selective_scan_keeps_float32_states_for_bf16_inputs():
+    """x, B and C in bf16 (as under O2), dt and A float32: y comes back
+    bf16, the state and the sums are float32, so the result is the
+    float32 recurrence's on the same rounded inputs to bf16's last
+    place; the gradients keep their inputs' dtypes."""
+    x, dt, A, B, C, D, w = s6_inputs(128, 16, 4, seed=1)
+    xb, Bb, Cb = (a.astype(jnp.bfloat16) for a in (x, B, C))
+    y = ssm.selective_scan(xb, dt, A, Bb, Cb, D, 16)
+    assert y.dtype == jnp.bfloat16
+    want = s6_family.selective_scan(*(a.astype(jnp.float32)
+                                      for a in (xb, dt, A, Bb, Cb, D)))
+    np.testing.assert_allclose(y.astype(jnp.float32), want, rtol=1e-2,
+                               atol=1e-2 * float(jnp.abs(want).max()))
+    grads = jax.grad(lambda *a: (ssm.selective_scan(*a, 16) * w).astype(
+        jnp.float32).sum(), argnums=tuple(range(6)))(xb, dt, A, Bb, Cb, D)
+    assert [g.dtype for g in grads] == [
+        jnp.bfloat16, jnp.float32, jnp.float32, jnp.bfloat16, jnp.bfloat16,
+        jnp.float32]
+
+
+def test_selective_scan_survives_steps_that_forget_everything():
+    """A step of 50 at a decay of -16 is exp(-800): the state forgets
+    all it held, and nothing overflows on the way (no exponential of a
+    positive sum anywhere)."""
+    x, dt, A, B, C, D, w = s6_inputs(64, 8, 4, seed=2)
+    dt = dt.at[10:20].set(50.0)
+    y, vjp = jax.vjp(lambda *a: ssm.selective_scan(*a, 8), x, dt, A, B, C, D)
+    want, ref_vjp = jax.vjp(s6_family.selective_scan, x, dt, A, B, C, D)
+    assert bool(jnp.isfinite(y).all())
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
+    for a, b in zip(vjp(w), ref_vjp(w)):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(a, b, rtol=1e-3,
+                                   atol=1e-4 * float(jnp.abs(b).max()))
+
+
+def test_selective_scans_backward_pass_is_given_inputs_and_chunk_starts():
+    """What the forward pass keeps: the six inputs and the state each
+    chunk starts from, ``[chunks, N, C]``; no state of any other
+    position."""
+    *inputs, _ = s6_inputs(64, 24, 4)
+    _, kept = ssm._selective_scan_fwd(*inputs, 16)
+    assert [k.shape for k in kept[:6]] == [a.shape for a in inputs]
+    assert kept[6].shape == (4, 4, 24) and len(kept) == 7
+
+
+@pytest.mark.parametrize("seq,chunk,chunks", [
+    (8192, 64, 128), (2048, 32, 64), (128, 8, 16), (64, 8, 8), (16, 4, 4),
+    (60, 4, 15), (61, 4, 1), (1, 1, 1)])
+def test_the_chunk_follows_from_the_sequence(seq, chunk, chunks):
+    """The power of two at or under the root of the length, so that the
+    steps inside a chunk and from chunk to chunk are fewest together; a
+    length that is no whole number of them goes a position at a time."""
+    assert ssm.selective_chunk(seq) == chunk
+    assert ssm.selective_scan_chunks(seq) == chunks
+    assert ssm.selective_scan_state_bytes(seq, 24, 4) == chunks * 24 * 4 * 4
+    x, dt, A, B, C, D, _ = s6_inputs(min(seq, 128), 8, 4)
+    np.testing.assert_array_equal(
+        ssm.selective_scan(x, dt, A, B, C, D),
+        ssm.selective_scan(x, dt, A, B, C, D,
+                           ssm.selective_chunk(x.shape[0])))
+
+
+def test_the_cells_scan_keeps_128_states():
+    # 128 chunks of 64, 5120 channels of 16 states
+    assert ssm.selective_scan_state_bytes(8192, 5120, 16) == 41943040
+
+
+def test_causal_conv_silu_is_the_convolution_and_silu():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((32, 8)), jnp.float32)
+    weight = jnp.asarray(rng.standard_normal((8, 4)), jnp.float32)
+    bias = jnp.asarray(rng.standard_normal((8,)), jnp.float32)
+    np.testing.assert_allclose(
+        ssm.causal_conv_silu(x, weight, bias),
+        jax.nn.silu(s6_family.causal_conv(x, weight, bias)), rtol=1e-5,
+        atol=1e-6)
