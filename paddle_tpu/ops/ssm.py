@@ -3,15 +3,17 @@
 channel and a decay a channel and state: :func:`selective_scan`, at the
 end of this file), and the causal depthwise convolution in front of
 either; a sequence at a time.  Each has two forms, and
-:func:`scan_form` and :func:`conv_form` name the one that runs, from
-platform and shape: on a TPU, for shapes that fill lane groups and whole
-chunks or tiles, the Mosaic kernels of ``ops/ssm_kernels.py`` (a chunk's
-matrices and the running state in VMEM only) and of
-``ops/ssm_conv_kernels.py`` (the taps, the bias, the SiLU and the split
-into x, B and C in one call, read where the in-projection wrote them;
-the backward pass's shifted terms in VMEM only); everywhere else (the
-CPU, odd shapes) the plain XLA operations below, which are also what the
-kernels are checked against.
+:func:`scan_form`, :func:`selective_scan_form` and :func:`conv_form` name
+the one that runs, from platform and shape: on a TPU, for shapes that
+fill lane groups and whole chunks or tiles, the Mosaic kernels of
+``ops/ssm_kernels.py`` (a chunk's matrices and the running state in VMEM
+only), of ``ops/ssm_s6_kernels.py`` (Mamba-1: a block of channels' state
+in VMEM from the first position to the last, forward in one call and the
+walk back in one) and of ``ops/ssm_conv_kernels.py`` (the taps, the bias,
+the SiLU and the split into x, B and C in one call, read where the
+in-projection wrote them; the backward pass's shifted terms in VMEM
+only); everywhere else (the CPU, odd shapes) the plain XLA operations
+below, which are also what the kernels are checked against.
 
 The recurrence, for head h with state ``H [P, N]`` (P the head's width,
 N the state's), ``a_t = dt_t A`` (A < 0):
@@ -45,7 +47,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import pallas_ops, ssm_conv_kernels, ssm_kernels
+from . import pallas_ops, ssm_conv_kernels, ssm_kernels, ssm_s6_kernels
 
 _LANES = 128
 # the XLA form's: chunks whose [heads, Q, Q] matrices are alive together,
@@ -315,33 +317,57 @@ def scan_state_bytes(seq: int, heads: int, width: int, state: int,
 #
 # B and C are shared by all channels, every channel has its own N decays,
 # and so no product of matrices computes it (the state-space duality
-# needs one decay a head).  In chunks of Q positions, all chunks side by
-# side: Q steps of the recurrence from nothing, each over ``[chunks, N,
-# channels]``; the state each chunk starts from, chunk to chunk; and what
-# that state adds to y, ``exp(A cumsum(dt))`` of it, which needs no
-# step.  The backward pass is given the inputs and the chunks' starting
-# states and nothing else: the adjoint recurrence from nothing and chunk
-# to chunk likewise, then some chunks at a time (``SELECTIVE_BYTES_AT_ONCE``
+# needs one decay a head).  On a TPU it is the two Mosaic kernels of
+# ``ops/ssm_s6_kernels.py``: the recurrence itself, a position after
+# another, with a block of channels' state in VMEM and registers from
+# the first position to the last, and one walk back that carries the
+# state's gradient the same way.  Everywhere else, and as what the
+# kernels are tested against, it is XLA operations in chunks of Q
+# positions, all chunks side by side: Q steps of the recurrence from
+# nothing, each over ``[chunks, N, channels]``; the state each chunk
+# starts from, chunk to chunk; and what that state adds to y, ``exp(A
+# cumsum(dt))`` of it, which needs no step.  That form's backward pass is
+# given the inputs and the chunks' starting states and nothing else (as
+# the kernels' is): the adjoint recurrence from nothing and chunk to
+# chunk likewise, then some chunks at a time (``SELECTIVE_BYTES_AT_ONCE``
 # of states) their states again and, walking back through them, the
 # gradients.  Everything is float32 but what is read and written.
 SELECTIVE_BYTES_AT_ONCE = 1 << 28
 
 
 def selective_chunk(seq: int) -> int:
-    """Q, from the sequence's length: the power of two at or under its
-    square root.  A call takes Q steps inside the chunks and ``seq / Q``
-    from chunk to chunk one after another, fewest at the root (64 at 8192
-    positions, where the chip read the same time from 32 to 128: PERF.md
-    section 6, PR 38), and keeps ``seq / Q`` states for the walk back."""
-    return 1 << (math.isqrt(max(int(seq), 1)).bit_length() - 1)
+    """Q, from the sequence's length.  Where the kernels run at all (a
+    TPU, or the interpreter), the longest of their chunks that divides it
+    (128 at 8192 positions: a visit's blocks of all 5120 channels fill 32
+    MB of VMEM), for a shape they refuse too: the XLA form read the same
+    time on the chip from 32 to 128 (PERF.md section 6, PR 38).
+    Everywhere else, and for a length no kernel chunk divides, the power
+    of two at or under its square root: the XLA form takes Q steps inside
+    the chunks and ``seq / Q`` from chunk to chunk one after another,
+    fewest at the root (64 at 8192 positions).  Either way ``seq / Q``
+    states are kept for the walk back."""
+    seq = int(seq)
+    kernels = ssm_s6_kernels.chunk_of(seq) if pallas_ops._kernels_enabled() \
+        else 0
+    return kernels or 1 << (math.isqrt(max(seq, 1)).bit_length() - 1)
 
 
-def selective_scan_form(seq: int, chunk: int) -> str:
-    """Which form of :func:`selective_scan` runs, from the shape:
-    ``"chunked"`` where the sequence is more than one whole chunk,
-    ``"sequential"`` (the recurrence a position at a time, and jax's own
-    derivative of it) for anything else.  Both are XLA operations on
-    every platform: there is no kernel yet."""
+def selective_scan_form(seq: int, chunk: int, channels: int = _LANES,
+                        state: int = 16, itemsize: int = 2) -> str:
+    """Which form of :func:`selective_scan` runs, from platform and
+    shape: ``"kernels"`` (``ops/ssm_s6_kernels.py``) on a TPU (or under
+    the interpreter) where the channels are whole lane groups, the states
+    whole sublane tiles, the sequence a whole number of chunks of whole
+    sixteen rows, and a visit's blocks fit VMEM
+    (``ssm_s6_kernels.fits``); else ``"chunked"`` where the sequence is
+    more than one whole chunk, and ``"sequential"`` (the recurrence a
+    position at a time, and jax's own derivative of it) for anything
+    else: XLA operations both.  Channels, states and the bytes of an
+    element of x default to a shape the kernels take, so that the length
+    and the chunk alone answer for such a shape."""
+    if pallas_ops._kernels_enabled() and ssm_s6_kernels.fits(
+            seq, channels, state, chunk, itemsize):
+        return "kernels"
     return "chunked" if seq % chunk == 0 and seq > chunk else "sequential"
 
 
@@ -518,16 +544,20 @@ def selective_scan(x, dt, A, B, C, D, chunk: Optional[int] = None):
     every sum float32.  ``chunk`` is :func:`selective_chunk`'s unless a
     test gives another: it changes how y is computed and not y."""
     chunk = selective_chunk(x.shape[0]) if chunk is None else int(chunk)
-    if selective_scan_form(x.shape[0], chunk) == "chunked":
+    form = selective_scan_form(x.shape[0], chunk, x.shape[1], A.shape[1],
+                               x.dtype.itemsize)
+    if form == "kernels":
+        return ssm_s6_kernels.scan(x, dt, A, B, C, D, chunk)
+    if form == "chunked":
         return _selective_scan(x, dt, A, B, C, D, chunk)
     return _selective_sequential(x, dt, A, B, C, D)
 
 
 def selective_scan_chunks(seq: int) -> int:
-    """Chunks a call of :func:`selective_scan` walks (1: sequential)."""
+    """Chunks a call of :func:`selective_scan` walks, whichever form (1:
+    sequential)."""
     chunk = selective_chunk(seq)
-    return seq // chunk if selective_scan_form(seq, chunk) == "chunked" \
-        else 1
+    return 1 if seq % chunk else seq // chunk
 
 
 def selective_scan_state_bytes(seq: int, channels: int, state: int) -> int:

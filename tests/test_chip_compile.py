@@ -393,19 +393,27 @@ def _flash_window_20_on_10(topo, monkeypatch):
 
 def _selective_scan(topo, monkeypatch):
     """The same cell's selective scan at ``[8192, 5120]``, state 16,
-    chunks of 64, forward and the walk back: XLA operations (no kernel
-    yet), compiled so that what the chip's compiler makes of the
-    chunked form is held: it fits."""
-    from paddle_tpu.ops import ssm
+    bf16, differentiated: the two Mosaic kernels of
+    ``ops/ssm_s6_kernels.py`` (the forward call, which also writes the
+    chunks' starting states, and the walk back) at the chunk the length
+    gives, 128, and all 5120 channels a visit.  Held: the cell's shape
+    compiles for v5e, so its inner loops' row loads, the rolls down the
+    sublanes and the product that sums dB and dC are all accepted, and a
+    visit's blocks fit the VMEM the calls ask for."""
+    from paddle_tpu.ops import ssm, ssm_s6_kernels
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
     spec = _one_chip_spec(topo)
     seq, channels, state = 8192, 5120, 16
+    assert ssm.selective_chunk(seq) == 128
+    assert ssm.selective_scan_form(seq, 128, channels, state, 2) == "kernels"
+    assert ssm_s6_kernels.block_of(128, channels, state, 2) == channels
     args = (spec((seq, channels)), spec((seq, channels), jnp.float32),
             spec((channels, state), jnp.float32), spec((seq, state)),
             spec((seq, state)), spec((channels,), jnp.float32),
             spec((seq, channels)))
 
     def grads(x, dt, A, B, C, D, w):
-        return jax.grad(lambda *a: (ssm.selective_scan(*a, 64) * w).astype(
+        return jax.grad(lambda *a: (ssm.selective_scan(*a) * w).astype(
             jnp.float32).sum(), argnums=tuple(range(6)))(x, dt, A, B, C, D)
 
     return grads, args
@@ -469,7 +477,7 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_conv_bwd(8), 1, None, id="ssm_conv_bwd_s8192_6144_channels"),
     pytest.param(_flash_window_20_on_10, 3, None,
                  id="flash_window_512_20_on_10_heads_of_64_s8192"),
-    pytest.param(_selective_scan, 0, None,
+    pytest.param(_selective_scan, 2, None,
                  id="selective_scan_s8192_5120_channels_state_16"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
@@ -653,8 +661,9 @@ def test_phi4flash_depth6_step_fits_a_v5e(topo, monkeypatch):
     under the configuration's limit (the rule its ``recompute`` was chosen
     by), and its kernels are in it: an attention-kind layer's four calls
     of ``flash_attention``, each forward, dq and dkv, and the forward once
-    more where the layer is recomputed.  The selective scans are XLA
-    operations and hold no site."""
+    more where the layer is recomputed; and, since PR 39, a Mamba layer's
+    selective scan, forward and the walk back, and the forward again where
+    the layer is recomputed, as both are."""
     import numpy as np
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
@@ -695,5 +704,8 @@ def test_phi4flash_depth6_step_fits_a_v5e(topo, monkeypatch):
     # layers 0, 1, 2 and 4 are recomputed: of the attention kinds the
     # window layer alone
     assert config["recompute"] == [0, 1, 2, 4]
-    assert compiled.as_text().count("tpu_custom_call") == sites == \
-        3 * 12 + 4
+    assert sites == 3 * 12 + 4
+    scans = sum(2 + (i in config["recompute"]) for i, kind in enumerate(kinds)
+                if kind in ("mamba", "mamba_memory"))
+    assert compiled.as_text().count("tpu_custom_call") == sites + scans == \
+        40 + 6

@@ -8,7 +8,9 @@ chunks gets (it is refused); and that the backward pass is given the
 inputs and nothing else.  Then the Mosaic kernels of both, interpreted:
 the scan's against the XLA form and the recurrence, the convolution's
 (taps, bias, SiLU and split in one call) against ``causal_conv1d`` +
-SiLU + split, alone and through ``Mamba2Mixer``.
+SiLU + split, alone and through ``Mamba2Mixer``; and Mamba-1's selective
+scan, its XLA forms and its kernels (``ops/ssm_s6_kernels.py``), each
+against the benchmark's recurrence (``families/sambay.py``).
 """
 
 import os
@@ -623,13 +625,22 @@ def test_selective_scan_is_the_recurrence_values_and_six_gradients(
         rtol=1e-5, atol=1e-5)
 
 
-def test_selective_scan_keeps_float32_states_for_bf16_inputs():
+@pytest.mark.parametrize("channels, state, form", [
+    pytest.param(16, 4, "chunked", id="chunked"),
+    pytest.param(256, 16, "kernels", id="kernels"),
+])
+def test_selective_scan_keeps_float32_states_for_bf16_inputs(
+        monkeypatch, channels, state, form):
     """x, B and C in bf16 (as under O2), dt and A float32: y comes back
     bf16, the state and the sums are float32, so the result is the
     float32 recurrence's on the same rounded inputs to bf16's last
-    place; the gradients keep their inputs' dtypes."""
-    x, dt, A, B, C, D, w = s6_inputs(128, 16, 4, seed=1)
+    place; the gradients keep their inputs' dtypes.  The same limits for
+    the XLA form and for the kernels."""
+    if form == "kernels":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    x, dt, A, B, C, D, w = s6_inputs(128, channels, state, seed=1)
     xb, Bb, Cb = (a.astype(jnp.bfloat16) for a in (x, B, C))
+    assert ssm.selective_scan_form(128, 16, channels, state, 2) == form
     y = ssm.selective_scan(xb, dt, A, Bb, Cb, D, 16)
     assert y.dtype == jnp.bfloat16
     want = s6_family.selective_scan(*(a.astype(jnp.float32)
@@ -641,15 +652,31 @@ def test_selective_scan_keeps_float32_states_for_bf16_inputs():
     assert [g.dtype for g in grads] == [
         jnp.bfloat16, jnp.float32, jnp.float32, jnp.bfloat16, jnp.bfloat16,
         jnp.float32]
+    ref = jax.grad(lambda *a: (s6_family.selective_scan(*a) * w).sum(),
+                   argnums=tuple(range(6)))(*(a.astype(jnp.float32) for a in (
+                       xb, dt, A, Bb, Cb, D)))
+    for name, a, b in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), grads, ref):
+        np.testing.assert_allclose(a.astype(jnp.float32), b, rtol=2e-2,
+                                   atol=2e-2 * float(jnp.abs(b).max()),
+                                   err_msg=name)
 
 
-def test_selective_scan_survives_steps_that_forget_everything():
+@pytest.mark.parametrize("seq, channels, state, chunk, form", [
+    pytest.param(64, 8, 4, 8, "chunked", id="chunked"),
+    pytest.param(64, 128, 8, 16, "kernels", id="kernels"),
+])
+def test_selective_scan_survives_steps_that_forget_everything(
+        monkeypatch, seq, channels, state, chunk, form):
     """A step of 50 at a decay of -16 is exp(-800): the state forgets
     all it held, and nothing overflows on the way (no exponential of a
-    positive sum anywhere)."""
-    x, dt, A, B, C, D, w = s6_inputs(64, 8, 4, seed=2)
+    positive sum anywhere, and no division by a decay)."""
+    if form == "kernels":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    x, dt, A, B, C, D, w = s6_inputs(seq, channels, state, seed=2)
     dt = dt.at[10:20].set(50.0)
-    y, vjp = jax.vjp(lambda *a: ssm.selective_scan(*a, 8), x, dt, A, B, C, D)
+    assert ssm.selective_scan_form(seq, chunk, channels, state, 4) == form
+    y, vjp = jax.vjp(lambda *a: ssm.selective_scan(*a, chunk), x, dt, A, B,
+                     C, D)
     want, ref_vjp = jax.vjp(s6_family.selective_scan, x, dt, A, B, C, D)
     assert bool(jnp.isfinite(y).all())
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
@@ -686,9 +713,191 @@ def test_the_chunk_follows_from_the_sequence(seq, chunk, chunks):
                            ssm.selective_chunk(x.shape[0])))
 
 
-def test_the_cells_scan_keeps_128_states():
-    # 128 chunks of 64, 5120 channels of 16 states
-    assert ssm.selective_scan_state_bytes(8192, 5120, 16) == 41943040
+@pytest.mark.parametrize("interpreted, chunk, chunks, kept", [
+    # the XLA form: 128 chunks of 64, 5120 channels of 16 states
+    pytest.param(False, 64, 128, 41943040, id="chunked"),
+    # the kernels: 64 chunks of 128
+    pytest.param(True, 128, 64, 20971520, id="kernels"),
+])
+def test_the_cells_scan_keeps_a_state_a_chunk(monkeypatch, interpreted,
+                                              chunk, chunks, kept):
+    """What ``models/sambay.py`` counts, with the arguments it gives: the
+    chunks of the form that runs and the bytes of their starting
+    states."""
+    if interpreted:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.selective_chunk(8192) == chunk
+    assert ssm.selective_scan_chunks(8192) == chunks
+    assert ssm.selective_scan_state_bytes(8192, 5120, 16) == kept
+
+
+# --------------------------------------------------------------------------
+# the scan of Mamba-1: the kernels, interpreted
+# --------------------------------------------------------------------------
+from paddle_tpu.ops import ssm_s6_kernels                     # noqa: E402
+
+
+def _s6_visits(kind):
+    from paddle_tpu.observability import metrics
+    return metrics.registry().counter(
+        "s6_scan_kernel_visits_total", labels={"kind": kind}).collect()
+
+
+@pytest.mark.parametrize("seq, chunk, channels, state, room, blocks", [
+    pytest.param(64, 16, 256, 8, None, 1, id="four_chunks-two_lane_groups"),
+    pytest.param(64, 16, 256, 8, 700000, 2,
+                 id="four_chunks-two_blocks_of_channels"),
+    pytest.param(48, 16, 384, 16, 1300000, 3,
+                 id="three_chunks-three_blocks-sixteen_states"),
+    pytest.param(32, 32, 512, 8, None, 1,
+                 id="chunk_is_the_sequence-four_lane_groups"),
+    pytest.param(256, None, 128, 8, None, 1, id="the_chunk_the_length_gives"),
+])
+def test_selective_scan_kernels_are_the_recurrence_values_and_six_gradients(
+        monkeypatch, seq, chunk, channels, state, room, blocks):
+    """The two Mosaic kernels, interpreted, against the benchmark's
+    reference, the recurrence a position at a time, in float32: y and the
+    gradients by x, dt, A, B, C and D; over more than two chunks (the
+    state and ``g`` carried in scratch), more than one block of channels
+    (dB and dC summed from the blocks' shares), lane groups side by side,
+    and the chunk the length gives."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    if room is not None:
+        monkeypatch.setattr(ssm_s6_kernels, "_ROOM", room)
+    q = chunk or ssm.selective_chunk(seq)
+    assert ssm.selective_scan_form(seq, q, channels, state, 4) == "kernels"
+    assert channels // ssm_s6_kernels.block_of(q, channels, state,
+                                               4) == blocks
+    *inputs, w = s6_inputs(seq, channels, state)
+
+    def weighted(scan):
+        return jax.value_and_grad(
+            lambda *a: (scan(*a) * w).sum(), argnums=tuple(range(6)))(*inputs)
+
+    before = _s6_visits("forward"), _s6_visits("backward")
+    got_y, got = weighted(lambda *a: ssm.selective_scan(*a, chunk))
+    # a visit a chunk and block, forward and backward
+    assert (_s6_visits("forward") - before[0],
+            _s6_visits("backward") - before[1]) == (seq // q * blocks,) * 2
+    want_y, want = weighted(s6_family.selective_scan)
+    assert float(got_y) == pytest.approx(float(want_y), rel=1e-5)
+    for name, a, b in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max()),
+                                   err_msg=name)
+    np.testing.assert_allclose(
+        ssm.selective_scan(*inputs, chunk), s6_family.selective_scan(*inputs),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("interpreted, shape, form", [
+    # (seq, chunk, channels, state, bytes of an element of x)
+    pytest.param(True, (8192, 128, 5120, 16, 2), "kernels",
+                 id="the_cells_shape"),
+    pytest.param(True, (8192, 64, 5120, 16, 2), "kernels",
+                 id="the_cells_shape-a_chunk_of_64"),
+    pytest.param(False, (8192, 128, 5120, 16, 2), "chunked",
+                 id="no_tpu_and_no_interpreter"),
+    pytest.param(True, (8192, 128, 5100, 16, 2), "chunked",
+                 id="channels_that_are_no_lane_groups"),
+    pytest.param(True, (8192 + 64, 128, 5120, 16, 2), "sequential",
+                 id="a_ragged_sequence"),
+    pytest.param(True, (8192, 8, 5120, 16, 2), "chunked",
+                 id="a_chunk_of_half_a_turn"),
+    pytest.param(True, (8192, 128, 5120, 8, 2), "chunked",
+                 id="bf16_states_that_are_half_a_tile"),
+    pytest.param(True, (8192, 128, 5120, 8, 4), "kernels",
+                 id="float32_and_eight_states"),
+    pytest.param(True, (8192, 128, 5120, 4096, 2), "chunked",
+                 id="a_lane_group_that_fills_vmem"),
+    pytest.param(True, (64, 8, 24, 4, 4), "chunked", id="the_tests_sizes"),
+])
+def test_selective_scan_form_reads_platform_and_shape(monkeypatch,
+                                                      interpreted, shape,
+                                                      form):
+    if interpreted:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm.selective_scan_form(*shape) == form
+
+
+@pytest.mark.parametrize("seq, channels, state, form", [
+    pytest.param(192, 100, 8, "chunked",
+                 id="channels_that_are_no_lane_group"),
+    pytest.param(72, 128, 8, "chunked", id="a_sequence_of_no_whole_turns"),
+    pytest.param(61, 128, 8, "sequential", id="a_ragged_sequence"),
+])
+def test_a_shape_the_kernels_refuse_takes_an_xla_form_and_agrees(
+        monkeypatch, seq, channels, state, form):
+    """With the kernels on: the refused shape's result is the XLA form's,
+    to the bit, values and gradients, and no visit is counted."""
+    *inputs, w = s6_inputs(seq, channels, state, seed=3)
+
+    def both(chunk=None):
+        return jax.value_and_grad(
+            lambda *a: (ssm.selective_scan(*a, chunk) * w).sum(),
+            argnums=tuple(range(6)))(*inputs)
+
+    with monkeypatch.context() as m:
+        m.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        chunk = ssm.selective_chunk(seq)
+        assert ssm.selective_scan_form(seq, chunk, channels, state,
+                                       4) == form
+        before = _s6_visits("forward"), _s6_visits("backward")
+        with_kernels_on = both()
+        assert (_s6_visits("forward"), _s6_visits("backward")) == before
+    for a, b in zip(jax.tree_util.tree_leaves(with_kernels_on),
+                    jax.tree_util.tree_leaves(both(chunk))):
+        np.testing.assert_array_equal(a, b)
+    want = jax.grad(lambda *a: (s6_family.selective_scan(*a) * w).sum(),
+                    argnums=tuple(range(6)))(*inputs)
+    for a, b in zip(with_kernels_on[1], want):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max()))
+
+
+def test_s6_kernel_visits_are_counted_as_the_calls_are_traced(monkeypatch):
+    """``s6_scan_kernel_visits_total{kind}``: a recomputed layer's trace
+    at the cell's shape reads two forward calls (the forward pass, and the
+    forward again for the backward pass) and one backward, each the
+    grid's 64 visits (one block of all 5120 channels, 64 chunks of 128);
+    an XLA form adds 0."""
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((8192, 5120), jnp.bfloat16), ((8192, 5120), f32),
+        ((5120, 16), f32), ((8192, 16), jnp.bfloat16),
+        ((8192, 16), jnp.bfloat16), ((5120,), f32))]
+
+    def traced():
+        @jax.checkpoint         # anew: a trace that is cached counts nothing
+        def layer(*a):
+            return ssm.selective_scan(*a).astype(f32).sum()
+
+        before = _s6_visits("forward"), _s6_visits("backward")
+        jax.eval_shape(jax.grad(layer, argnums=tuple(range(6))), *shapes)
+        return (_s6_visits("forward") - before[0],
+                _s6_visits("backward") - before[1])
+
+    assert traced() == (0, 0)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ssm_s6_kernels.block_of(128, 5120, 16, 2) == 5120
+    assert traced() == (128, 64)
+
+
+def test_the_s6_kernels_backward_pass_is_given_inputs_and_chunk_starts(
+        monkeypatch):
+    """What the kernels' forward pass keeps: the six inputs themselves
+    and the state each chunk starts from, ``[chunks * N, C]`` float32; no
+    float32 copy of x, no state of any other position."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    *inputs, _ = s6_inputs(64, 128, 8)
+    inputs[0] = inputs[0].astype(jnp.bfloat16)
+    y, kept = ssm_s6_kernels._scan_fwd(*inputs, 16)
+    assert y.shape == (64, 128) and len(kept) == 7
+    for residual, given in zip(kept[:6], inputs):
+        assert residual is given
+    assert kept[6].shape == (4 * 8, 128) and kept[6].dtype == jnp.float32
+    # the first chunk starts from nothing
+    np.testing.assert_array_equal(kept[6][:8], 0.0)
 
 
 def test_causal_conv_silu_is_the_convolution_and_silu():
