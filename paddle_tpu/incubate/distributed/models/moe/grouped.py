@@ -15,7 +15,7 @@ matrices an expert has:
     softmax router   p = softmax(y W_r);  T_t = the k largest p
                      g[t, e] = p[t, e] / sum of p over T_t
     sigmoid router   s = sigmoid(y W_r);  T_t = the k largest s + b
-                     g[t, e] = scale * s[t, e] / (sum of s over T_t + 1e-20)
+                     g[t, e] = scale * s[t, e] / (sum of s over T_t + eps)
                      (b chooses and does not weigh; no gradient reaches it)
     SiLU-gated       expert_e(y) = (silu(y W1_e) * (y W3_e)) W2_e
     squared ReLU     expert_e(y) = relu(y W1_e)^2 W2_e
@@ -79,17 +79,17 @@ def route(logits, k: int):
     return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
-def route_sigmoid(logits, bias, k: int, scale: float):
+def route_sigmoid(logits, bias, k: int, scale: float, eps: float = 1e-20):
     """(experts ``[T, k]`` int32, gates ``[T, k]`` float32) from float32
     router logits over all experts: the k largest of ``sigmoid(logits) +
     bias`` are chosen, and weighed by the sigmoid alone, normalised over
-    the k chosen and multiplied by ``scale``."""
+    the k chosen (their sum plus ``eps``) and multiplied by ``scale``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, experts = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
     top = jnp.take_along_axis(scores, experts, axis=-1)
     return (experts.astype(jnp.int32),
-            scale * top / (top.sum(-1, keepdims=True) + 1e-20))
+            scale * top / (top.sum(-1, keepdims=True) + eps))
 
 
 def plan(experts, first: int, held: int) -> Plan:

@@ -25,3 +25,6 @@ from .nemotron_h import (  # noqa
 from .sambay import (  # noqa
     SambaYConfig, SambaYModel, SambaYForCausalLM,
     SambaYPretrainingCriterion, sambay_tiny)
+from .lfm2_moe import (  # noqa
+    Lfm2MoeConfig, Lfm2MoeModel, Lfm2MoeForCausalLM,
+    Lfm2MoePretrainingCriterion, lfm2_moe_tiny)

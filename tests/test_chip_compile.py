@@ -508,28 +508,18 @@ def _shifted_terms_in_hbm(text: str, channels: int):
             and line.split(" fusion(")[0].count(shape) >= 4]
 
 
-def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
-    """The whole training step of the cell granite4h-micro-stage0-s8192
-    (ten layers at published widths, b1 x s8192, bf16 O2 with float32
-    master weights, every layer recomputed), as ``DistributedRunner``
-    builds it, compiled for one described v5e chip: what it needs on the
-    device stays under the configuration's limit, and the attention
-    layer's kernels (forward, the forward again, dq, dkv), the nine
-    scans' and the nine convolutions' (three each a layer) are in it,
-    and the convolution's four shifted gradient terms are not.
-    The parameters are zeros placeholders (``LazyGuard``) and nothing is
-    put on a device: the step is lowered on shapes."""
+def _compiled_step(topo, monkeypatch, driver, config, *more):
+    """(the compiled step, its ``memory_analysis()``, the bytes it needs on
+    a device) of the ``DistributedRunner`` that ``driver.build_runner``
+    makes of ``config`` for one described chip, on one sequence of 8192
+    tokens.  The parameters are zeros placeholders (``LazyGuard``) and
+    nothing is put on a device: the step is lowered on shapes."""
     import numpy as np
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    monkeypatch.syspath_prepend(root)
-    from benchmarks.drivers import train_granite_lm as driver
-    from benchmarks.harness import cells
-    config = cells.load_cell("granite4h-micro-stage0-s8192", root).config
     monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
     prev_mesh = collective.get_mesh()
     try:
         with paddle_tpu.LazyGuard():
-            runner = driver.build_runner(config, 0, topo.devices[:1])
+            runner = driver.build_runner(config, 0, topo.devices[:1], *more)
         monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
         ids = np.zeros((1, 8192), np.int64)
         data = sum(runner._prep_step_args([ids], [ids]), [])
@@ -546,8 +536,29 @@ def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
     finally:
         collective.set_mesh(prev_mesh)
     memory = compiled.memory_analysis()
-    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    return compiled, memory, (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+
+
+def test_granite_stage0_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell granite4h-micro-stage0-s8192
+    (ten layers at published widths, b1 x s8192, bf16 O2 with float32
+    master weights, every layer recomputed), as ``DistributedRunner``
+    builds it, compiled for one described v5e chip: what it needs on the
+    device stays under the configuration's limit, and the attention
+    layer's kernels (forward, the forward again, dq, dkv), the nine
+    scans' and the nine convolutions' (three each a layer) are in it,
+    and the convolution's four shifted gradient terms are not.
+    The parameters are zeros placeholders (``LazyGuard``) and nothing is
+    put on a device: the step is lowered on shapes."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_granite_lm as driver
+    from benchmarks.harness import cells
+    config = cells.load_cell("granite4h-micro-stage0-s8192", root).config
+    compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
+                                            config)
     # 772 160 448 parameters at 14 bytes, and the batch
     assert memory.argument_size_in_bytes == approx(10.81e9, rel=2e-3)
     assert step < config["step_bytes_limit"] == 15.6e9
@@ -582,7 +593,6 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     of ``ops/grouped_matmul.py`` by their names: fourteen an expert
     block, six of them the first window's (two products forward, their
     four gradients) and eight the overflow branch's."""
-    import numpy as np
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks.drivers import train_nemotron_lm as driver
@@ -590,7 +600,6 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     from benchmarks.harness import cells
     config = cells.load_json(os.path.join(
         root, "benchmarks", "configs", "nemotron-3-nano-30b-a3b.json"))
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
     from paddle_tpu.observability import metrics
 
     def product_calls():
@@ -599,28 +608,8 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
         ).collect() for kind in ("fwd", "dlhs", "drhs")]
 
     before = product_calls()
-    prev_mesh = collective.get_mesh()
-    try:
-        with paddle_tpu.LazyGuard():
-            runner = driver.build_runner(config, 0, topo.devices[:1])
-        monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
-        ids = np.zeros((1, 8192), np.int64)
-        data = sum(runner._prep_step_args([ids], [ids]), [])
-        on_chip = NamedSharding(runner.mesh, P())
-
-        def shapes(tree):
-            return jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=on_chip), tree)
-
-        compiled = runner._step_fn.lower(
-            *shapes(runner._sync_val_cache()), shapes(runner._opt_state),
-            *shapes([jnp.float32(0), jnp.uint32(1)] + data)).compile()
-    finally:
-        collective.set_mesh(prev_mesh)
-    memory = compiled.memory_analysis()
-    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
+                                            config)
     # 666 962 944 parameters at 14 bytes, and the batch
     assert memory.argument_size_in_bytes == approx(9.3375e9, rel=1e-3)
     assert step < config["step_bytes_limit"] == 15.6e9
@@ -664,36 +653,14 @@ def test_phi4flash_depth6_step_fits_a_v5e(topo, monkeypatch):
     more where the layer is recomputed; and, since PR 39, a Mamba layer's
     selective scan, forward and the walk back, and the forward again where
     the layer is recomputed, as both are."""
-    import numpy as np
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks.drivers import train_sambay_lm as driver
     from benchmarks.families import sambay as family
     from benchmarks.harness import cells
     config = cells.load_cell("phi4flash-depth6-s8192", root).config
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
-    prev_mesh = collective.get_mesh()
-    try:
-        with paddle_tpu.LazyGuard():
-            runner = driver.build_runner(config, 0, topo.devices[:1])
-        monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
-        ids = np.zeros((1, 8192), np.int64)
-        data = sum(runner._prep_step_args([ids], [ids]), [])
-        on_chip = NamedSharding(runner.mesh, P())
-
-        def shapes(tree):
-            return jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=on_chip), tree)
-
-        compiled = runner._step_fn.lower(
-            *shapes(runner._sync_val_cache()), shapes(runner._opt_state),
-            *shapes([jnp.float32(0), jnp.uint32(1)] + data)).compile()
-    finally:
-        collective.set_mesh(prev_mesh)
-    memory = compiled.memory_analysis()
-    step = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
+                                            config)
     # 697 094 272 parameters at 14 bytes (the LayerNorms' 66 560 hold no
     # bf16 copy), and the batch
     assert memory.argument_size_in_bytes == approx(9.76e9, rel=1e-3)
@@ -709,3 +676,46 @@ def test_phi4flash_depth6_step_fits_a_v5e(topo, monkeypatch):
                 if kind in ("mamba", "mamba_memory"))
     assert compiled.as_text().count("tpu_custom_call") == sites + scans == \
         40 + 6
+
+
+def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell lfm2-8b-a1b-ep4share-s8192 (the
+    published layers 1-5 of LFM2-8B-A1B at published widths, 8 of 32
+    experts, b1 x s8192, bf16 O2 with float32 master weights, nothing
+    recomputed), as ``DistributedRunner`` builds it, compiled for one
+    described v5e chip: what it needs on the device stays a tenth under
+    the configuration's limit (the rule its empty ``recompute`` was chosen
+    by), and its kernels are in it: the attention layer's forward, dq and
+    dkv, and the experts' grouped products, the kernels of
+    ``ops/grouped_matmul.py`` by their names, at ``[16384, 2048] x [8,
+    2048, 1792]`` and its transpose.  The gated short convolution is XLA
+    operations today: no call of its own."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_lfm2_lm as driver
+    from benchmarks.families import lfm2_moe as family
+    from benchmarks.harness import cells
+    config = cells.load_cell("lfm2-8b-a1b-ep4share-s8192", root).config
+    compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
+                                            config, 8192)
+    # 507 820 160 parameters at 14 bytes (the norms' 24 704 hold no bf16
+    # copy), the routers' biases, the step's counts and choices, the batch
+    assert memory.argument_size_in_bytes == approx(7.1098e9, rel=1e-3)
+    assert step < 0.9 * config["step_bytes_limit"] == 0.9 * 15.6e9
+    print(f"compiled step: {step} bytes a device")
+    kinds = family.kinds(config)
+    assert config["recompute"] == []
+    own = driver.kernel_sites(kinds, set())
+    assert own == 3
+    sites = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    products = [line for line in sites if "grouped_dot" in line]
+    print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
+          "experts' grouped products")
+    # an expert layer: nine the first window's (three products forward,
+    # their six gradients), fifteen the overflow branch's (three forward
+    # and, in its loop's backward pass, twelve as the compiler leaves
+    # them: the three again and the gradients)
+    assert len(products) == 24 * sum(k.endswith("_moe") for k in kinds) == 96
+    assert len(sites) - len(products) == own
+    assert "ragged-dot" not in compiled.as_text()
