@@ -22,11 +22,17 @@ copy of it.  With ``dc = dy * C``:
     dB = dz * x;   dx = dz * B
     dweight[:, k] = sum_t dc[t] * z[t - (W - 1) + k]
 
-One form today, XLA operations; :func:`gated_short_conv_form` names the
-form that runs from platform and shape, as ``ssm.conv_form`` does of
-Mamba's convolution, so that a kernel has a place to stand.  The calls
-count themselves as they are traced: ``short_conv_calls_total{kind=
-forward|backward}``.
+Two forms, which :func:`gated_short_conv_form` names from platform and
+shape, as ``ssm.conv_form`` does of Mamba's convolution: ``"kernels"``,
+two Mosaic calls (``ops/short_conv_kernels.py``: ``bcx`` read where the
+in-projection wrote it, the taps' shifted terms in VMEM only, forward in
+one call and the walk back in one), on a TPU or under the interpreter
+where the shape suits them; ``"xla"``, the XLA operations below,
+everywhere else and as the kernels' reference.  Either is the one
+``custom_vjp`` here, given the same inputs.  The calls count themselves as
+they are traced: ``short_conv_calls_total{kind=forward|backward}``
+whatever the form, and ``short_conv_kernel_calls_total{kind=fwd|bwd}``
+the kernels' calls alone.
 """
 
 from __future__ import annotations
@@ -34,11 +40,24 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import pallas_ops, short_conv_kernels
+
 
 def gated_short_conv_form(seq: int, channels: int, width: int) -> str:
     """Which form of :func:`gated_short_conv` runs, from platform and
-    shape: ``"xla"`` everywhere today."""
-    return "xla"
+    shape: ``"kernels"`` on a TPU (or under the interpreter) where the
+    channels are whole lane groups, the sequence a whole number of sublane
+    tiles, the taps reach no further back than the eight rows a visit is
+    given, and a visit's blocks fit the VMEM the call asks for; ``"xla"``
+    everywhere else."""
+    fits = (channels % short_conv_kernels._LANES == 0
+            and 1 <= width <= short_conv_kernels.REACH + 1
+            and short_conv_kernels.fits_vmem(seq, channels, width, 4))
+    return "kernels" if fits and pallas_ops._kernels_enabled() else "xla"
+
+
+def _kernels(bcx, weight) -> bool:
+    return gated_short_conv_form(bcx.shape[0], *weight.shape) == "kernels"
 
 
 def gated_short_conv_bytes(seq: int, channels: int, itemsize: int = 2) -> int:
@@ -86,19 +105,27 @@ def _forward(bcx, weight):
     return (c_gate * conv).astype(bcx.dtype)
 
 
+def _either(bcx, weight):
+    if _kernels(bcx, weight):
+        return short_conv_kernels.forward(bcx, weight)
+    return _forward(bcx, weight)
+
+
 @jax.custom_vjp
 def _gated_short_conv(bcx, weight):
-    return _forward(bcx, weight)
+    return _either(bcx, weight)
 
 
 def _fwd(bcx, weight):
     # the inputs are all the backward pass is given
-    return _forward(bcx, weight), (bcx, weight)
+    return _either(bcx, weight), (bcx, weight)
 
 
 def _bwd(kept, dy):
     _note_call("backward")
     bcx, weight = kept
+    if _kernels(bcx, weight):
+        return short_conv_kernels.backward(bcx, weight, dy)
     width = weight.shape[1]
     w = weight.astype(jnp.float32)
     b, c_gate, x = _gates(bcx)

@@ -356,6 +356,27 @@ def _conv_bwd(groups):
     return build
 
 
+def _short_conv_operands(topo, monkeypatch):
+    """LFM2's gated short convolution at the cell
+    lfm2-8b-a1b-ep4share-s8192's shape: the in-projection's result ``[8192,
+    3 x 2048]``, three taps, and y's gradient."""
+    from paddle_tpu.ops import short_conv
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    assert short_conv.gated_short_conv_form(8192, 2048, 3) == "kernels"
+    return short_conv.gated_short_conv, (spec((8192, 3 * 2048)),
+                                         spec((2048, 3))), spec((8192, 2048))
+
+
+def _short_conv_fwd(topo, monkeypatch):
+    return _short_conv_operands(topo, monkeypatch)[:2]
+
+
+def _short_conv_bwd(topo, monkeypatch):
+    op, args, dy = _short_conv_operands(topo, monkeypatch)
+    return (lambda bcx, w, d: jax.vjp(op, bcx, w)[1](d)), args + (dy,)
+
+
 def _flash_32_on_2(topo, monkeypatch):
     """The same cell's attention block: 32 query heads on 2 key/value
     heads of width 128 at s8192, forward and backward, through the public
@@ -475,6 +496,10 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_conv_bwd(1), 1, None, id="ssm_conv_bwd_s8192_4352_channels"),
     pytest.param(_conv_fwd(8), 1, None, id="ssm_conv_fwd_s8192_6144_channels"),
     pytest.param(_conv_bwd(8), 1, None, id="ssm_conv_bwd_s8192_6144_channels"),
+    pytest.param(_short_conv_fwd, 1, None,
+                 id="short_conv_fwd_s8192_2048_channels_3_taps"),
+    pytest.param(_short_conv_bwd, 1, None,
+                 id="short_conv_bwd_s8192_2048_channels_3_taps"),
     pytest.param(_flash_window_20_on_10, 3, None,
                  id="flash_window_512_20_on_10_heads_of_64_s8192"),
     pytest.param(_selective_scan, 2, None,
@@ -688,16 +713,23 @@ def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
     by), and its kernels are in it: the attention layer's forward, dq and
     dkv, and the experts' grouped products, the kernels of
     ``ops/grouped_matmul.py`` by their names, at ``[16384, 2048] x [8,
-    2048, 1792]`` and its transpose.  The gated short convolution is XLA
-    operations today: no call of its own."""
+    2048, 1792]`` and its transpose, and the gated short convolution's two
+    kernels a convolution layer (``ops/short_conv_kernels.py``), counted
+    by ``short_conv_kernel_calls_total`` as the step is traced."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks.drivers import train_lfm2_lm as driver
     from benchmarks.families import lfm2_moe as family
     from benchmarks.harness import cells
+    from paddle_tpu.observability import metrics
     config = cells.load_cell("lfm2-8b-a1b-ep4share-s8192", root).config
+    calls = [metrics.registry().counter("short_conv_kernel_calls_total",
+                                        labels={"kind": kind})
+             for kind in ("fwd", "bwd")]
+    before = [c.collect() for c in calls]
     compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
                                             config, 8192)
+    assert [c.collect() - b for c, b in zip(calls, before)] == [4, 4]
     # 507 820 160 parameters at 14 bytes (the norms' 24 704 hold no bf16
     # copy), the routers' biases, the step's counts and choices, the batch
     assert memory.argument_size_in_bytes == approx(7.1098e9, rel=1e-3)
@@ -717,5 +749,9 @@ def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
     # and, in its loop's backward pass, twelve as the compiler leaves
     # them: the three again and the gradients)
     assert len(products) == 24 * sum(k.endswith("_moe") for k in kinds) == 96
-    assert len(sites) - len(products) == own
+    # the four convolution layers' operators, a call forward and one back
+    conv = [line for line in sites if "%_gated_conv_" in line]
+    assert [sum(f"%_gated_conv_{kind}" in line for line in conv)
+            for kind in ("fwd", "bwd")] == [4, 4]
+    assert len(sites) - len(products) - len(conv) == own
     assert "ragged-dot" not in compiled.as_text()

@@ -144,7 +144,7 @@ def _operator_inputs(seq=48, channels=16, width=3, dtype=jnp.float32, seed=0):
 
 
 @pytest.mark.parametrize("width", [1, 3, 4])
-def test_the_operator_is_the_loop_a_position_at_a_time(width):
+def test_the_operator_is_the_loop_a_position_at_a_time(width, monkeypatch):
     bcx, taps, w = _operator_inputs(width=width)
     before = [metrics.registry().counter(
         "short_conv_calls_total", labels={"kind": k}).collect()
@@ -163,8 +163,13 @@ def test_the_operator_is_the_loop_a_position_at_a_time(width):
     assert [metrics.registry().counter(
         "short_conv_calls_total", labels={"kind": k}).collect()
         for k in ("forward", "backward")] == [before[0] + 1, before[1] + 1]
+    # the XLA form on the CPU; the cell's shape takes the kernels where
+    # they run
     assert short_conv.gated_short_conv_form(48, 16, width) == "xla"
     assert short_conv.gated_short_conv_form(8192, 2048, 3) == "xla"
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert short_conv.gated_short_conv_form(48, 16, width) == "xla"
+    assert short_conv.gated_short_conv_form(8192, 2048, 3) == "kernels"
 
 
 def test_the_operators_first_positions_read_zeros_and_it_is_causal():
@@ -206,6 +211,112 @@ def test_the_operator_in_bf16_sums_in_float32():
         err = float(jnp.abs(a.astype(jnp.float32) - b).max()
                     / jnp.abs(b).max())
         assert err < 8e-3, (name, err)        # one rounding of the result
+
+
+# --------------------------------------------------------------------------
+# the operator's Mosaic kernels (ops/short_conv_kernels.py), interpreted
+# --------------------------------------------------------------------------
+def _kernel_calls():
+    return [metrics.registry().counter(
+        "short_conv_kernel_calls_total", labels={"kind": k}).collect()
+        for k in ("fwd", "bwd")]
+
+
+@pytest.mark.parametrize("seq, channels, width", [
+    pytest.param(768, 256, 1, id="three_tiles-two_lane_groups-1_tap"),
+    pytest.param(768, 256, 3, id="three_tiles-two_lane_groups-3_taps"),
+    pytest.param(768, 256, 4, id="three_tiles-two_lane_groups-4_taps"),
+    pytest.param(48, 128, 9, id="three_tiles_of_16_rows-reach_of_8_rows"),
+])
+def test_the_kernels_are_the_loop_a_position_at_a_time(
+        monkeypatch, seq, channels, width):
+    """y and the gradients by ``[B | C | x]`` and by the taps through the
+    kernels, over tiles whose carried rows cross each boundary forward
+    (z) and back (dc), against the float32 loop and the XLA form."""
+    bcx, taps, w = _operator_inputs(seq, channels, width, seed=width)
+    xla_form = jax.vjp(short_conv.gated_short_conv, bcx, taps)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert short_conv.gated_short_conv_form(seq, channels,
+                                            width) == "kernels"
+    before = _kernel_calls()
+    y, vjp = jax.vjp(short_conv.gated_short_conv, bcx, taps)
+    got = (y,) + vjp(w)
+    assert [a - b for a, b in zip(_kernel_calls(), before)] == [1, 1]
+    want = family.reference_conv_grads(bcx, taps, w)
+    for name, a, b, c in zip(("y", "dbcx", "dweight"), got, want,
+                             (xla_form[0],) + xla_form[1](w)):
+        assert a.shape == b.shape and a.dtype == jnp.float32, name
+        scale = float(jnp.abs(b).max())     # dweight: sums of 768 terms
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * scale,
+                                   err_msg=name)
+        np.testing.assert_allclose(a, c, rtol=1e-5, atol=1e-6 * scale,
+                                   err_msg=name)
+
+
+def test_the_kernels_in_bf16_round_as_the_xla_form(monkeypatch):
+    """bf16 operands, float32 sums, one rounding of each result: within
+    check (c)'s limit of the float32 loop and a rounding of the XLA form."""
+    bcx, taps, w = _operator_inputs(512, 128, 3, jnp.bfloat16)
+    xla_form = jax.vjp(short_conv.gated_short_conv, bcx, taps)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    y, vjp = jax.vjp(short_conv.gated_short_conv, bcx, taps)
+    want = family.reference_conv_grads(*(a.astype(jnp.float32)
+                                         for a in (bcx, taps, w)))
+    for name, a, b, c in zip(("y", "dbcx", "dweight"), (y,) + vjp(w), want,
+                             (xla_form[0],) + xla_form[1](w)):
+        assert a.dtype == jnp.bfloat16, name
+        a, c = a.astype(jnp.float32), c.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max() / jnp.abs(b).max()) < 8e-3, name
+        assert float(jnp.abs(a - c).max() / jnp.abs(c).max()) < 8e-3, name
+
+
+def test_the_kernels_are_counted_as_they_are_traced(monkeypatch):
+    """``short_conv_kernel_calls_total{kind}``: a call differentiated at
+    the cell's shape reads one forward and one backward call, and keeps
+    ``bcx`` and the taps alone; the XLA form adds 0, and
+    ``short_conv_calls_total`` counts either."""
+    bcx = jax.ShapeDtypeStruct((8192, 3 * 2048), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((2048, 3), jnp.bfloat16)
+
+    def traced():
+        def weighted(b, t):
+            return short_conv.gated_short_conv(b, t).astype(
+                jnp.float32).sum()
+
+        before = _kernel_calls() + [metrics.registry().counter(
+            "short_conv_calls_total", labels={"kind": k}).collect()
+            for k in ("forward", "backward")]
+        jax.eval_shape(jax.grad(weighted, argnums=(0, 1)), bcx, taps)
+        after = _kernel_calls() + [metrics.registry().counter(
+            "short_conv_calls_total", labels={"kind": k}).collect()
+            for k in ("forward", "backward")]
+        return [a - b for a, b in zip(after, before)]
+
+    assert traced() == [0, 0, 1, 1]
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert traced() == [1, 1, 1, 1]
+    small, taps_, _ = _operator_inputs(48, 128, 3)
+    _, kept = short_conv._fwd(small, taps_)
+    assert len(kept) == 2 and kept[0] is small and kept[1] is taps_
+
+
+@pytest.mark.parametrize("interpreted, shape, form", [
+    # (seq, channels, width)
+    pytest.param(True, (8192, 2048, 3), "kernels", id="the_cells_shape"),
+    pytest.param(False, (8192, 2048, 3), "xla", id="on_the_cpu"),
+    pytest.param(True, (8192, 2000, 3), "xla", id="no_whole_lane_groups"),
+    pytest.param(True, (8192 + 8, 2048, 3), "xla",
+                 id="no_whole_number_of_sublane_tiles"),
+    pytest.param(True, (8192, 2048, 10), "xla",
+                 id="taps_further_back_than_eight_rows"),
+    pytest.param(True, (8192, 8 * 2048, 3), "xla",
+                 id="a_tile_of_all_channels_fills_vmem"),
+])
+def test_the_form_reads_platform_and_shape(monkeypatch, interpreted, shape,
+                                           form):
+    if interpreted:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert short_conv.gated_short_conv_form(*shape) == form
 
 
 # --------------------------------------------------------------------------
