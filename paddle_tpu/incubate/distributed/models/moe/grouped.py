@@ -30,14 +30,22 @@ stands in for one.
 How: the pairs are sorted by expert (pairs of absent experts last), the
 tokens of the pairs held here are gathered into that order, the
 projections (three or two) run as grouped matrix products over the uneven
-groups, and each token gathers its results back and adds them up in
-float32.  The products and their two gradients have two forms, and
+groups, and the results come back to their tokens, added up in float32.
+The products and their two gradients have two forms, and
 ``ops/grouped_matmul.form`` names the one that runs from platform, shape
 and dtype (:func:`_dot`, :func:`_dot_back`): on a TPU, for windows of
 whole row tiles, the Mosaic kernels of ``ops/grouped_matmul.py``, which
 visit only the row tiles that hold pairs; everywhere else (the CPU, odd
 shapes) ``jax.lax.ragged_dot`` and its ``jax.vjp``, which the kernels
 are checked against.  Both leave the rows past the count 0.
+
+The way back (``combine``, and its transpose in the backward pass's
+``dispatch``) has two forms too, which ``ops/token_rows.form`` names from
+platform and shape (:func:`_add_back`): on a TPU, ``ops/token_rows.py``'s
+kernel adds each row of the window that holds a pair into its token, so
+only the held pairs are read; everywhere else (the CPU, odd shapes)
+every token gathers its k slots of the window, masks the ones not held
+here and sums them, which is what the kernel is checked against.
 
 Shapes must be static and the number of pairs held here is not.  The
 sorted pairs are taken a window of rows at a time, a window being twice
@@ -57,7 +65,7 @@ import jax.numpy as jnp
 
 from .....nn.layer import Layer
 from .....nn import initializer as I
-from .....ops import grouped_matmul
+from .....ops import grouped_matmul, token_rows
 from .....ops._primitive import apply_closure
 
 
@@ -170,6 +178,33 @@ def _window(p: Plan, start, rows: int) -> _Window:
         (start + jnp.arange(rows) < p.count)[:, None])
 
 
+def _note_combine(form: str) -> None:
+    from .....observability import metrics
+    metrics.registry().counter(
+        "moe_combine_calls_total",
+        "calls of the routed experts' way back to their tokens, the "
+        "forward combine and the backward dispatch, counted a call when "
+        "the call is traced: held_rows each window row added into its "
+        "token, per_slot every token's k slots gathered and summed",
+        labels={"form": form}).inc()
+
+
+def _add_back(w: _Window, rows, k: int, gates=None, dtype=jnp.float32):
+    """``[T, d]`` of ``dtype``: each token's rows of the window that hold
+    its pairs here, weighed by their ``gates [T, k]`` where given, added
+    up in float32."""
+    tokens = w.here.shape[0]
+    held_rows = token_rows.form(rows, tokens, k) == "kernel"
+    _note_combine("held_rows" if held_rows else "per_slot")
+    if held_rows:
+        pair = jnp.where(w.in_use[:, 0], w.pairs, tokens * k)
+        return token_rows.add(rows, pair, k, tokens, gates, dtype)
+    picked = jnp.where(w.here[..., None], rows[w.dest], 0).astype(jnp.float32)
+    if gates is not None:
+        picked = picked * gates[..., None]
+    return picked.sum(1).astype(dtype)
+
+
 def _window_forward(w: _Window, y, gates, weights):
     """(the window's part of the result ``[T, d]`` float32, what its
     backward pass reads: the pairs' rows, their products with each of an
@@ -184,14 +219,14 @@ def _window_forward(w: _Window, y, gates, weights):
         pre = tuple(dot(x, m) for m in inner)
         rows = dot(_activation(len(inner))(*pre), last)
     with jax.named_scope("combine"):
-        picked = jnp.where(w.here[..., None], rows[w.dest], 0)
-        out = (picked.astype(jnp.float32) * gates[..., None]).sum(1)
+        out = _add_back(w, rows, gates.shape[1], gates)
     return out, (x, pre, rows)
 
 
 def _window_backward(w: _Window, kept, gates, weights, g):
-    """Gradients for (y, gates, weights) of the window's part.  Every
-    gather of the forward pass has a gather as its transpose, because
+    """Gradients for (y, gates, weights) of the window's part.  The
+    forward pass's way back has a gather as its transpose, and its
+    gather into expert order the way back (:func:`_add_back`), because
     the sorted order is a permutation of the pairs."""
     x, pre, rows = kept
     *inner, last = weights
@@ -209,9 +244,7 @@ def _window_backward(w: _Window, kept, gates, weights, g):
         back = [_dot_back(x, m, w.sizes, d)
                 for m, d in zip(inner, activation_back(d_h))]
     with jax.named_scope("dispatch"):
-        picked = jnp.where(w.here[..., None],
-                           sum(d_x for d_x, _ in back)[w.dest], 0)
-        d_y = picked.astype(jnp.float32).sum(1).astype(x.dtype)
+        d_y = _add_back(w, sum(d_x for d_x, _ in back), k, dtype=x.dtype)
     return d_y, d_gates, tuple(d_m for _, d_m in back) + (d_last,)
 
 

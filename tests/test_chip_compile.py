@@ -253,6 +253,37 @@ def _dropless_experts_of_1856(topo, monkeypatch):
                              k=6, matrices=2)
 
 
+def _held_rows(topo, monkeypatch, tokens=8192, d=2688, held=8, k=6):
+    """The experts' way back at the shape of the cell
+    nemotron3-nano-ep16stage0-s8192 (8 held of 128, 6 a token, a window of
+    6 144 rows for 49 152 slots, width 2688): the forward combine, gates
+    and float32 sums, and the backward dispatch, bf16, each one call of
+    ``ops/token_rows.py``'s kernel, which ``token_rows.form`` gives these
+    shapes."""
+    from paddle_tpu.incubate.distributed.models.moe import grouped
+    from paddle_tpu.ops import token_rows
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    usual = grouped.usual_rows(tokens, k, held, 128)
+    assert token_rows.form(spec((usual, d)), tokens, k) == "kernel"
+
+    def both(rows, d_x, logits):
+        experts, gates = grouped.route(logits, k)
+        w = grouped._window(grouped.plan(experts, 0, held), 0, usual)
+        return (grouped._add_back(w, rows, k, gates),
+                grouped._add_back(w, d_x, k, dtype=d_x.dtype))
+
+    return both, (spec((usual, d)), spec((usual, d)),
+                  spec((tokens, 128), jnp.float32))
+
+
+def _held_rows_of_keye(topo, monkeypatch):
+    """... and at the shape of the cell keye2-lm-ep8share-s8192: 16 held
+    of 128, 8 a token, a window of 16 384 rows for 65 536 slots, width
+    2048."""
+    return _held_rows(topo, monkeypatch, d=2048, held=16, k=8)
+
+
 def _scan_operands(topo, monkeypatch):
     """``ssd_scan`` at the shape of the cell granite4h-micro-stage0-s8192:
     x ``[8192, 64 heads, 64]``, one group of B and C, state 128, chunk
@@ -485,6 +516,10 @@ def _refused(build, case_id, pattern, why):
     pytest.param(_dropless_experts, 18, None, id="dropless_experts"),
     pytest.param(_dropless_experts_of_1856, 12, None,
                  id="dropless_experts_8_held_2688_by_1856"),
+    pytest.param(_held_rows, 2, None,
+                 id="held_rows_back_6144_rows_of_2688_to_8192_tokens"),
+    pytest.param(_held_rows_of_keye, 2, None,
+                 id="held_rows_back_16384_rows_of_2048_to_8192_tokens"),
     pytest.param(_scan_fwd, 1, None, id="ssd_scan_fwd_s8192"),
     pytest.param(_scan_bwd, 2, None, id="ssd_scan_bwd_s8192"),
     pytest.param(_scan_fwd_8_groups, 1, None,
@@ -617,7 +652,9 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     where a block is recomputed; the rest are the experts' grouped products, the kernels
     of ``ops/grouped_matmul.py`` by their names: fourteen an expert
     block, six of them the first window's (two products forward, their
-    four gradients) and eight the overflow branch's."""
+    four gradients) and eight the overflow branch's; and the way back to
+    the tokens, ``ops/token_rows.py``'s kernel by its name, four a block,
+    with no ``[8192, 6, 2688]`` array of a token's slots left."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks.drivers import train_nemotron_lm as driver
@@ -632,7 +669,11 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
             "moe_grouped_kernel_calls_total", labels={"kind": kind}
         ).collect() for kind in ("fwd", "dlhs", "drhs")]
 
-    before = product_calls()
+    def back_calls():
+        return metrics.registry().counter(
+            "moe_combine_calls_total", labels={"form": "held_rows"}).collect()
+
+    before, back_before = product_calls(), back_calls()
     compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
                                             config)
     # 666 962 944 parameters at 14 bytes, and the batch
@@ -661,7 +702,14 @@ def test_nemotron_stage0_step_fits_a_v5e(topo, monkeypatch):
     # four gradients in the first window and in the branch
     assert [now - was for now, was in zip(product_calls(), before)] == [
         6 * blocks, 4 * blocks, 4 * blocks]
-    assert len(sites) - len(products) == own + convolutions
+    # the way back by held rows: as traced, the first window's combine and
+    # dispatch, the overflow branch's and its forward again; compiled,
+    # four a block, the last one's result unused
+    back = [line for line in sites if "token_rows_add" in line]
+    assert back_calls() - back_before == 5 * blocks
+    assert len(back) == 4 * blocks
+    assert len(sites) - len(products) - len(back) == own + convolutions
+    assert "bf16[8192,6,2688]" not in text
     assert "ragged-dot" not in text
     assert _shifted_terms_in_hbm(text, 4096 + 2 * 8 * 128) == []
 
@@ -713,7 +761,8 @@ def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
     by), and its kernels are in it: the attention layer's forward, dq and
     dkv, and the experts' grouped products, the kernels of
     ``ops/grouped_matmul.py`` by their names, at ``[16384, 2048] x [8,
-    2048, 1792]`` and its transpose, and the gated short convolution's two
+    2048, 1792]`` and its transpose, with the way back to the tokens,
+    ``ops/token_rows.py``'s kernel, and the gated short convolution's two
     kernels a convolution layer (``ops/short_conv_kernels.py``), counted
     by ``short_conv_kernel_calls_total`` as the step is traced."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -745,13 +794,19 @@ def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
     print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
           "experts' grouped products")
     # an expert layer: nine the first window's (three products forward,
-    # their six gradients), fifteen the overflow branch's (three forward
-    # and, in its loop's backward pass, twelve as the compiler leaves
+    # their six gradients), fourteen the overflow branch's (three forward
+    # and, in its loop's backward pass, eleven as the compiler leaves
     # them: the three again and the gradients)
-    assert len(products) == 24 * sum(k.endswith("_moe") for k in kinds) == 96
+    layers = sum(k.endswith("_moe") for k in kinds)
+    assert len(products) == 23 * layers == 92
+    # and the way back by held rows: the first window's combine and
+    # dispatch and the overflow branch's
+    back = [line for line in sites if "token_rows_add" in line]
+    assert len(back) == 4 * layers
     # the four convolution layers' operators, a call forward and one back
     conv = [line for line in sites if "%_gated_conv_" in line]
     assert [sum(f"%_gated_conv_{kind}" in line for line in conv)
             for kind in ("fwd", "bwd")] == [4, 4]
-    assert len(sites) - len(products) - len(conv) == own
+    assert len(sites) - len(products) - len(back) - len(conv) == own
+    assert "bf16[8192,4,2048]" not in compiled.as_text()
     assert "ragged-dot" not in compiled.as_text()

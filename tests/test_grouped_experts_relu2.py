@@ -1,8 +1,10 @@
 """``moe/grouped.py``'s squared-ReLU expert form (two matrices, no gate)
 against a loop over the experts, forward and backward, under the
 sigmoid router: a share of the experts, all of them, and a routing so
-uneven that the later windows run.  The SiLU-gated cases stand in
-``tests/test_keye_lm.py`` as they were.
+uneven that the later windows run.  Its way back to the tokens by the
+window's held rows against every token's k slots, forward and
+gradients.  The SiLU-gated cases stand in ``tests/test_keye_lm.py`` as
+they were, and their way back in ``tests/test_moe.py``.
 """
 
 import os
@@ -20,6 +22,8 @@ if ROOT not in sys.path:
 
 import paddle_tpu as paddle                                    # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import grouped  # noqa: E402
+from paddle_tpu.observability import metrics                  # noqa: E402
+from paddle_tpu.ops import token_rows                         # noqa: E402
 
 TOKENS, D, F, EXPERTS, K = 1024, 16, 8, 32, 4
 
@@ -118,3 +122,90 @@ def test_the_layer_holds_two_matrices_and_is_told_its_experts():
     np.testing.assert_array_equal(
         np.asarray(jax.grad(lambda u: grouped._relu2(u).astype(
             jnp.float32).sum())(up).astype(jnp.float32)), [[0.0, 0.0, 4.0]])
+
+
+def _combine_calls():
+    return {form: metrics.registry().counter(
+        "moe_combine_calls_total", labels={"form": form}).collect()
+        for form in ("held_rows", "per_slot")}
+
+
+def _value_and_grads(y, chosen, gates, weights, first):
+    probe = jnp.asarray(np.random.default_rng(5).standard_normal(y.shape),
+                        jnp.float32)
+
+    def loss(y_, gates_, *weights_):
+        out, _ = grouped.experts_forward(y_, chosen, gates_, weights_, first,
+                                         EXPERTS)
+        return (out * probe).sum(), out
+
+    (_, out), grads = jax.value_and_grad(
+        loss, argnums=tuple(range(2 + len(weights))), has_aux=True)(
+            y, gates, *weights)
+    return out, grads
+
+
+@pytest.mark.parametrize("lifted", [None, 9],
+                         ids=["rows_past_the_count", "later_windows_run"])
+def test_held_rows_form_is_the_per_slot_form(monkeypatch, lifted):
+    """Under the interpreter the window's rows go back to their tokens by
+    ``ops/token_rows.py``'s kernel (the counter says so: the first
+    window's combine and dispatch, the later windows' under the scan, and
+    their forward again in the backward pass); the result and the
+    gradients for y, the gates and both matrices are the per-slot form's.
+    Uniform routing leaves tokens with several held pairs, tokens with
+    none, and rows past the count; one lifted expert overflows the
+    window."""
+    first, held = 8, 4
+    y, router, w1, w2 = layer(20)
+    bias = jnp.zeros((EXPERTS,))
+    if lifted is not None:
+        bias = bias.at[lifted].set(10.0)
+    chosen, gates = grouped.route_sigmoid(y @ router, bias, K, 2.5)
+    weights = (w1[first:first + held], w2[first:first + held])
+    mine = ((chosen >= first) & (chosen < first + held)).sum(1)
+    usual = grouped.usual_rows(TOKENS, K, held, EXPERTS)
+    assert (int(mine.sum()) > usual) == (lifted is not None)
+    if lifted is None:
+        assert int(mine.min()) == 0 and int(mine.max()) >= 2
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    before = _combine_calls()
+    out, grads = _value_and_grads(y, chosen, gates, weights, first)
+    after = _combine_calls()
+    assert {f: after[f] - before[f] for f in after} == {
+        "held_rows": 5, "per_slot": 0}
+    monkeypatch.setattr(token_rows, "form", lambda *a: "xla")
+    want, want_grads = _value_and_grads(y, chosen, gates, weights, first)
+    assert _combine_calls()["per_slot"] - after["per_slot"] == 5
+    np.testing.assert_allclose(out, want, rtol=1e-5,
+                               atol=1e-6 * float(jnp.abs(want).max()))
+    for name, a, b in zip(("y", "gates", "w1", "w2"), grads, want_grads):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
+            err_msg=name)
+
+
+def test_the_combine_counter_names_the_form_that_ran(monkeypatch):
+    """``moe_combine_calls_total{form}`` at the Nemotron cell's shape (8
+    of 128 experts, 6 a token, a window of 6 144 rows for 49 152 slots):
+    the CPU traces the per-slot form five times a layer's step, the
+    kernels' platform (here the interpreter) the held-rows form, and the
+    other label reads 0."""
+    shapes = (jax.ShapeDtypeStruct((8192, 2688), jnp.bfloat16),
+              jax.ShapeDtypeStruct((8192, 6), jnp.int32),
+              jax.ShapeDtypeStruct((8192, 6), jnp.float32),
+              (jax.ShapeDtypeStruct((8, 2688, 1856), jnp.bfloat16),
+               jax.ShapeDtypeStruct((8, 1856, 2688), jnp.bfloat16)))
+
+    def traced():
+        before = _combine_calls()
+        jax.eval_shape(jax.grad(
+            lambda y, c, g, w: grouped.experts_forward(
+                y, c, g, w, 0, 128)[0].sum(), argnums=(0, 2, 3)), *shapes)
+        after = _combine_calls()
+        return {f: after[f] - before[f] for f in after}
+
+    assert grouped.usual_rows(8192, 6, 8, 128) == 6144
+    assert traced() == {"held_rows": 0, "per_slot": 5}
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert traced() == {"held_rows": 5, "per_slot": 0}

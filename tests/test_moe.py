@@ -12,6 +12,9 @@ from paddle_tpu import nn, ops
 from paddle_tpu.incubate.distributed.models.moe import (
     ExpertLayer, GShardGate, GroupedExpertsFFN, MoELayer, NaiveGate,
     SwitchGate, global_gather, global_scatter)
+from paddle_tpu.incubate.distributed.models.moe import grouped
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops import token_rows
 
 pytestmark = pytest.mark.dist
 
@@ -154,3 +157,73 @@ def test_moe_in_transformer_block_trains():
         opt.clear_grad()
         losses.append(float(loss))
     assert losses[-1] < losses[0]
+
+
+def _combine_calls():
+    return {form: metrics.registry().counter(
+        "moe_combine_calls_total", labels={"form": form}).collect()
+        for form in ("held_rows", "per_slot")}
+
+
+@pytest.mark.parametrize("lifted", [None, 9],
+                         ids=["rows_past_the_count", "later_windows_run"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_grouped_swiglu_experts_go_back_by_held_rows(monkeypatch, router,
+                                                     lifted):
+    """``GroupedSwiGLUExperts`` under the softmax router (Keye's pairing)
+    and the sigmoid router (LFM2's): with the kernels interpreted the
+    window's rows go back to their tokens by ``ops/token_rows.py`` (the
+    counter says so), and the layer's result and the gradients for y,
+    the gates and all three matrices are the per-slot form's.  Uniform
+    routing leaves tokens with several held pairs, tokens with none and
+    rows past the count; one lifted expert overflows the window."""
+    tokens, d, f, experts, k, first, held = 1024, 16, 8, 32, 4, 8, 4
+    paddle.seed(11)
+    layer = grouped.GroupedSwiGLUExperts(d, f, experts, first, held,
+                                         initializer_range=0.3)
+    rng = np.random.default_rng(12)
+    y = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    logits = jnp.asarray(rng.standard_normal((tokens, experts)), jnp.float32)
+    if lifted is not None:
+        logits = logits.at[:, lifted].add(20.0)
+    if router == "softmax":
+        chosen, gates = grouped.route(logits, k)
+    else:
+        chosen, gates = grouped.route_sigmoid(logits, jnp.zeros(experts), k,
+                                              1.0)
+    weights = tuple(m._value for m in (layer.w1, layer.w3, layer.w2))
+    mine = ((chosen >= first) & (chosen < first + held)).sum(1)
+    usual = grouped.usual_rows(tokens, k, held, experts)
+    assert (int(mine.sum()) > usual) == (lifted is not None)
+    if lifted is None:
+        assert int(mine.min()) == 0 and int(mine.max()) >= 2
+    probe = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+
+    def loss(y_, gates_, *weights_):
+        out, _ = grouped.experts_forward(y_, chosen, gates_, weights_, first,
+                                         experts)
+        return (out * probe).sum(), out
+
+    run = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    before = _combine_calls()
+    out, _ = layer(paddle.to_tensor(y), paddle.to_tensor(chosen),
+                   paddle.to_tensor(gates))
+    (_, got), got_grads = run(y, gates, *weights)
+    after = _combine_calls()
+    # the layer's forward: the first window and the later ones under the
+    # scan; the step's the same, their dispatch, and the later windows'
+    # forward again in the backward pass
+    assert {f_: after[f_] - before[f_] for f_ in after} == {
+        "held_rows": 7, "per_slot": 0}
+    monkeypatch.setattr(token_rows, "form", lambda *a: "xla")
+    (_, want), want_grads = run(y, gates, *weights)
+    np.testing.assert_allclose(out._value, want, rtol=1e-5,
+                               atol=1e-6 * float(jnp.abs(want).max()))
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-6 * float(jnp.abs(want).max()))
+    for name, a, b in zip(("y", "gates", "w1", "w3", "w2"), got_grads,
+                          want_grads):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
+            err_msg=name)
