@@ -33,18 +33,16 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import nn, ops
-from ..nn import initializer as I
-from ..ops import pallas_ops
 from ..ops._primitive import apply_closure
-from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
-from .keye_lm import KeyeRMSNorm as RMSNorm, _linear
-from .mamba2 import Mamba2Mixer, _silu_gate
+from .blocks import (CausalLMCriterion, PositionFreeAttention, Recomputable,
+                     RMSNorm, embedding, gated_mlp, linear)
+from .mamba2 import Mamba2Mixer
 
 
 @dataclass
@@ -118,74 +116,19 @@ def granite_hybrid_tiny(**kw):
     return GraniteHybridConfig(**base)
 
 
-class GraniteMambaMixer(Mamba2Mixer):
-    """``models/mamba2.py``'s mixer at this config's sizes; ``out_proj``
-    starts as every other matrix does."""
-
-    def __init__(self, config: GraniteHybridConfig, layer_idx: int):
-        c = config
-        super().__init__(
-            c.hidden_size, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
-            c.mamba_n_groups, c.mamba_d_conv, c.mamba_chunk_size,
-            c.rms_norm_eps, c.initializer_range, c.initializer_range,
-            layer_idx)
-
-
-class GraniteAttention(nn.Layer):
-    """Grouped-query attention with no positions (``nope``)."""
-
-    def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        c, std = config, config.initializer_range
-        self.config = c
-        kv = c.num_key_value_heads * c.head_dim
-        self.q_proj = _linear(c.hidden_size, c.hidden_size, std)
-        self.k_proj = _linear(c.hidden_size, kv, std)
-        self.v_proj = _linear(c.hidden_size, kv, std)
-        self.o_proj = _linear(c.hidden_size, c.hidden_size, std)
-
-    @jax.named_scope("attn")
-    def forward(self, x):
-        c = self.config
-        # the kernels scale by 1 / sqrt(head); q takes what is left of
-        # attention_multiplier
-        left = c.attention_multiplier * math.sqrt(c.head_dim)
-
-        def closure(x_, wq, wk, wv, wo):
-            batch, seq = x_.shape[:2]
-            q = ((x_ @ wq) * left).astype(x_.dtype)
-            heads = lambda a, n: a.reshape(batch, seq, n, c.head_dim)  # noqa
-            with jax.named_scope("gqa_core"):
-                out = pallas_ops.flash_attention.raw(
-                    heads(q, c.num_attention_heads),
-                    heads(x_ @ wk, c.num_key_value_heads),
-                    heads(x_ @ wv, c.num_key_value_heads), causal=True)
-            return out.reshape(batch, seq, -1) @ wo
-
-        return apply_closure(
-            closure, [x, self.q_proj.weight, self.k_proj.weight,
-                      self.v_proj.weight, self.o_proj.weight],
-            name="granite_attention")
-
-
 class GraniteMLP(nn.Layer):
     def __init__(self, config: GraniteHybridConfig):
         super().__init__()
         c, std = config, config.initializer_range
-        self.input_linear = _linear(c.hidden_size,
-                                    2 * c.shared_intermediate_size, std)
-        self.output_linear = _linear(c.shared_intermediate_size,
-                                     c.hidden_size, std)
+        self.input_linear = linear(c.hidden_size,
+                                   2 * c.shared_intermediate_size, std)
+        self.output_linear = linear(c.shared_intermediate_size,
+                                    c.hidden_size, std)
 
     @jax.named_scope("mlp")
     def forward(self, x):
-        def closure(x_, w_in, w_out):
-            a, b = jnp.split(x_ @ w_in, 2, axis=-1)
-            return _silu_gate(a, b) @ w_out
-
-        return apply_closure(
-            closure, [x, self.input_linear.weight, self.output_linear.weight],
-            name="granite_mlp")
+        return gated_mlp(x, self.input_linear.weight,
+                         self.output_linear.weight)
 
 
 def _add_scaled(h, out, scale: float):
@@ -194,20 +137,29 @@ def _add_scaled(h, out, scale: float):
             jnp.float32)).astype(h_.dtype), [h, out], name="granite_residual")
 
 
-class GraniteDecoderLayer(nn.Layer):
+class GraniteDecoderLayer(Recomputable):
     def __init__(self, config: GraniteHybridConfig, layer_idx: int):
-        super().__init__()
+        super().__init__(config.recompute)
         c = config
         self.input_layernorm = RMSNorm(c.hidden_size, c.rms_norm_eps)
         if c.layer_types[layer_idx] == "mamba":
-            self.mamba = GraniteMambaMixer(c, layer_idx)
+            # out_proj starts as every other matrix does
+            self.mamba = Mamba2Mixer(
+                c.hidden_size, c.mamba_n_heads, c.mamba_d_head,
+                c.mamba_d_state, c.mamba_n_groups, c.mamba_d_conv,
+                c.mamba_chunk_size, c.rms_norm_eps, c.initializer_range,
+                c.initializer_range, layer_idx)
         else:
-            self.self_attn = GraniteAttention(c)
+            # q takes what the kernels' 1 / sqrt(head) leaves of
+            # attention_multiplier
+            self.self_attn = PositionFreeAttention(
+                c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                c.head_dim, c.initializer_range, c.initializer_range,
+                c.attention_multiplier * math.sqrt(c.head_dim))
         self.post_attention_layernorm = RMSNorm(c.hidden_size,
                                                 c.rms_norm_eps)
         self.shared_mlp = GraniteMLP(c)
         self._scale = c.residual_multiplier
-        self._recompute = c.recompute
 
     def _block(self, h):
         mixer = getattr(self, "mamba", None) or self.self_attn
@@ -215,21 +167,13 @@ class GraniteDecoderLayer(nn.Layer):
         return _add_scaled(
             h, self.shared_mlp(self.post_attention_layernorm(h)), self._scale)
 
-    def forward(self, h):
-        if self._recompute and self.training:
-            from ..distributed.fleet.recompute import recompute
-            return recompute(self._block, h)
-        return self._block(h)
-
 
 class GraniteHybridModel(nn.Layer):
     def __init__(self, config: GraniteHybridConfig):
         super().__init__()
         c = config
-        self.embed_tokens = nn.Embedding(
-            c.vocab_rows_held, c.hidden_size,
-            weight_attr=nn.ParamAttr(initializer=I.Normal(
-                0.0, c.initializer_range)))
+        self.embed_tokens = embedding(c.vocab_rows_held, c.hidden_size,
+                                      c.initializer_range)
         self.layers = nn.LayerList([GraniteDecoderLayer(c, i)
                                     for i in range(c.num_hidden_layers)])
         self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps)
@@ -264,13 +208,4 @@ class GraniteHybridForCausalLM(nn.Layer):
             return logits * (1.0 / c.logits_scaling)
 
 
-class GraniteHybridPretrainingCriterion(nn.Layer):
-    """Mean cross-entropy over the rows held."""
-
-    def __init__(self, config: Optional[GraniteHybridConfig] = None):
-        super().__init__()
-        self.loss_fn = ParallelCrossEntropy()
-
-    @jax.named_scope("loss")
-    def forward(self, logits, labels):
-        return ops.mean(self.loss_fn(logits, labels))
+GraniteHybridPretrainingCriterion = CausalLMCriterion

@@ -30,20 +30,19 @@ writes them to the metrics registry; call it where the loss is read.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import nn, ops
-from ..nn import initializer as I
 from ..ops import sparse_attention as dsa
 from ..ops._primitive import apply_closure
 from ..tensor import Tensor
-from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
 from ..incubate.distributed.models.moe import grouped
+from .blocks import (CausalLMCriterion, RMSNorm, apply_rotary, linear,
+                     normed_rotated, observe_expert_tokens, rotary_angles)
 
 
 @dataclass
@@ -96,75 +95,8 @@ def keye_lm_tiny(**kw):
 
 
 # --------------------------------------------------------------------------
-# rotary positions
-# --------------------------------------------------------------------------
-def rotary_angles(positions, dim: int, theta: float, sections=None):
-    """Angles ``[S, dim / 2]`` float32.  ``positions`` is ``[S]``, or
-    ``[3, S]`` with ``sections``: frequency pair i then turns with the
-    position stream its section names (temporal, height, width).  On
-    text the three streams are the token's index and this is plain
-    rotary."""
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    positions = jnp.asarray(positions, jnp.float32)
-    if positions.ndim == 1:
-        return positions[:, None] * inv_freq[None, :]
-    stream = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
-                        total_repeat_length=dim // 2)
-    return positions[stream, :].T * inv_freq[None, :]
-
-
-def apply_rotary(x, angles):
-    """``x [S, heads, dim]`` (or ``[S, dim]``) rotated by ``angles [S,
-    dim / 2]``: the pairs are (i, i + dim / 2), computed in float32."""
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    if x.ndim == 3:
-        cos, sin = cos[:, None, :], sin[:, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def _rms(x, weight, eps):
-    xf = x.astype(jnp.float32)
-    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (out * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-class KeyeRMSNorm(nn.Layer):
-    """RMSNorm whose backward pass keeps its input as it is stored and
-    computes the float32 insides again (``ops.rms_norm`` keeps three
-    float32 copies of a ``[S, hidden]`` input: 0.4 GB a layer at 8k
-    tokens)."""
-
-    def __init__(self, size: int, epsilon: float):
-        super().__init__()
-        self._epsilon = epsilon
-        self.weight = self.create_parameter(
-            shape=[size], default_initializer=I.Constant(1.0))
-
-    def forward(self, x):
-        eps = self._epsilon
-        return apply_closure(
-            jax.checkpoint(lambda x_, w: _rms(x_, w, eps)),
-            [x, self.weight], name="keye_rms_norm")
-
-
-@functools.partial(jax.checkpoint, static_argnums=(3,))
-def _normed_rotated(x, weight, angles, eps):
-    """Per-head RMS normalisation, then the rotation.  Its float32
-    insides are cheap to compute again, so the backward pass keeps the
-    bf16 input and nothing else."""
-    return apply_rotary(_rms(x, weight, eps), angles)
-
-
-# --------------------------------------------------------------------------
 # layers
 # --------------------------------------------------------------------------
-def _linear(fan_in, fan_out, std):
-    return nn.Linear(fan_in, fan_out, bias_attr=False,
-                     weight_attr=nn.ParamAttr(initializer=I.Normal(0.0, std)))
-
-
 class KeyeIndexer(nn.Layer):
     """qI = x W_q (heads x width), kI = LayerNorm(x W_k) (one head), both
     rotated over their whole width; w = x W_w (a weight a head)."""
@@ -172,11 +104,11 @@ class KeyeIndexer(nn.Layer):
     def __init__(self, config: KeyeLMConfig):
         super().__init__()
         c, std = config, config.initializer_range
-        self.wq = _linear(c.hidden_size,
-                          c.indexer_num_heads * c.indexer_head_dim, std)
-        self.wk = _linear(c.hidden_size, c.indexer_head_dim, std)
+        self.wq = linear(c.hidden_size,
+                         c.indexer_num_heads * c.indexer_head_dim, std)
+        self.wk = linear(c.hidden_size, c.indexer_head_dim, std)
         self.k_norm = nn.LayerNorm(c.indexer_head_dim)
-        self.weights_proj = _linear(c.hidden_size, c.indexer_num_heads, std)
+        self.weights_proj = linear(c.hidden_size, c.indexer_num_heads, std)
 
     def parameters_in_order(self):
         return [self.wq.weight, self.wk.weight, self.k_norm.weight,
@@ -188,16 +120,16 @@ class KeyeSparseAttention(nn.Layer):
         super().__init__()
         c, std = config, config.initializer_range
         self.config = c
-        self.q_proj = _linear(c.hidden_size,
-                              c.num_attention_heads * c.head_dim, std)
-        self.k_proj = _linear(c.hidden_size,
-                              c.num_key_value_heads * c.head_dim, std)
-        self.v_proj = _linear(c.hidden_size,
-                              c.num_key_value_heads * c.head_dim, std)
-        self.o_proj = _linear(c.num_attention_heads * c.head_dim,
-                              c.hidden_size, std)
-        self.q_norm = KeyeRMSNorm(c.head_dim, c.rms_norm_eps)
-        self.k_norm = KeyeRMSNorm(c.head_dim, c.rms_norm_eps)
+        self.q_proj = linear(c.hidden_size,
+                             c.num_attention_heads * c.head_dim, std)
+        self.k_proj = linear(c.hidden_size,
+                             c.num_key_value_heads * c.head_dim, std)
+        self.v_proj = linear(c.hidden_size,
+                             c.num_key_value_heads * c.head_dim, std)
+        self.o_proj = linear(c.num_attention_heads * c.head_dim,
+                             c.hidden_size, std)
+        self.q_norm = RMSNorm(c.head_dim, c.rms_norm_eps)
+        self.k_norm = RMSNorm(c.head_dim, c.rms_norm_eps)
         self.indexer = KeyeIndexer(c)
 
     def _indexed(self, x, positions, wq, wk, ln_w, ln_b, ww):
@@ -232,8 +164,8 @@ class KeyeSparseAttention(nn.Layer):
         q = (x @ wq).reshape(seq, c.num_attention_heads, c.head_dim)
         k = (x @ wk).reshape(seq, c.num_key_value_heads, c.head_dim)
         v = (x @ wv).reshape(seq, c.num_key_value_heads, c.head_dim)
-        q = _normed_rotated(q, qn, angles, c.rms_norm_eps)
-        k = _normed_rotated(k, kn, angles, c.rms_norm_eps)
+        q = normed_rotated(q, qn, angles, c.rms_norm_eps)
+        k = normed_rotated(k, kn, angles, c.rms_norm_eps)
         with jax.named_scope("sparse_core"):
             out, lse = dsa.core(q, k, v, mask)
         y = out.reshape(seq, -1) @ wo
@@ -283,8 +215,8 @@ class KeyeSparseMoeBlock(nn.Layer):
         super().__init__()
         c = config
         self.top_k = c.num_experts_per_tok
-        self.gate = _linear(c.hidden_size, c.num_experts,
-                            c.initializer_range)
+        self.gate = linear(c.hidden_size, c.num_experts,
+                           c.initializer_range)
         first, held = c.experts_held
         self.experts = grouped.GroupedSwiGLUExperts(
             c.hidden_size, c.moe_intermediate_size, c.num_experts, first,
@@ -316,9 +248,9 @@ class KeyeDecoderLayer(nn.Layer):
     def __init__(self, config: KeyeLMConfig):
         super().__init__()
         c = config
-        self.input_layernorm = KeyeRMSNorm(c.hidden_size, c.rms_norm_eps)
+        self.input_layernorm = RMSNorm(c.hidden_size, c.rms_norm_eps)
         self.self_attn = KeyeSparseAttention(c)
-        self.post_attention_layernorm = KeyeRMSNorm(c.hidden_size,
+        self.post_attention_layernorm = RMSNorm(c.hidden_size,
                                                     c.rms_norm_eps)
         self.mlp = KeyeSparseMoeBlock(c)
 
@@ -349,7 +281,7 @@ class KeyeLMModel(nn.Layer):
         self.embed_tokens = nn.Embedding(c.vocab_rows_held, c.hidden_size)
         self.layers = nn.LayerList([KeyeDecoderLayer(c)
                                     for _ in range(c.num_hidden_layers)])
-        self.norm = KeyeRMSNorm(c.hidden_size, c.rms_norm_eps)
+        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps)
 
 
 class KeyeLMForCausalLM(nn.Layer):
@@ -358,8 +290,8 @@ class KeyeLMForCausalLM(nn.Layer):
         c = config
         self.config = c
         self.model = KeyeLMModel(c)
-        self.lm_head = _linear(c.hidden_size, c.vocab_rows_held,
-                               c.initializer_range)
+        self.lm_head = linear(c.hidden_size, c.vocab_rows_held,
+                              c.initializer_range)
         held = c.experts_held[1]
         self.register_buffer("indexer_loss", Tensor(
             jnp.zeros((), jnp.float32)))
@@ -412,22 +344,12 @@ class KeyeLMForCausalLM(nn.Layer):
         It reads buffers the step returned with its loss, so where the
         loss has been read it waits for nothing."""
         from ..observability import metrics
-        reg = metrics.registry()
-        tokens = jax.device_get(self.expert_tokens._value)
-        for i, row in enumerate(tokens):
-            labels = {"layer": str(i)}
-            reg.counter("moe_pairs_total",
-                        "(token, expert) pairs computed by the experts "
-                        "held here", labels=labels).inc(int(row.sum()))
-            reg.gauge("moe_expert_tokens_max",
-                      "pairs of the fullest held expert in the last step "
-                      "observed", labels=labels).set(float(row.max()))
-            reg.gauge("moe_expert_tokens_mean",
-                      "pairs of a held expert in the last step observed, "
-                      "on average", labels=labels).set(float(row.mean()))
-        reg.counter("dsa_selected_keys_total",
-                    "keys the sparse attention read, summed over queries "
-                    "and layers").inc(float(self.selected_keys._value))
+        observe_expert_tokens(self.expert_tokens,
+                              range(self.config.num_hidden_layers))
+        metrics.registry().counter(
+            "dsa_selected_keys_total", "keys the sparse attention read, "
+            "summed over queries and layers").inc(
+                float(self.selected_keys._value))
 
 
 def selected_keys(seq: int, topk: int) -> int:
@@ -436,14 +358,11 @@ def selected_keys(seq: int, topk: int) -> int:
     return full * (full + 1) // 2 + (seq - full) * topk
 
 
-class KeyeLMPretrainingCriterion(nn.Layer):
+class KeyeLMPretrainingCriterion(CausalLMCriterion):
     """``L_LM + L_I``: the mean cross-entropy over the rows held plus the
     indexer's loss as the model returns it (weight 1)."""
 
-    def __init__(self, config: Optional[KeyeLMConfig] = None):
-        super().__init__()
-        self.loss_fn = ParallelCrossEntropy()
-
-    @jax.named_scope("loss")
     def forward(self, logits, indexer_loss, labels):
-        return ops.mean(self.loss_fn(logits, labels)) + indexer_loss
+        lm = super().forward(logits, labels)
+        with jax.named_scope("loss"):
+            return lm + indexer_loss

@@ -27,7 +27,7 @@ sqrt(head)) v`` through ``ops/pallas_ops.flash_attention``.  Dense ff:
 
 ``b`` (``expert_bias``) is a buffer: it chooses, does not weigh, and no
 gradient reaches it.  What moves it is the balancing rule of
-``models/nemotron_h.py``: after each forward pass in training mode, ``b_e
+``models/blocks.py``: after each forward pass in training mode, ``b_e
 += router_bias_update_rate * sign(mean load - load_e)`` over all experts,
 held here or not (the rate is no key of ``config.json``; 0, the default,
 leaves ``b`` where it is).
@@ -66,7 +66,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,11 +76,11 @@ from ..nn import initializer as I
 from ..ops import pallas_ops, short_conv
 from ..ops._primitive import apply_closure
 from ..tensor import Tensor
-from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
 from ..incubate.distributed.models.moe import grouped
-from .keye_lm import (KeyeRMSNorm as RMSNorm, _linear, _normed_rotated,
-                      rotary_angles)
-from .mamba2 import _silu_gate
+from .blocks import (CausalLMCriterion, RMSNorm, Recomputable, balance,
+                     embedding, gauge_recomputed, linear, normed_rotated,
+                     observe_expert_tokens, rotary_angles, sigmoid_router,
+                     silu_gate)
 
 OPERATORS = ("conv", "full_attention")
 ATTENTION_AT = (2, 6, 10, 14, 18, 21)       # of the published 24 layers
@@ -187,13 +187,13 @@ class Lfm2ShortConv(nn.Layer):
         super().__init__()
         c, std = config, config.initializer_range
         self.layer_idx = layer_idx
-        self.in_proj = _linear(c.hidden_size, 3 * c.hidden_size, std)
+        self.in_proj = linear(c.hidden_size, 3 * c.hidden_size, std)
         # torch's Conv1d: uniform within 1 / sqrt(fan-in), the taps
         bound = 1.0 / math.sqrt(c.conv_L_cache)
         self.conv_weight = self.create_parameter(
             shape=[c.hidden_size, c.conv_L_cache],
             default_initializer=I.Uniform(-bound, bound))
-        self.out_proj = _linear(c.hidden_size, c.hidden_size, std)
+        self.out_proj = linear(c.hidden_size, c.hidden_size, std)
 
     def forward(self, u):
         """``u [B, S, hidden]`` -> the operator's output."""
@@ -228,10 +228,10 @@ class Lfm2Attention(nn.Layer):
         c, std = config, config.initializer_range
         self.config = c
         kv = c.num_key_value_heads * c.head_dim
-        self.q_proj = _linear(c.hidden_size, c.hidden_size, std)
-        self.k_proj = _linear(c.hidden_size, kv, std)
-        self.v_proj = _linear(c.hidden_size, kv, std)
-        self.out_proj = _linear(c.hidden_size, c.hidden_size, std)
+        self.q_proj = linear(c.hidden_size, c.hidden_size, std)
+        self.k_proj = linear(c.hidden_size, kv, std)
+        self.v_proj = linear(c.hidden_size, kv, std)
+        self.out_proj = linear(c.hidden_size, c.hidden_size, std)
         self.q_layernorm = RMSNorm(c.head_dim, c.norm_eps)
         self.k_layernorm = RMSNorm(c.head_dim, c.norm_eps)
 
@@ -244,7 +244,7 @@ class Lfm2Attention(nn.Layer):
             with jax.named_scope("attn_proj"):
                 angles = rotary_angles(jnp.arange(seq), c.head_dim,
                                        c.rope_theta)
-                q, k = (jax.vmap(lambda a, w=w: _normed_rotated(
+                q, k = (jax.vmap(lambda a, w=w: normed_rotated(
                     a, w, angles, c.norm_eps))(heads(u_ @ m, n))
                     for m, w, n in ((wq, qn, c.num_attention_heads),
                                     (wk, kn, c.num_key_value_heads)))
@@ -268,14 +268,14 @@ class Lfm2MLP(nn.Layer):
     def __init__(self, config: Lfm2MoeConfig):
         super().__init__()
         c, std = config, config.initializer_range
-        self.w1 = _linear(c.hidden_size, c.intermediate_size, std)
-        self.w3 = _linear(c.hidden_size, c.intermediate_size, std)
-        self.w2 = _linear(c.intermediate_size, c.hidden_size, std)
+        self.w1 = linear(c.hidden_size, c.intermediate_size, std)
+        self.w3 = linear(c.hidden_size, c.intermediate_size, std)
+        self.w2 = linear(c.intermediate_size, c.hidden_size, std)
 
     def forward(self, y):
         def closure(y_, w1, w3, w2):
             with jax.named_scope("dense_mlp"):
-                return _silu_gate(y_ @ w1, y_ @ w3) @ w2
+                return silu_gate(y_ @ w1, y_ @ w3) @ w2
 
         return apply_closure(
             closure, [y, self.w1.weight, self.w3.weight, self.w2.weight],
@@ -290,7 +290,7 @@ class Lfm2SparseMoeBlock(nn.Layer):
         c, std = config, config.initializer_range
         self.top_k = c.num_experts_per_tok
         self.scale = c.routed_scaling_factor
-        self.gate = _linear(c.hidden_size, c.num_experts, std)
+        self.gate = linear(c.hidden_size, c.num_experts, std)
         self.register_buffer("expert_bias", Tensor(
             jnp.zeros((c.num_experts,), jnp.float32)))
         first, held = c.experts_held
@@ -304,32 +304,20 @@ class Lfm2SparseMoeBlock(nn.Layer):
         k]``)."""
         shape = y.shape
         flat = ops.reshape(y, [-1, shape[-1]])
-        top_k, scale = self.top_k, self.scale
-
-        def router(flat_, gate_w, bias):
-            with jax.named_scope("router"):
-                # float32 operands and sums: a bf16 product flips the
-                # choice of an expert at the border
-                logits = jnp.matmul(
-                    flat_.astype(jnp.float32), gate_w.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST)
-                return grouped.route_sigmoid(logits, bias, top_k, scale,
-                                             eps=GATE_EPS)
-
-        experts, gates = apply_closure(
-            router, [flat, self.gate.weight, self.expert_bias],
-            name="lfm2_router")
+        experts, gates = sigmoid_router(flat, self.gate.weight,
+                                        self.expert_bias, self.top_k,
+                                        self.scale, GATE_EPS)
         out, sizes = self.experts(flat, experts, gates)
         return (ops.reshape(ops.cast(out, y.dtype), list(shape)), sizes,
                 experts)
 
 
-class Lfm2MoeDecoderLayer(nn.Layer):
+class Lfm2MoeDecoderLayer(Recomputable):
     """``h + op(norm(h))``, then ``h + ff(norm(h))``; an expert layer also
     returns the pairs of each held expert and the experts chosen."""
 
     def __init__(self, config: Lfm2MoeConfig, held_idx: int):
-        super().__init__()
+        super().__init__(held_idx in config.recompute)
         c = config
         self.layer_idx = c.layers_held[0] + held_idx
         self.kind = c.kinds[held_idx]
@@ -342,7 +330,6 @@ class Lfm2MoeDecoderLayer(nn.Layer):
         self.ffn_norm = RMSNorm(c.hidden_size, c.norm_eps)
         self.feed_forward = (Lfm2SparseMoeBlock(c) if self.is_moe
                              else Lfm2MLP(c))
-        self._recompute = held_idx in c.recompute
 
     def _block(self, h):
         operator = getattr(self, "conv", None) or self.self_attn
@@ -354,25 +341,13 @@ class Lfm2MoeDecoderLayer(nn.Layer):
                 return (h + out[0],) + tuple(out[1:])
             return h + out
 
-    @property
-    def recomputed(self) -> bool:
-        return self._recompute and self.training
-
-    def forward(self, h):
-        if self.recomputed:
-            from ..distributed.fleet.recompute import recompute
-            return recompute(self._block, h)
-        return self._block(h)
-
 
 class Lfm2MoeModel(nn.Layer):
     def __init__(self, config: Lfm2MoeConfig):
         super().__init__()
         c = config
-        self.embed_tokens = nn.Embedding(
-            c.vocab_rows_held, c.hidden_size,
-            weight_attr=nn.ParamAttr(initializer=I.Normal(
-                0.0, c.initializer_range)))
+        self.embed_tokens = embedding(c.vocab_rows_held, c.hidden_size,
+                                      c.initializer_range)
         self.layers = nn.LayerList([Lfm2MoeDecoderLayer(c, i)
                                     for i in range(c.layers_held[1])])
         self.embedding_norm = RMSNorm(c.hidden_size, c.norm_eps)
@@ -400,13 +375,7 @@ class Lfm2MoeForCausalLM(nn.Layer):
         layers, B * S, k]`` and the pairs of each held expert ``[expert
         layers, held]``."""
         layers = self.model.layers
-        from ..observability import metrics
-        for kind in dict.fromkeys(self.config.kinds):
-            metrics.registry().gauge(
-                "recompute_layers", "layers of the model last traced that "
-                "run their forward pass again in the backward pass, by "
-                "their operator and ff", labels={"kind": kind}).set(sum(
-                    l.recomputed for l in layers if l.kind == kind))
+        gauge_recomputed(layers, dict.fromkeys(self.config.kinds))
         with jax.named_scope("embed"):
             h = self.model.embed_tokens(input_ids)
         sizes, chosen = [], []
@@ -415,8 +384,9 @@ class Lfm2MoeForCausalLM(nn.Layer):
                 h, pairs, experts = layer(h)
                 sizes.append(pairs)
                 chosen.append(experts)
-                if self.training and self.config.router_bias_update_rate:
-                    self._balance(layer.feed_forward, experts)
+                rate = self.config.router_bias_update_rate
+                if self.training and rate:
+                    balance(layer.feed_forward.expert_bias, experts, rate)
             else:
                 h = layer(h)
         with jax.named_scope("head"):
@@ -434,19 +404,6 @@ class Lfm2MoeForCausalLM(nn.Layer):
             return [logits, routing, Tensor(self.expert_tokens._value)]
         return logits
 
-    @jax.named_scope("mlp")
-    def _balance(self, block, experts):
-        """The balancing rule on one router's bias, from the experts this
-        pass's tokens chose ``[T, k]``: outside the layer, so that a
-        recomputed layer has nothing to write."""
-        rate = self.config.router_bias_update_rate
-        bias = block.expert_bias
-        with jax.named_scope("router"):
-            load = (experts._value[..., None] == jnp.arange(
-                bias.shape[0], dtype=experts._value.dtype)).sum(
-                    (0, 1)).astype(jnp.float32)
-            bias._value = bias._value + rate * jnp.sign(load.mean() - load)
-
     def moe_layers(self) -> Tuple[int, ...]:
         """The expert layers held, by their index in the whole model."""
         c = self.config
@@ -454,34 +411,9 @@ class Lfm2MoeForCausalLM(nn.Layer):
                      if kind.endswith("_moe"))
 
     def observe_step(self):
-        """Writes what the last step counted to the metrics registry:
-        ``moe_pairs_total{layer}``, ``moe_expert_tokens_max{layer}``,
-        ``moe_expert_tokens_mean{layer}``.  It reads a buffer the step
-        returned with its loss, so where the loss has been read it waits
-        for nothing."""
-        from ..observability import metrics
-        reg = metrics.registry()
-        tokens = jax.device_get(self.expert_tokens._value)
-        for layer, row in zip(self.moe_layers(), tokens):
-            labels = {"layer": str(layer)}
-            reg.counter("moe_pairs_total",
-                        "(token, expert) pairs computed by the experts "
-                        "held here", labels=labels).inc(int(row.sum()))
-            reg.gauge("moe_expert_tokens_max",
-                      "pairs of the fullest held expert in the last step "
-                      "observed", labels=labels).set(float(row.max()))
-            reg.gauge("moe_expert_tokens_mean",
-                      "pairs of a held expert in the last step observed, "
-                      "on average", labels=labels).set(float(row.mean()))
+        """Writes what the last step counted to the metrics registry
+        (:func:`blocks.observe_expert_tokens`)."""
+        observe_expert_tokens(self.expert_tokens, self.moe_layers())
 
 
-class Lfm2MoePretrainingCriterion(nn.Layer):
-    """Mean cross-entropy over the rows held."""
-
-    def __init__(self, config: Optional[Lfm2MoeConfig] = None):
-        super().__init__()
-        self.loss_fn = ParallelCrossEntropy()
-
-    @jax.named_scope("loss")
-    def forward(self, logits, labels):
-        return ops.mean(self.loss_fn(logits, labels))
+Lfm2MoePretrainingCriterion = CausalLMCriterion

@@ -23,8 +23,6 @@ their own calls (``ssm_scan_kernel_visits_total{kind}``,
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 
@@ -33,44 +31,32 @@ from ..framework import dtype as dtypes, random as _random
 from ..nn import initializer as I
 from ..ops import ssm
 from ..ops._primitive import apply_closure
-from .keye_lm import KeyeRMSNorm as RMSNorm, _linear, _rms
-
-
-# --------------------------------------------------------------------------
-# pieces that keep their stored input and compute their float32 insides
-# again in the backward pass
-# --------------------------------------------------------------------------
-@jax.checkpoint
-def _silu_gate(a, b):
-    af = a.astype(jnp.float32)
-    return (af * jax.nn.sigmoid(af) * b.astype(jnp.float32)).astype(a.dtype)
-
-
-@jax.checkpoint
-def _step_sizes(dt, dt_bias):
-    return jax.nn.softplus(dt.astype(jnp.float32)
-                           + dt_bias.astype(jnp.float32))
+from .blocks import (Conv1d, InverseSoftplusOfSteps, RMSNorm, linear, rms,
+                     silu_gate, step_sizes)
 
 
 # jitted: the groups of a norm, and every norm of a model, share one trace
 # and one lowering of it (a step of four blocks of eight groups traced
-# and lowered 680 equations more without)
-_rms_of_a_group = jax.jit(_rms)
+# and lowered 680 equations more without); the step calls the program
+# ``_rms``
+@jax.jit
+def _rms(x, weight, eps):
+    return rms(x, weight, eps)
 
 
 def _rms_by_group(x, weight, eps, groups: int):
-    """:func:`_rms` with the mean square over each of ``groups`` groups of
-    channels; one group is ``_rms`` itself.  A group at a time, each a
+    """:func:`rms` with the mean square over each of ``groups`` groups of
+    channels; one group is ``rms`` itself.  A group at a time, each a
     block of whole columns, which the chip takes as it lies: rows-major,
     as the mixer's arrays lie since its convolution is a kernel, it lays
     a float32 ``[S, groups, n]`` out anew, a copy each way (8.3 ms a step
     in the Nemotron cell: ``PERF.md`` section 6, PR 37)."""
     if groups == 1:
-        return _rms(x, weight, eps)
+        return rms(x, weight, eps)
     size = x.shape[-1] // groups
     return jnp.concatenate(
-        [_rms_of_a_group(x[..., g * size:(g + 1) * size],
-                         weight[g * size:(g + 1) * size], eps)
+        [_rms(x[..., g * size:(g + 1) * size],
+              weight[g * size:(g + 1) * size], eps)
          for g in range(groups)], axis=-1)
 
 
@@ -93,19 +79,6 @@ def _count_scan(layer: int, seq: int, calls: int, heads: int, width: int,
 # --------------------------------------------------------------------------
 # layers
 # --------------------------------------------------------------------------
-class _Conv1d(nn.Layer):
-    """Depthwise, causal: ``weight [channels, width]`` and a bias."""
-
-    def __init__(self, channels: int, width: int):
-        super().__init__()
-        bound = 1.0 / math.sqrt(width)     # torch's Conv1d, fan-in = width
-        self.weight = self.create_parameter(
-            shape=[channels, width],
-            default_initializer=I.Uniform(-bound, bound))
-        self.bias = self.create_parameter(
-            shape=[channels], default_initializer=I.Uniform(-bound, bound))
-
-
 class Mamba2Mixer(nn.Layer):
     """``heads`` heads of width ``head_dim`` over ``groups`` groups of B
     and C with ``state`` states each; ``out_proj`` starts at
@@ -121,21 +94,21 @@ class Mamba2Mixer(nn.Layer):
         self.layer_idx = layer_idx
         self.d_inner = heads * head_dim
         self.conv_dim = self.d_inner + 2 * groups * state
-        self.in_proj = _linear(hidden_size,
-                               self.d_inner + self.conv_dim + heads,
-                               initializer_range)
-        self.conv1d = _Conv1d(self.conv_dim, conv_width)
+        self.in_proj = linear(hidden_size,
+                              self.d_inner + self.conv_dim + heads,
+                              initializer_range)
+        self.conv1d = Conv1d(self.conv_dim, conv_width)
         # a step drawn log-uniformly from [0.001, 0.1] and put through
         # the inverse of softplus; A uniform in [1, 16]; D = 1: Mamba-2's
         # own start
         self.dt_bias = self.create_parameter(
-            shape=[heads], default_initializer=_InverseSoftplusOfSteps())
+            shape=[heads], default_initializer=InverseSoftplusOfSteps())
         self.A_log = self.create_parameter(
             shape=[heads], default_initializer=_LogOfUniform(1.0, 16.0))
         self.D = self.create_parameter(
             shape=[heads], default_initializer=I.Constant(1.0))
         self.norm = RMSNorm(self.d_inner, eps)
-        self.out_proj = _linear(self.d_inner, hidden_size, out_range)
+        self.out_proj = linear(self.d_inner, hidden_size, out_range)
 
     def _one_sequence(self, x, w_in, conv_w, conv_b, dt_bias, a_log, d,
                       norm_w, w_out):
@@ -153,12 +126,12 @@ class Mamba2Mixer(nn.Layer):
             # [seq, d_inner]: the kernels read and write it as it lies
             y = ssm.ssd_scan(
                 xs.reshape(seq, self.heads, self.head_dim),
-                _step_sizes(dt, dt_bias), -jnp.exp(a_log.astype(jnp.float32)),
+                step_sizes(dt, dt_bias), -jnp.exp(a_log.astype(jnp.float32)),
                 b.reshape(seq, self.groups, self.state),
                 cc.reshape(seq, self.groups, self.state),
                 d.astype(jnp.float32), self.chunk)
         with jax.named_scope("ssm_norm"):
-            y = _rms_by_group(_silu_gate(z, y.reshape(seq, inner)), norm_w,
+            y = _rms_by_group(silu_gate(z, y.reshape(seq, inner)), norm_w,
                               self.eps, self.groups)
         with jax.named_scope("ssm_proj"):
             return y @ w_out
@@ -177,20 +150,6 @@ class Mamba2Mixer(nn.Layer):
                               for b in range(x_.shape[0])])
 
         return apply_closure(closure, [x] + weights, name="mamba2_mixer")
-
-
-class _InverseSoftplusOfSteps(I.Initializer):
-    """``softplus(value)`` is a step drawn log-uniformly from [low, high]."""
-
-    def __init__(self, low: float = 0.001, high: float = 0.1):
-        self.low, self.high = math.log(low), math.log(high)
-
-    def __call__(self, shape, dtype):
-        step = jnp.exp(jax.random.uniform(
-            _random.next_key(), tuple(shape), jnp.float32, self.low,
-            self.high))
-        return (step + jnp.log(-jnp.expm1(-step))).astype(
-            dtypes.to_jax_dtype(dtype))
 
 
 class _LogOfUniform(I.Initializer):

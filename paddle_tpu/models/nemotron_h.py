@@ -53,23 +53,23 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import nn, ops
-from ..nn import initializer as I
-from ..ops import pallas_ops
 from ..ops._primitive import apply_closure
 from ..tensor import Tensor
-from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
 from ..incubate.distributed.models.moe import grouped
+from .blocks import (CausalLMCriterion, PositionFreeAttention, Recomputable,
+                     RMSNorm, balance, embedding, gauge_recomputed, linear,
+                     observe_expert_tokens, sigmoid_router)
 from .mamba2 import Mamba2Mixer
-from .keye_lm import KeyeRMSNorm as RMSNorm, _linear
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+GATE_EPS = 1e-20                            # added to the chosen gates' sum
 
 
 @dataclass
@@ -172,43 +172,6 @@ def nemotron_h_tiny(**kw):
 # --------------------------------------------------------------------------
 # mixers
 # --------------------------------------------------------------------------
-class NemotronHAttention(nn.Layer):
-    """Grouped-query attention with no positions; the heads' width is the
-    config's own."""
-
-    def __init__(self, config: NemotronHConfig):
-        super().__init__()
-        c, std = config, config.initializer_range
-        self.config = c
-        self.q_proj = _linear(c.hidden_size,
-                              c.num_attention_heads * c.head_dim, std)
-        self.k_proj = _linear(c.hidden_size,
-                              c.num_key_value_heads * c.head_dim, std)
-        self.v_proj = _linear(c.hidden_size,
-                              c.num_key_value_heads * c.head_dim, std)
-        self.o_proj = _linear(c.num_attention_heads * c.head_dim,
-                              c.hidden_size, c.residual_range)
-
-    @jax.named_scope("attn")
-    def forward(self, x):
-        c = self.config
-
-        def closure(x_, wq, wk, wv, wo):
-            batch, seq = x_.shape[:2]
-            heads = lambda a, n: a.reshape(batch, seq, n, c.head_dim)  # noqa
-            with jax.named_scope("gqa_core"):
-                out = pallas_ops.flash_attention.raw(
-                    heads(x_ @ wq, c.num_attention_heads),
-                    heads(x_ @ wk, c.num_key_value_heads),
-                    heads(x_ @ wv, c.num_key_value_heads), causal=True)
-            return out.reshape(batch, seq, -1) @ wo
-
-        return apply_closure(
-            closure, [x, self.q_proj.weight, self.k_proj.weight,
-                      self.v_proj.weight, self.o_proj.weight],
-            name="nemotron_h_attention")
-
-
 class NemotronHMoE(nn.Layer):
     """The routed experts held here and the shared expert."""
 
@@ -217,17 +180,17 @@ class NemotronHMoE(nn.Layer):
         c, std = config, config.initializer_range
         self.top_k = c.num_experts_per_tok
         self.scale = c.routed_scaling_factor
-        self.gate = _linear(c.hidden_size, c.n_routed_experts, std)
+        self.gate = linear(c.hidden_size, c.n_routed_experts, std)
         self.register_buffer("e_score_correction_bias", Tensor(
             jnp.zeros((c.n_routed_experts,), jnp.float32)))
         first, held = c.experts_held
         self.experts = grouped.GroupedRelu2Experts(
             c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
             first, held, std, c.residual_range)
-        self.shared_up = _linear(c.hidden_size,
-                                 c.moe_shared_expert_intermediate_size, std)
-        self.shared_down = _linear(c.moe_shared_expert_intermediate_size,
-                                   c.hidden_size, c.residual_range)
+        self.shared_up = linear(c.hidden_size,
+                                c.moe_shared_expert_intermediate_size, std)
+        self.shared_down = linear(c.moe_shared_expert_intermediate_size,
+                                  c.hidden_size, c.residual_range)
 
     def shared(self, flat):
         """``W2_s relu(W1_s y)^2`` of every token."""
@@ -246,31 +209,20 @@ class NemotronHMoE(nn.Layer):
         ``[held]``, the experts chosen ``[B * S, k]``)."""
         shape = y.shape
         flat = ops.reshape(y, [-1, shape[-1]])
-        top_k, scale = self.top_k, self.scale
-
-        def router(flat_, gate_w, bias):
-            with jax.named_scope("router"):
-                # float32 operands and sums: a bf16 product flips the
-                # choice of an expert at the border
-                logits = jnp.matmul(
-                    flat_.astype(jnp.float32), gate_w.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST)
-                return grouped.route_sigmoid(logits, bias, top_k, scale)
-
-        experts, gates = apply_closure(
-            router, [flat, self.gate.weight, self.e_score_correction_bias],
-            name="nemotron_h_router")
+        experts, gates = sigmoid_router(
+            flat, self.gate.weight, self.e_score_correction_bias, self.top_k,
+            self.scale, GATE_EPS)
         routed, sizes = self.experts(flat, experts, gates)
         out = ops.cast(routed, y.dtype) + self.shared(flat)
         return ops.reshape(out, list(shape)), sizes, experts
 
 
-class NemotronHBlock(nn.Layer):
+class NemotronHBlock(Recomputable):
     """``h + mixer(RMSNorm(h))``; an expert block also returns the pairs
     of each held expert and the experts chosen."""
 
     def __init__(self, config: NemotronHConfig, held_idx: int):
-        super().__init__()
+        super().__init__(held_idx in config.recompute)
         c = config
         self.kind = c.kinds[held_idx]
         self.norm = RMSNorm(c.hidden_size, c.layer_norm_epsilon)
@@ -283,8 +235,10 @@ class NemotronHBlock(nn.Layer):
         elif self.kind == "moe":
             self.mixer = NemotronHMoE(c)
         else:
-            self.mixer = NemotronHAttention(c)
-        self._recompute = held_idx in c.recompute
+            # the heads' width is the config's own
+            self.mixer = PositionFreeAttention(
+                c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                c.head_dim, c.initializer_range, c.residual_range)
 
     def _block(self, h):
         out = self.mixer(self.norm(h))
@@ -292,25 +246,13 @@ class NemotronHBlock(nn.Layer):
             return (h + out[0],) + tuple(out[1:])
         return h + out
 
-    @property
-    def recomputed(self) -> bool:
-        return self._recompute and self.training
-
-    def forward(self, h):
-        if self.recomputed:
-            from ..distributed.fleet.recompute import recompute
-            return recompute(self._block, h)
-        return self._block(h)
-
 
 class NemotronHModel(nn.Layer):
     def __init__(self, config: NemotronHConfig):
         super().__init__()
         c = config
-        self.embeddings = nn.Embedding(
-            c.vocab_rows_held, c.hidden_size,
-            weight_attr=nn.ParamAttr(initializer=I.Normal(
-                0.0, c.embedding_range)))
+        self.embeddings = embedding(c.vocab_rows_held, c.hidden_size,
+                                    c.embedding_range)
         self.layers = nn.LayerList([NemotronHBlock(c, i)
                                     for i in range(c.blocks_held[1])])
         self.norm_f = RMSNorm(c.hidden_size, c.layer_norm_epsilon)
@@ -322,8 +264,8 @@ class NemotronHForCausalLM(nn.Layer):
         c = config
         self.config = c
         self.backbone = NemotronHModel(c)
-        self.lm_head = _linear(c.hidden_size, c.vocab_rows_held,
-                               c.initializer_range)
+        self.lm_head = linear(c.hidden_size, c.vocab_rows_held,
+                              c.initializer_range)
         self.register_buffer("expert_tokens", Tensor(jnp.zeros(
             (c.kinds.count("moe"), c.experts_held[1]), jnp.int32)))
 
@@ -333,13 +275,7 @@ class NemotronHForCausalLM(nn.Layer):
         blocks, B * S, k]`` and the pairs of each held expert ``[expert
         blocks, held]``."""
         blocks = self.backbone.layers
-        from ..observability import metrics
-        for kind in KINDS.values():
-            metrics.registry().gauge(
-                "recompute_layers", "blocks of the model last traced that "
-                "run their forward pass again in the backward pass, by "
-                "their mixer", labels={"kind": kind}).set(sum(
-                    b.recomputed for b in blocks if b.kind == kind))
+        gauge_recomputed(blocks, KINDS.values())
         with jax.named_scope("embed"):
             h = self.backbone.embeddings(input_ids)
         sizes, chosen = [], []
@@ -348,8 +284,10 @@ class NemotronHForCausalLM(nn.Layer):
                 h, pairs, experts = block(h)
                 sizes.append(pairs)
                 chosen.append(experts)
-                if self.training and self.config.router_bias_update_rate:
-                    self._balance(block.mixer, experts)
+                rate = self.config.router_bias_update_rate
+                if self.training and rate:
+                    balance(block.mixer.e_score_correction_bias, experts,
+                            rate)
             else:
                 h = block(h)
         h = self.backbone.norm_f(h)
@@ -362,19 +300,6 @@ class NemotronHForCausalLM(nn.Layer):
                     Tensor(self.expert_tokens._value)]
         return logits
 
-    @jax.named_scope("mlp")
-    def _balance(self, mixer, experts):
-        """The balancing rule on one router's bias, from the experts this
-        pass's tokens chose ``[T, k]``: outside the block, so that a
-        recomputed block has nothing to write."""
-        rate = self.config.router_bias_update_rate
-        bias = mixer.e_score_correction_bias
-        with jax.named_scope("router"):
-            load = (experts._value[..., None] == jnp.arange(
-                bias.shape[0], dtype=experts._value.dtype)).sum(
-                    (0, 1)).astype(jnp.float32)
-            bias._value = bias._value + rate * jnp.sign(load.mean() - load)
-
     def moe_blocks(self) -> Tuple[int, ...]:
         """The expert blocks held, by their index in the whole model."""
         c = self.config
@@ -382,34 +307,9 @@ class NemotronHForCausalLM(nn.Layer):
                      if kind == "moe")
 
     def observe_step(self):
-        """Writes what the last step counted to the metrics registry:
-        ``moe_pairs_total{layer}``, ``moe_expert_tokens_max{layer}``,
-        ``moe_expert_tokens_mean{layer}``.  It reads a buffer the step
-        returned with its loss, so where the loss has been read it waits
-        for nothing."""
-        from ..observability import metrics
-        reg = metrics.registry()
-        tokens = jax.device_get(self.expert_tokens._value)
-        for layer, row in zip(self.moe_blocks(), tokens):
-            labels = {"layer": str(layer)}
-            reg.counter("moe_pairs_total",
-                        "(token, expert) pairs computed by the experts "
-                        "held here", labels=labels).inc(int(row.sum()))
-            reg.gauge("moe_expert_tokens_max",
-                      "pairs of the fullest held expert in the last step "
-                      "observed", labels=labels).set(float(row.max()))
-            reg.gauge("moe_expert_tokens_mean",
-                      "pairs of a held expert in the last step observed, "
-                      "on average", labels=labels).set(float(row.mean()))
+        """Writes what the last step counted to the metrics registry
+        (:func:`blocks.observe_expert_tokens`)."""
+        observe_expert_tokens(self.expert_tokens, self.moe_blocks())
 
 
-class NemotronHPretrainingCriterion(nn.Layer):
-    """Mean cross-entropy over the rows held."""
-
-    def __init__(self, config: Optional[NemotronHConfig] = None):
-        super().__init__()
-        self.loss_fn = ParallelCrossEntropy()
-
-    @jax.named_scope("loss")
-    def forward(self, logits, labels):
-        return ops.mean(self.loss_fn(logits, labels))
+NemotronHPretrainingCriterion = CausalLMCriterion

@@ -55,10 +55,9 @@ from ..framework import dtype as dtypes
 from ..nn import initializer as I
 from ..ops import pallas_ops, ssm
 from ..ops._primitive import apply_closure
-from ..distributed.fleet.meta_parallel import ParallelCrossEntropy
-from .keye_lm import _linear, _rms
-from .mamba2 import (_Conv1d, _InverseSoftplusOfSteps, _silu_gate,
-                     _step_sizes)
+from .blocks import (CausalLMCriterion, Conv1d, InverseSoftplusOfSteps,
+                     Recomputable, embedding, gated_mlp, gauge_recomputed,
+                     linear, rms, silu_gate, step_sizes)
 
 KINDS = ("mamba", "swa", "mamba_memory", "full_kv", "gmu", "cross")
 SUBLN_EPS = 1e-5
@@ -181,7 +180,7 @@ def _diff_combine(parts, lams, weight, lam0):
     lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
     out = (jnp.concatenate([a11, a12], -1)
            - lam * jnp.concatenate([a21, a22], -1))
-    return (_rms(out, weight, SUBLN_EPS) * (1.0 - lam0)).astype(
+    return (rms(out, weight, SUBLN_EPS) * (1.0 - lam0)).astype(
         parts[0].dtype)
 
 
@@ -223,19 +222,19 @@ class SambaYMamba(nn.Layer):
         c, std = config, config.initializer_range
         self.config, self.layer_idx, self.hands_on = c, layer_idx, hands_on
         inner, rank, state = c.d_inner, c.mamba_dt_rank, c.mamba_d_state
-        self.in_proj = _linear(c.hidden_size, 2 * inner, std)
-        self.conv1d = _Conv1d(inner, c.mamba_d_conv)
-        self.x_proj = _linear(inner, rank + 2 * state, std)
+        self.in_proj = linear(c.hidden_size, 2 * inner, std)
+        self.conv1d = Conv1d(inner, c.mamba_d_conv)
+        self.x_proj = linear(inner, rank + 2 * state, std)
         bound = rank ** -0.5
         self.dt_proj = nn.Linear(
             rank, inner,
             weight_attr=nn.ParamAttr(initializer=I.Uniform(-bound, bound)),
-            bias_attr=nn.ParamAttr(initializer=_InverseSoftplusOfSteps()))
+            bias_attr=nn.ParamAttr(initializer=InverseSoftplusOfSteps()))
         self.A_log = self.create_parameter(
             shape=[inner, state], default_initializer=_LogOfStates())
         self.D = self.create_parameter(
             shape=[inner], default_initializer=I.Constant(1.0))
-        self.out_proj = _linear(inner, c.hidden_size, std)
+        self.out_proj = linear(inner, c.hidden_size, std)
 
     def _one_sequence(self, u, w_in, conv_w, conv_b, w_x, w_dt, b_dt, a_log,
                       d, w_out):
@@ -250,11 +249,11 @@ class SambaYMamba(nn.Layer):
             steps = low @ w_dt
         with jax.named_scope("s6_scan"):
             y = ssm.selective_scan(
-                x, _step_sizes(steps, b_dt),
+                x, step_sizes(steps, b_dt),
                 -jnp.exp(a_log.astype(jnp.float32)), b, cc,
                 d.astype(jnp.float32))
         with jax.named_scope("ssm_proj"):
-            return _silu_gate(z, y) @ w_out, y
+            return silu_gate(z, y) @ w_out, y
 
     def _count(self, batch: int, seq: int):
         from ..observability import metrics
@@ -297,14 +296,14 @@ class SambaYGatedMemory(nn.Layer):
     def __init__(self, config: SambaYConfig):
         super().__init__()
         c, std = config, config.initializer_range
-        self.in_proj = _linear(c.hidden_size, c.d_inner, std)
-        self.out_proj = _linear(c.d_inner, c.hidden_size, std)
+        self.in_proj = linear(c.hidden_size, c.d_inner, std)
+        self.out_proj = linear(c.d_inner, c.hidden_size, std)
 
     @jax.named_scope("attn")
     def forward(self, u, memory):
         def closure(u_, m, w_in, w_out):
             with jax.named_scope("gmu"):
-                return _silu_gate(u_ @ w_in, m) @ w_out
+                return silu_gate(u_ @ w_in, m) @ w_out
 
         return apply_closure(
             closure, [u, memory, self.in_proj.weight, self.out_proj.weight],
@@ -374,26 +373,21 @@ class SambaYMLP(nn.Layer):
     def __init__(self, config: SambaYConfig):
         super().__init__()
         c, std = config, config.initializer_range
-        self.fc1 = _linear(c.hidden_size, 2 * c.intermediate_size, std)
-        self.fc2 = _linear(c.intermediate_size, c.hidden_size, std)
+        self.fc1 = linear(c.hidden_size, 2 * c.intermediate_size, std)
+        self.fc2 = linear(c.intermediate_size, c.hidden_size, std)
 
     @jax.named_scope("mlp")
     def forward(self, x):
-        def closure(x_, w1, w2):
-            gate, up = jnp.split(x_ @ w1, 2, axis=-1)
-            return _silu_gate(gate, up) @ w2
-
-        return apply_closure(closure, [x, self.fc1.weight, self.fc2.weight],
-                             name="sambay_mlp")
+        return gated_mlp(x, self.fc1.weight, self.fc2.weight)
 
 
-class SambaYDecoderLayer(nn.Layer):
+class SambaYDecoderLayer(Recomputable):
     """A layer takes the stream and what its kind reads (``gmu``: m;
     ``cross``: K, V) and returns the stream and what its kind hands on
     (``mamba_memory``: m; ``full_kv``: K, V)."""
 
     def __init__(self, config: SambaYConfig, layer_idx: int):
-        super().__init__()
+        super().__init__(layer_idx in config.recompute)
         c = config
         self.kind = kind = c.kinds[layer_idx]
         self.input_layernorm = SambaYLayerNorm(c.hidden_size,
@@ -407,7 +401,6 @@ class SambaYDecoderLayer(nn.Layer):
         self.post_attention_layernorm = SambaYLayerNorm(c.hidden_size,
                                                         c.layer_norm_eps)
         self.mlp = SambaYMLP(c)
-        self._recompute = layer_idx in c.recompute
 
     def _block(self, h, *read):
         # a block's norm and residual sum are the block's: the device
@@ -426,25 +419,13 @@ class SambaYDecoderLayer(nn.Layer):
             h = h + out
         return (h,) + handed if handed else h
 
-    @property
-    def recomputed(self) -> bool:
-        return self._recompute and self.training
-
-    def forward(self, h, *read):
-        if self.recomputed:
-            from ..distributed.fleet.recompute import recompute
-            return recompute(self._block, h, *read)
-        return self._block(h, *read)
-
 
 class SambaYModel(nn.Layer):
     def __init__(self, config: SambaYConfig):
         super().__init__()
         c = config
-        self.embed_tokens = nn.Embedding(
-            c.vocab_rows_held, c.hidden_size,
-            weight_attr=nn.ParamAttr(initializer=I.Normal(
-                0.0, c.initializer_range)))
+        self.embed_tokens = embedding(c.vocab_rows_held, c.hidden_size,
+                                      c.initializer_range)
         self.layers = nn.LayerList([SambaYDecoderLayer(c, i)
                                     for i in range(c.num_hidden_layers)])
         self.final_layernorm = SambaYLayerNorm(c.hidden_size,
@@ -466,13 +447,8 @@ class SambaYForCausalLM(nn.Layer):
 
     def _count(self, memory, key, value):
         from ..observability import metrics
+        gauge_recomputed(self.model.layers, KINDS)
         reg = metrics.registry()
-        layers = self.model.layers
-        for kind in KINDS:
-            reg.gauge("recompute_layers", "layers of the model last traced "
-                      "that run their forward pass again in the backward "
-                      "pass, by their kind", labels={"kind": kind}).set(sum(
-                          l.recomputed for l in layers if l.kind == kind))
         reg.gauge("yoco_shared_kv_bytes", "bytes of the keys and values "
                   "one layer makes and the cross-decoder's attention "
                   "layers read, in the model last traced").set(
@@ -505,13 +481,4 @@ class SambaYForCausalLM(nn.Layer):
                               transpose_y=True)
 
 
-class SambaYPretrainingCriterion(nn.Layer):
-    """Mean cross-entropy over the rows held."""
-
-    def __init__(self, config: Optional[SambaYConfig] = None):
-        super().__init__()
-        self.loss_fn = ParallelCrossEntropy()
-
-    @jax.named_scope("loss")
-    def forward(self, logits, labels):
-        return ops.mean(self.loss_fn(logits, labels))
+SambaYPretrainingCriterion = CausalLMCriterion

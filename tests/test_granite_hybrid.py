@@ -201,9 +201,9 @@ def test_an_eighth_for_q_is_the_published_scale_bit_for_bit_in_bf16():
 def test_the_mixers_start_as_mamba2_starts(tiny):
     net, config, _, _ = tiny
     paddle.seed(3)
-    mixer = granite_hybrid.GraniteMambaMixer(
+    mixer = granite_hybrid.GraniteDecoderLayer(
         granite_hybrid_tiny(mamba_n_heads=64, mamba_d_head=2,
-                            hidden_size=64), 0)
+                            hidden_size=64), 0).mamba
     steps = np.asarray(jax.nn.softplus(mixer.dt_bias._value))
     assert 0.001 <= steps.min() and steps.max() <= 0.1 + 1e-6
     assert steps.max() / steps.min() > 10        # spread over the decades

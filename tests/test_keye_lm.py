@@ -22,7 +22,7 @@ import paddle_tpu as paddle                                   # noqa: E402
 from paddle_tpu.nn import functional_call as F                # noqa: E402
 from paddle_tpu.models import (KeyeLMForCausalLM,             # noqa: E402
                                KeyeLMPretrainingCriterion, keye_lm_tiny)
-from paddle_tpu.models import keye_lm                         # noqa: E402
+from paddle_tpu.models import blocks, keye_lm                 # noqa: E402
 from paddle_tpu.ops import sparse_attention as dsa            # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import grouped  # noqa: E402
 from benchmarks.families import keye_lm as family             # noqa: E402
@@ -415,22 +415,22 @@ def test_score_visit_counter_reads_the_closed_form(monkeypatch, seq, heads,
 def test_three_equal_position_streams_are_plain_rotary():
     seq, dim, theta = 40, 128, 1e7
     pos = jnp.arange(3, seq + 3)
-    plain = keye_lm.rotary_angles(pos, dim, theta)
-    three = keye_lm.rotary_angles(jnp.stack([pos, pos, pos]), dim, theta,
-                                  (16, 24, 24))
+    plain = blocks.rotary_angles(pos, dim, theta)
+    three = blocks.rotary_angles(jnp.stack([pos, pos, pos]), dim, theta,
+                                 (16, 24, 24))
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(three))
     np.testing.assert_allclose(
         family.rotary_angles(jnp.stack([pos, pos, pos]), dim, theta,
                              (16, 24, 24)), plain, rtol=1e-6)
     # streams that differ turn their own sections only
-    other = keye_lm.rotary_angles(jnp.stack([pos, pos + 5, pos]), dim,
-                                  theta, (16, 24, 24))
+    other = blocks.rotary_angles(jnp.stack([pos, pos + 5, pos]), dim,
+                                 theta, (16, 24, 24))
     same = np.asarray(other) == np.asarray(plain)
     assert same[:, :16].all() and same[:, 40:].all()
     assert not same[:, 16:40].any()
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (seq, 2, dim)), jnp.float32)
-    np.testing.assert_allclose(keye_lm.apply_rotary(x, plain),
+    np.testing.assert_allclose(blocks.apply_rotary(x, plain),
                                family.rotate(x, plain), rtol=1e-6)
 
 

@@ -27,7 +27,7 @@ from paddle_tpu.nn import functional_call as F                # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import grouped  # noqa: E402
 from paddle_tpu.models import (                               # noqa: E402
     NemotronHConfig, NemotronHForCausalLM, NemotronHPretrainingCriterion,
-    mamba2, nemotron_h, nemotron_h_tiny)
+    blocks, mamba2, nemotron_h, nemotron_h_tiny)
 from paddle_tpu.observability import metrics                  # noqa: E402
 from paddle_tpu.ops import pallas_ops, ssm                    # noqa: E402
 from benchmarks.families import nemotron_h as family          # noqa: E402
@@ -332,12 +332,12 @@ def test_the_gated_norm_is_by_group_and_the_references():
             for _ in range(2))
     y = y * jnp.repeat(jnp.asarray([0.1, 1.0, 10.0, 100.0]), 32)
     weight = jnp.asarray(1 + 0.1 * rng.standard_normal(128), jnp.float32)
-    gated = mamba2._silu_gate(z, y)
+    gated = blocks.silu_gate(z, y)
     by_group = mamba2._rms_by_group(gated, weight, 1e-5, 4)
     whole = mamba2._rms_by_group(gated, weight, 1e-5, 1)
     np.testing.assert_array_equal(
         np.asarray(whole),
-        np.asarray(mamba2._rms(gated, weight, 1e-5)))
+        np.asarray(blocks.rms(gated, weight, 1e-5)))
     np.testing.assert_allclose(
         by_group, family.gated_norm(y, z, weight, 4, 1e-5), rtol=1e-5)
     np.testing.assert_allclose(
