@@ -28,3 +28,6 @@ from .sambay import (  # noqa
 from .lfm2_moe import (  # noqa
     Lfm2MoeConfig, Lfm2MoeModel, Lfm2MoeForCausalLM,
     Lfm2MoePretrainingCriterion, lfm2_moe_tiny)
+from .solar_open2 import (  # noqa
+    SolarOpen2Config, SolarOpen2Model, SolarOpen2ForCausalLM,
+    SolarOpen2PretrainingCriterion, solar_open2_tiny)

@@ -122,10 +122,30 @@ class InverseSoftplusOfSteps(I.Initializer):
             dtypes.to_jax_dtype(dtype))
 
 
+class LogOfUniform(I.Initializer):
+    """``log(value)`` drawn uniformly from [low, high]."""
+
+    def __init__(self, low: float, high: float):
+        self.low, self.high = low, high
+
+    def __call__(self, shape, dtype):
+        return jnp.log(jax.random.uniform(
+            _random.next_key(), tuple(shape), jnp.float32, self.low,
+            self.high)).astype(dtypes.to_jax_dtype(dtype))
+
+
 @jax.checkpoint
 def silu_gate(a, b):
     af = a.astype(jnp.float32)
     return (af * jax.nn.sigmoid(af) * b.astype(jnp.float32)).astype(a.dtype)
+
+
+@jax.checkpoint
+def sigmoid_gate(x, gate):
+    """``x * sigmoid(gate)`` in float32; the backward pass keeps the two
+    operands as they are stored."""
+    return (x.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))).astype(x.dtype)
 
 
 @jax.checkpoint
@@ -148,11 +168,13 @@ class PositionFreeAttention(nn.Layer):
     heads of ``head_dim`` over ``kv_heads``.  ``o_proj`` starts at
     ``out_std``, the other three at ``std``.  The kernels scale the scores
     by ``1 / sqrt(head_dim)``; where ``q_scale`` is given q is multiplied
-    by it first."""
+    by it first.  ``gated``: the heads' output is multiplied by
+    ``sigmoid(x W_g)``, a gate a channel, before ``o_proj`` (``g_proj``,
+    at ``std``)."""
 
     def __init__(self, hidden: int, heads: int, kv_heads: int,
                  head_dim: int, std: float, out_std: float,
-                 q_scale: Optional[float] = None):
+                 q_scale: Optional[float] = None, gated: bool = False):
         super().__init__()
         self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
         self.q_scale = q_scale
@@ -160,13 +182,16 @@ class PositionFreeAttention(nn.Layer):
         self.k_proj = linear(hidden, kv_heads * head_dim, std)
         self.v_proj = linear(hidden, kv_heads * head_dim, std)
         self.o_proj = linear(heads * head_dim, hidden, out_std)
+        self.gated = gated
+        if gated:
+            self.g_proj = linear(hidden, heads * head_dim, std)
 
     @jax.named_scope("attn")
     def forward(self, x):
         heads, kv_heads, dim = self.heads, self.kv_heads, self.head_dim
         scale = self.q_scale
 
-        def closure(x_, wq, wk, wv, wo):
+        def closure(x_, wq, wk, wv, wo, *wg):
             batch, seq = x_.shape[:2]
             split = lambda a, n: a.reshape(batch, seq, n, dim)  # noqa
             # a scaled q is made before the core, an unscaled one in it
@@ -177,11 +202,15 @@ class PositionFreeAttention(nn.Layer):
                     split(x_ @ wq if q is None else q, heads),
                     split(x_ @ wk, kv_heads), split(x_ @ wv, kv_heads),
                     causal=True)
-            return out.reshape(batch, seq, -1) @ wo
+            out = out.reshape(batch, seq, -1)
+            if wg:
+                out = sigmoid_gate(out, x_ @ wg[0])
+            return out @ wo
 
+        gate = [self.g_proj.weight] if self.gated else []
         return apply_closure(
             closure, [x, self.q_proj.weight, self.k_proj.weight,
-                      self.v_proj.weight, self.o_proj.weight],
+                      self.v_proj.weight, self.o_proj.weight] + gate,
             name="position_free_attention")
 
 

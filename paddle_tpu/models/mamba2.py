@@ -27,12 +27,11 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..framework import dtype as dtypes, random as _random
 from ..nn import initializer as I
 from ..ops import ssm
 from ..ops._primitive import apply_closure
-from .blocks import (Conv1d, InverseSoftplusOfSteps, RMSNorm, linear, rms,
-                     silu_gate, step_sizes)
+from .blocks import (Conv1d, InverseSoftplusOfSteps, LogOfUniform, RMSNorm,
+                     linear, rms, silu_gate, step_sizes)
 
 
 # jitted: the groups of a norm, and every norm of a model, share one trace
@@ -104,7 +103,7 @@ class Mamba2Mixer(nn.Layer):
         self.dt_bias = self.create_parameter(
             shape=[heads], default_initializer=InverseSoftplusOfSteps())
         self.A_log = self.create_parameter(
-            shape=[heads], default_initializer=_LogOfUniform(1.0, 16.0))
+            shape=[heads], default_initializer=LogOfUniform(1.0, 16.0))
         self.D = self.create_parameter(
             shape=[heads], default_initializer=I.Constant(1.0))
         self.norm = RMSNorm(self.d_inner, eps)
@@ -150,13 +149,3 @@ class Mamba2Mixer(nn.Layer):
                               for b in range(x_.shape[0])])
 
         return apply_closure(closure, [x] + weights, name="mamba2_mixer")
-
-
-class _LogOfUniform(I.Initializer):
-    def __init__(self, low: float, high: float):
-        self.low, self.high = low, high
-
-    def __call__(self, shape, dtype):
-        return jnp.log(jax.random.uniform(
-            _random.next_key(), tuple(shape), jnp.float32, self.low,
-            self.high)).astype(dtypes.to_jax_dtype(dtype))

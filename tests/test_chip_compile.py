@@ -810,3 +810,54 @@ def test_lfm2_ep4share_step_fits_a_v5e(topo, monkeypatch):
     assert len(sites) - len(products) - len(back) - len(conv) == own
     assert "bf16[8192,4,2048]" not in compiled.as_text()
     assert "ragged-dot" not in compiled.as_text()
+
+
+def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the cell solar-open2-kda-rank0-s8192
+    (layers 0-3 of Solar-Open2-250B, GQA then three KDA layers, at
+    published widths, 8 of 64 heads, 8 of 320 experts, b1 x s8192, bf16
+    O2 with float32 master weights, every mixer recomputed), as
+    ``DistributedRunner`` builds it, compiled for one described v5e chip:
+    what it needs on the device stays under the configuration's limit, and
+    its kernels are in it: the GQA layer's forward, the forward again,
+    dq and dkv; a KDA layer's short convolutions (``ops/ssm.py``'s kernels
+    over ``[q | k | v]``), forward, the forward again and the walk back;
+    the experts' grouped products, 21 a layer, and the way back to the
+    tokens, 4 a layer.  The delta rule is XLA operations, traced a KDA
+    layer once forward (perhaps once again for the recomputation)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.drivers import train_solar_lm as driver
+    from benchmarks.families import solar_open2 as family
+    from benchmarks.harness import cells
+    from paddle_tpu.observability import metrics
+    config = cells.load_cell("solar-open2-kda-rank0-s8192", root).config
+    calls = metrics.registry().counter("delta_rule_calls_total")
+    before = calls.collect()
+    compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
+                                            config, 8192)
+    kinds = family.kinds(config)
+    assert kinds == ("gqa", "kda", "kda", "kda")
+    assert config["recompute"] == [0, 1, 2, 3]
+    # the forward pass once a KDA layer, and once more for the
+    # recomputation where jax's cache of traces does not serve that trace
+    # (it does or not by what the process traced before)
+    assert calls.collect() - before in (3, 6)
+    # 840 874 392 parameters at 14 bytes (the norms hold no bf16 copy),
+    # the routers' biases, the step's counts and choices, the batch
+    assert memory.argument_size_in_bytes == approx(11.7725e9, rel=1e-3)
+    assert step < config["step_bytes_limit"] == 15.6e9
+    print(f"compiled step: {step} bytes a device")
+    sites = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    products = [line for line in sites if "grouped_dot" in line]
+    back = [line for line in sites if "token_rows_add" in line]
+    conv = [line for line in sites
+            if re.search(r"%_(forward|backward)_call", line)]
+    flash = [line for line in sites if "%_flash_packed_" in line]
+    print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
+          "experts' grouped products")
+    assert len(products) == 21 * 4 and len(back) == 4 * 4
+    assert len(conv) == 3 * 3
+    assert len(flash) == driver.kernel_sites(kinds, {0, 1, 2, 3}) == 4
+    assert len(sites) == len(products) + len(back) + len(conv) + len(flash)
