@@ -37,9 +37,16 @@ e^{g_r - g_s}`` with both exponents at most 0, so a pair is two matrix
 products; inside a sub-chunk pair by pair.  All sums are float32, every
 product at ``HIGHEST`` precision.
 
-The backward pass is jax's differentiation of the chunked form, through
-the triangular solve and the scan.  The calls count themselves as they
-are traced, a forward pass recomputed under ``jax.checkpoint`` once more:
+The rule has two forms, and :func:`rule_form` names the one that runs,
+from platform and shape: on a TPU (or under the interpreter), for heads
+whose keys and values fill lane groups and a sequence of whole chunks,
+the Mosaic kernels of ``ops/delta_rule_kernels.py`` (a head's state in
+VMEM from the first chunk to the last, the pairs and the solve inside the
+visit, the forward pass one call and the walk back one call); everywhere
+else (the CPU, odd shapes) the XLA operations below, whose backward pass
+is jax's differentiation of the chunked form, through the triangular
+solve and the scan.  The calls count themselves as they are traced, a
+forward pass recomputed under ``jax.checkpoint`` once more:
 ``delta_rule_calls_total``.
 """
 
@@ -48,8 +55,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import delta_rule_kernels, pallas_ops
+
 CHUNK = 64
 SUB = 16
+_LANES = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -151,6 +161,19 @@ def _chunked(q, k, v, log_alpha, beta, chunk: int):
     return out[:seq]
 
 
+def rule_form(seq: int, heads: int, dim: int, values: int,
+              chunk: int) -> str:
+    """Which form of the rule runs, from platform and shape: ``"kernels"``
+    (``ops/delta_rule_kernels.py``) on a TPU (or under the interpreter)
+    where the keys' and the values' widths are multiples of 128, the
+    sequence is a whole number of chunks, the chunk a whole number of
+    sub-chunks, and a visit fits VMEM; ``"xla"`` everywhere else."""
+    fits = (dim % _LANES == 0 and values % _LANES == 0 and chunk % SUB == 0
+            and seq % chunk == 0
+            and delta_rule_kernels.fits_vmem(heads, dim, values, chunk))
+    return "kernels" if fits and pallas_ops._kernels_enabled() else "xla"
+
+
 def gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK):
     """``o [S, H, V]`` of one sequence, in v's dtype, by the chunked form:
     ``q, k [S, H, K]``, ``v [S, H, V]``, ``log_alpha [S, H, K]`` (at most
@@ -162,4 +185,13 @@ def gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK):
         raise ValueError(f"gated_delta_rule: chunk {chunk} is no whole "
                          f"number of sub-chunks of {SUB}")
     _note_call()
+    if rule_form(*k.shape, v.shape[-1], chunk) == "kernels":
+        # the kernels hold nothing wider than 32 bits: a float64 input
+        # (jax.random's default under the package's x64) is float32 there,
+        # as the XLA form's sums are
+        narrow = lambda x: (x.astype(jnp.float32)                # noqa: E731
+                            if x.dtype.itemsize > 4 else x)
+        return delta_rule_kernels.rule(
+            *map(narrow, (q, k, v, log_alpha, beta)), chunk,
+            SUB).astype(v.dtype)
     return _chunked(q, k, v, log_alpha, beta, chunk).astype(v.dtype)

@@ -496,6 +496,40 @@ def _refused(build, case_id, pattern, why):
                             reason=f"{pattern}: {why}"))
 
 
+def _delta_rule_operands(topo, monkeypatch):
+    """The cell solar-open2-kda-rank0-s8192's delta rule: q, k and log
+    alpha ``[8192, 8 heads, 128]`` float32, v bf16, beta ``[8192, 8]``,
+    chunk 64: 128 visits of four turns of two heads stacked."""
+    from paddle_tpu.ops import delta_rule, delta_rule_kernels
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    spec = _one_chip_spec(topo)
+    seq, heads, dim = 8192, 8, 128
+    assert delta_rule.rule_form(seq, heads, dim, dim, 64) == "kernels"
+    assert delta_rule_kernels.group_of(heads, 64) == 2
+    keys = spec((seq, heads, dim), jnp.float32)
+    return (keys, keys, spec((seq, heads, dim)), keys,
+            spec((seq, heads), jnp.float32))
+
+
+def _delta_rule_fwd(topo, monkeypatch):
+    from paddle_tpu.ops import delta_rule
+    return (lambda *a: delta_rule.gated_delta_rule(*a, 64)), \
+        _delta_rule_operands(topo, monkeypatch)
+
+
+def _delta_rule_bwd(topo, monkeypatch):
+    """... differentiated: the forward kernel, which also writes the
+    states the chunks start from, and the walk back."""
+    from paddle_tpu.ops import delta_rule
+    args = _delta_rule_operands(topo, monkeypatch)
+
+    def grads(q, k, v, log_alpha, beta, do):
+        return jax.vjp(lambda *a: delta_rule.gated_delta_rule(*a, 64),
+                       q, k, v, log_alpha, beta)[1](do)
+
+    return grads, args + (args[2],)
+
+
 @pytest.mark.parametrize("build, n_calls, refused", [
     pytest.param(_bh_fwd, 1, None, id="flash_bh_fwd"),
     pytest.param(_bh_bwd, 2, None, id="flash_bh_bwd"),
@@ -539,6 +573,8 @@ def _refused(build, case_id, pattern, why):
                  id="flash_window_512_20_on_10_heads_of_64_s8192"),
     pytest.param(_selective_scan, 2, None,
                  id="selective_scan_s8192_5120_channels_state_16"),
+    pytest.param(_delta_rule_fwd, 1, None, id="delta_rule_fwd_s8192"),
+    pytest.param(_delta_rule_bwd, 2, None, id="delta_rule_bwd_s8192"),
     _refused(_paged_decode, "paged_ragged_attention",
              r"Unable to parse attribute:\s+error: "
              r"\"#tpu\.dot_dimension_numbers",
@@ -823,8 +859,10 @@ def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
     dq and dkv; a KDA layer's short convolutions (``ops/ssm.py``'s kernels
     over ``[q | k | v]``), forward, the forward again and the walk back;
     the experts' grouped products, 21 a layer, and the way back to the
-    tokens, 4 a layer.  The delta rule is XLA operations, traced a KDA
-    layer once forward (perhaps once again for the recomputation)."""
+    tokens, 4 a layer; and a KDA layer's delta rule, the Mosaic kernels of
+    ``ops/delta_rule_kernels.py``: forward, the forward again (which also
+    writes the chunks' starting states) and the walk back, traced a layer
+    once forward (perhaps once again for the recomputation)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks.drivers import train_solar_lm as driver
@@ -833,7 +871,11 @@ def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
     from paddle_tpu.observability import metrics
     config = cells.load_cell("solar-open2-kda-rank0-s8192", root).config
     calls = metrics.registry().counter("delta_rule_calls_total")
+    visits = [metrics.registry().counter("delta_rule_kernel_visits_total",
+                                         labels={"kind": kind})
+              for kind in ("fwd", "bwd")]
     before = calls.collect()
+    visits_before = [v.collect() for v in visits]
     compiled, memory, step = _compiled_step(topo, monkeypatch, driver,
                                             config, 8192)
     kinds = family.kinds(config)
@@ -843,6 +885,10 @@ def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
     # recomputation where jax's cache of traces does not serve that trace
     # (it does or not by what the process traced before)
     assert calls.collect() - before in (3, 6)
+    # a call is 128 chunks of 8 heads: the forward kernel traced once a
+    # forward pass and once for the states, the walk back once a layer
+    fwd, bwd = [v.collect() - was for v, was in zip(visits, visits_before)]
+    assert fwd in (3 * 2 * 1024, 3 * 3 * 1024) and bwd == 3 * 1024
     # 840 874 392 parameters at 14 bytes (the norms hold no bf16 copy),
     # the routers' biases, the step's counts and choices, the batch
     assert memory.argument_size_in_bytes == approx(11.7725e9, rel=1e-3)
@@ -854,10 +900,13 @@ def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
     back = [line for line in sites if "token_rows_add" in line]
     conv = [line for line in sites
             if re.search(r"%_(forward|backward)_call", line)]
+    rule = [line for line in sites if re.search(r"_delta_(fwd|bwd)", line)]
     flash = [line for line in sites if "%_flash_packed_" in line]
     print(f"{len(sites)} tpu_custom_call sites, {len(products)} of them the "
           "experts' grouped products")
     assert len(products) == 21 * 4 and len(back) == 4 * 4
-    assert len(conv) == 3 * 3
+    assert len(conv) == 3 * 3 and len(rule) == 3 * 3
+    assert not set(conv) & set(rule)
     assert len(flash) == driver.kernel_sites(kinds, {0, 1, 2, 3}) == 4
-    assert len(sites) == len(products) + len(back) + len(conv) + len(flash)
+    assert len(sites) == (len(products) + len(back) + len(conv) + len(rule)
+                          + len(flash))

@@ -3,7 +3,10 @@
 what its pieces promise: the chunked gated delta rule is the recurrence a
 position at a time, forward and in all five gradients, with beta near 2
 and with a decay steep enough that ``e^-g`` of a chunk's sum would
-overflow; recomputing the mixers changes nothing; the shares of heads and
+overflow, and so is its Mosaic kernel form (under the interpreter), which
+``rule_form`` picks by platform and shape, counting its visits, and which a
+recomputed KDA layer at kernel widths trains through as through the XLA
+form; recomputing the mixers changes nothing; the shares of heads and
 of experts, with the shared expert and the stream counted once, add up to
 the uncut layer; the gated grouped-query attention is the plain one times
 its gate; the balancing rule draws the loads level; and the model trains
@@ -31,7 +34,7 @@ from paddle_tpu.models import (                               # noqa: E402
 from paddle_tpu.models.blocks import PositionFreeAttention    # noqa: E402
 from paddle_tpu.models.solar_open2 import SolarOpen2Config    # noqa: E402
 from paddle_tpu.observability import metrics                  # noqa: E402
-from paddle_tpu.ops import delta_rule                         # noqa: E402
+from paddle_tpu.ops import delta_rule, ssm                    # noqa: E402
 from benchmarks.families import solar_open2 as family         # noqa: E402
 
 VOCAB, SEQ, BATCH = 64, 48, 2
@@ -196,6 +199,166 @@ def test_the_rule_in_bf16_sums_in_float32():
         *(half[:2] + (half[2].astype(jnp.float32),) + half[3:]), 64)
     np.testing.assert_allclose(np.asarray(o, np.float32), want, rtol=1e-2,
                                atol=1e-2 * float(jnp.abs(want).max()))
+
+
+# --------------------------------------------------------------------------
+# the delta rule's Mosaic kernels (ops/delta_rule_kernels.py), interpreted
+# --------------------------------------------------------------------------
+KERNELS = "PADDLE_TPU_PALLAS_INTERPRET"
+# two heads of 128 at chunk 64 are one turn of the kernels' head loop, the
+# cell's; the cases share their shapes, and so the kernels' traces
+KERNEL_CASES = {
+    # name: (sequence, steepness, beta near 2, v in bf16)
+    "two_chunks": (128, 1.0, False, False),
+    "two_chunks_steep_gates_beta_near_2": (128, 5.0, True, False),
+    "one_chunk_bf16_v": (64, 1.0, False, True),
+}
+
+
+def _visits(kind):
+    return metrics.registry().counter("delta_rule_kernel_visits_total",
+                                      labels={"kind": kind})
+
+
+@pytest.mark.parametrize("seq, steep, near_2, half", KERNEL_CASES.values(),
+                         ids=KERNEL_CASES.keys())
+def test_the_kernels_are_the_xla_form_and_the_recurrence(
+        monkeypatch, seq, steep, near_2, half):
+    """o and the gradients of ``sum(o * w)`` by q, k, v, log alpha and
+    beta from the kernels, against the XLA form on the same inputs and
+    against the recurrence a position at a time (the family's
+    ``kda_loop``): two heads of 128 stacked in a turn, one chunk or two,
+    v in bf16, gates steep enough (log alpha down to about -20 a
+    position) that ``e^-g`` of a chunk's sum overflows, beta near 2."""
+    heads, chunk = 2, 64
+    xs, w = _rule_inputs(seq, heads=heads, dim=128, steep=steep,
+                         beta_near_2=near_2)
+    if half:
+        xs = xs[:2] + (xs[2].astype(jnp.bfloat16),) + xs[3:]
+    if near_2:
+        assert float(xs[4].min()) > 1.95
+    if steep > 1:
+        g = np.cumsum(np.asarray(xs[3][:chunk]), 0)
+        assert g.min() < -88 and float(xs[3].min()) < -15
+
+    def weighted(*a):
+        o = delta_rule.gated_delta_rule(*a, chunk)
+        return (o.astype(jnp.float32) * w).sum(), o
+
+    def run():
+        form = delta_rule.rule_form(seq, heads, 128, 128, chunk)
+        grads, o = jax.jit(jax.grad(weighted, argnums=tuple(range(5)),
+                                    has_aux=True))(*xs)
+        return form, (o,) + grads
+
+    monkeypatch.setenv(KERNELS, "1")
+    before = _visits("bwd").collect()
+    form, got = run()
+    assert form == "kernels"
+    assert _visits("bwd").collect() - before == seq // chunk * heads
+    monkeypatch.setenv(KERNELS, "")
+    form, xla = run()
+    assert form == "xla"
+    want = family.reference_kda_grads(
+        *(x.astype(jnp.float32) for x in xs), w)
+    # bf16 v: o and dv are bf16, and the cotangent of o is rounded to it
+    tol = 1e-2 if half else 2e-4 if steep > 1 else 2e-5
+    for name, a, b, c in zip(("o", "dq", "dk", "dv", "dlog_alpha", "dbeta"),
+                             got, xla, want):
+        assert a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), name
+        for other in (b, c):
+            other = np.asarray(other, np.float32)
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), other, rtol=0,
+                atol=tol * float(np.abs(other).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("shape, interpreted, form", [
+    ((8192, 8, 128, 128, 64), "1", "kernels"),   # the cell's
+    ((8192, 8, 128, 128, 64), "", "xla"),        # the CPU
+    ((8192, 8, 64, 128, 64), "1", "xla"),        # keys no lane group
+    ((8192, 8, 128, 96, 64), "1", "xla"),        # values no lane group
+    ((8100, 8, 128, 128, 64), "1", "xla"),       # no whole chunks
+    ((8192, 8, 128, 128, 8), "1", "xla"),        # under a sub-chunk
+    ((8192, 64, 256, 256, 64), "1", "xla"),      # a visit over VMEM
+], ids=["cell", "cpu", "keys_64", "values_96", "ragged", "chunk_8",
+        "over_vmem"])
+def test_the_rule_form_follows_platform_and_shape(monkeypatch, shape,
+                                                  interpreted, form):
+    monkeypatch.setenv(KERNELS, interpreted)
+    assert delta_rule.rule_form(*shape) == form
+
+
+def test_the_kernels_count_their_visits_as_they_are_traced(monkeypatch):
+    """At the cell's shape a kernel call is 128 chunks of 8 heads, 1 024
+    visits, forward and back; where the XLA form runs, none."""
+    f32 = jnp.float32
+    spec = jax.ShapeDtypeStruct((8192, 8, 128), f32)
+    args = (spec, spec, jax.ShapeDtypeStruct((8192, 8, 128), jnp.bfloat16),
+            spec, jax.ShapeDtypeStruct((8192, 8), f32))
+    do = jax.ShapeDtypeStruct((8192, 8, 128), jnp.bfloat16)
+
+    def differentiated(*a):
+        return jax.vjp(lambda *b: delta_rule.gated_delta_rule(*b, 64),
+                       *a[:5])[1](a[5])
+
+    calls = metrics.registry().counter("delta_rule_calls_total")
+    for interpreted, visits in (("1", 1024), ("", 0)):
+        monkeypatch.setenv(KERNELS, interpreted)
+        before = [c.collect() for c in (_visits("fwd"), _visits("bwd"),
+                                        calls)]
+        # a function of its own each time: jax keeps the trace of one
+        jax.eval_shape(lambda *a: differentiated(*a), *args, do)
+        assert [c.collect() - b for c, b in zip(
+            (_visits("fwd"), _visits("bwd"), calls), before)] == [
+                visits, visits, 1]
+
+
+def test_a_recomputed_kda_layer_at_kernel_widths_is_the_xla_forms(
+        monkeypatch):
+    """A KDA mixer of two heads of 128 (so that the rule takes its kernels
+    under the interpreter; the convolution keeps its XLA form on both
+    sides), through ``fleet.recompute``: the loss and every gradient are
+    the XLA form's.
+    ``A_log``'s gradient, a sum over every position and key channel of a
+    head whose terms cancel to about a hundredth of their size, is held
+    to 2e-3 of its largest value, the others to 1e-4."""
+    config = solar_open2_tiny(
+        head_dim=128, linear_attn_config={"short_conv_kernel_size": 4,
+                                          "head_dim": 128, "num_heads": 4,
+                                          "num_kv_heads": None},
+        heads_held=(0, 2), layers_held=(1, 1), recompute=(0,))
+    mixer = seeded(config).model.layers[0].mixer
+    assert mixer.kind == "kda" and mixer.recomputed
+    monkeypatch.setattr(ssm, "conv_form", lambda *a: "xla")
+    params = F.param_dict(mixer)
+    rng = np.random.default_rng(8)
+    h = jnp.asarray(rng.standard_normal((1, 128, 64)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((1, 128, 64)), jnp.float32)
+
+    def loss(p, x):
+        out, _ = F.functional_call(mixer, p, {}, (paddle.to_tensor(x),))
+        return (out._value * w).sum()
+
+    def run(interpreted):
+        monkeypatch.setenv(KERNELS, interpreted)
+        before = _visits("bwd").collect()
+        value, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+            params, h)
+        return value, grads, _visits("bwd").collect() - before
+
+    value, grads, visits = run("1")
+    want_value, want, none = run("")
+    assert (visits, none) == (2 * 2, 0)
+    np.testing.assert_allclose(float(value), float(want_value), rtol=1e-4)
+    for name in want[0]:
+        scale = float(jnp.abs(want[0][name]).max())
+        tol = 2e-3 if name.endswith("A_log") else 1e-4
+        np.testing.assert_allclose(grads[0][name], want[0][name], rtol=0,
+                                   atol=tol * scale, err_msg=name)
+    np.testing.assert_allclose(grads[1], want[1], rtol=0,
+                               atol=1e-4 * float(jnp.abs(want[1]).max()))
 
 
 # --------------------------------------------------------------------------
