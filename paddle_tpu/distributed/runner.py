@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -35,6 +36,7 @@ from ..nn import functional_call as F
 from ..framework import random as _random
 from ..io.staging import to_device_values, stack_to_device
 from . import collective as coll
+from . import step_schedule as _schedule
 from .fleet.meta_parallel.sharding_parallel import shard_spec_for
 from .resilience import elastic_rank as _elastic
 from .resilience import faults as _faults
@@ -154,6 +156,11 @@ class DistributedRunner:
         # DistributedStrategy.recompute wiring (trade FLOPs for HBM)
         self.remat = remat
         self._step_fn = None
+        # (jitted step, arguments of the call that built its program
+        # last on a multi-device mesh) and that program's collectives,
+        # counted when step_collectives is first read (_observe_schedule)
+        self._scheduled = None
+        self._schedule_counts = None
         # the argument signature of the executable _step_fn built last
         # (_count_step_program): why a further one is built
         self._step_signature = None
@@ -798,7 +805,47 @@ class DistributedRunner:
         # opts back in for real-TPU memory-bound runs (ROADMAP
         # re-measure backlog).
         donate = (0, 3) if self._donate_explicit_ok() else ()
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(step, donate_argnums=donate, **self._jit_options())
+
+    def _jit_options(self) -> dict:
+        """What the step programs add to ``jax.jit``: the compiler's
+        options for the mesh (``step_schedule.compiler_options``: the dp
+        gradient all-reduces under the backward pass on a multi-device
+        TPU mesh), nothing where there are none."""
+        options = _schedule.compiler_options(self.mesh)
+        return {} if options is None else {"compiler_options": options}
+
+    def _observe_schedule(self, fn, args):
+        """Point ``step_collectives{kind, mode}`` at the program the
+        call of ``fn`` on ``args`` just built, on a multi-device mesh.
+        Nothing is read here but at the gauge's first read
+        (:meth:`_step_collectives`): on four v5e chips at 8 of GPT-3
+        XL's 24 layers a read takes 1.3 s, 0.9 s of it jax's handling
+        of the arguments, which a run that never reads the gauge should
+        not pay."""
+        if self.mesh.devices.size <= 1:
+            return
+        self._scheduled, self._schedule_counts = (fn, args), None
+
+        def counts(method=weakref.WeakMethod(self._step_collectives)):
+            bound = method()       # None once the runner is gone
+            return None if bound is None else bound()
+        _schedule.observe(counts)
+
+    def _step_collectives(self):
+        """``step_schedule.collective_modes`` of the program the step
+        built last, counted once: the step is lowered and compiled on
+        the arguments of the call that built it, which jax answers from
+        that call's caches, so nothing is traced, lowered or compiled
+        again and the body, which reads the mesh, does not run (pinned
+        by tests/test_step_schedule.py; a donated argument still gives
+        its shape and sharding), and the executable's text is read."""
+        if self._schedule_counts is None:
+            fn, args = self._scheduled
+            compiled = fn.lower(*args).compile()
+            self._schedule_counts = _schedule.collective_modes(
+                compiled.as_text() or "")
+        return self._schedule_counts
 
     def train_step(self, inputs, labels) -> float:
         """Run one compiled step; commits params/state/buffers."""
@@ -885,19 +932,20 @@ class DistributedRunner:
         with _obs_trace.span("mesh.commit"):
             if step_fn._cache_size() != held:
                 self._count_step_program(
-                    (params, frozen, bufs, self._opt_state, lr, ctr,
-                     *inputs_v, *labels_v))
+                    step_fn, (params, frozen, bufs, self._opt_state, lr,
+                              ctr, *inputs_v, *labels_v))
             self._commit_step(params, bufs, new_p, new_s, new_buf, 1)
         if self.capture_outputs:
             return loss, out_vals
         return loss
 
-    def _count_step_program(self, args):
+    def _count_step_program(self, step_fn, args):
         """The jitted step gained an executable: count it under the
-        reason, and say once which arguments differ from those of the
-        executable before it.  Walks the leaves, so it is called only
-        when ``_step_fn._cache_size()`` grew; donated leaves still say
-        all but their layout."""
+        reason, say once which arguments differ from those of the
+        executable before it, and point ``step_collectives`` at it
+        (:meth:`_observe_schedule`).  Walks the leaves, so it is called
+        only when ``_step_fn._cache_size()`` grew; donated leaves still
+        say all but their layout."""
         now = _host_events.argument_signature(args)
         before, self._step_signature = self._step_signature, now
         reason, differing = _host_events.signature_change(before, now)
@@ -908,6 +956,7 @@ class DistributedRunner:
             labels={"reason": reason}).inc()
         _obs_events.record("step_program", reason=reason,
                            step=self._step_ctr, differing=differing)
+        self._observe_schedule(step_fn, args)
 
     def _commit_step(self, params, bufs, new_p, new_s, new_buf,
                      n_steps: int):
@@ -1110,7 +1159,8 @@ class DistributedRunner:
         from ..framework.dispatch import build_folded_step
         return build_folded_step(per_step, fold, donate_buffers=False,
                                  place_data=place_data,
-                                 donate_carry=self._donate_explicit_ok())
+                                 donate_carry=self._donate_explicit_ok(),
+                                 **self._jit_options())
 
     def train_steps_folded(self, groups, metric_fns=(),
                            metric_acc=None):
@@ -1172,11 +1222,14 @@ class DistributedRunner:
             ctr0 = getattr(self, "_step_ctr", 0) + 1
             macc = tuple(metric_acc) if metric_acc is not None else ()
             base_key = self._ensure_base_key()
-        with _obs_trace.span("mesh.launch"):
-            losses, mstacks, new_acc, new_p, new_st, new_buf = fn(
-                params, frozen, bufs, self._opt_state, macc, lr,
+        held = fn._cache_size()
+        args = (params, frozen, bufs, self._opt_state, macc, lr,
                 base_key, np.uint32(ctr0), *stacked)
+        with _obs_trace.span("mesh.launch"):
+            losses, mstacks, new_acc, new_p, new_st, new_buf = fn(*args)
         with _obs_trace.span("mesh.commit"):
+            if fn._cache_size() != held:
+                self._observe_schedule(fn, args)
             self._step_ctr = ctr0 + fold - 1
             self._commit_step(params, bufs, new_p, new_st, new_buf, fold)
         from ..framework.lazy import LazyStack

@@ -210,7 +210,7 @@ def guarded_jit(fun: Callable, label: str, single_trace: bool = True,
 def build_folded_step(per_step: Callable, fold: int,
                       donate_buffers: bool = True,
                       place_data: Optional[Callable] = None,
-                      donate_carry: bool = True):
+                      donate_carry: bool = True, **jit_kwargs):
     """ONE compiled program running ``fold`` train steps as a rolled
     ``lax.scan`` over batches stacked on a new leading axis.
 
@@ -236,6 +236,8 @@ def build_folded_step(per_step: Callable, fold: int,
     explicit-dp (shard_map) mesh programs use it because this
     container's jaxlib corrupts donated buffers aliased through
     shard_map manual collectives (see DistributedRunner._build).
+    ``jit_kwargs`` go to ``jax.jit`` as they are (the runner's
+    compiler options on a multi-device TPU mesh: DESIGN-DCN.md).
     """
     import jax
     import jax.numpy as jnp
@@ -276,7 +278,8 @@ def build_folded_step(per_step: Callable, fold: int,
     # one entry per (fold, batch signature), so a second trace of THIS
     # entry is always the silent-retrace bug class
     return guarded_jit(program, label=f"folded_step[fold={fold}]",
-                       single_trace=True, donate_argnums=donate)
+                       single_trace=True, donate_argnums=donate,
+                       **jit_kwargs)
 
 
 # -- auto-K ---------------------------------------------------------------

@@ -910,3 +910,73 @@ def test_solar_kda_rank0_step_fits_a_v5e(topo, monkeypatch):
     assert len(flash) == driver.kernel_sites(kinds, {0, 1, 2, 3}) == 4
     assert len(sites) == (len(products) + len(back) + len(conv) + len(rule)
                           + len(flash))
+
+
+def _dp2_mp2_gpt_step(topo, monkeypatch, options):
+    """The compiled text of the GPT train step that ``DistributedRunner``
+    builds on a dp2 x mp2 mesh of the described v5e:2x2 (two layers of
+    1024, 8 heads of 128, b4 x s512, bf16 O2 AdamW): as the runner
+    compiles it where ``options`` is true, with no compiler options
+    where it is false.  Zeros placeholders, lowered on shapes."""
+    import numpy as np
+    from paddle_tpu import amp, optimizer
+    from paddle_tpu.distributed.runner import DistributedRunner
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   GPTPretrainingCriterion)
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    mesh = collective.build_mesh({"dp": 2, "mp": 2}, devices=topo.devices)
+    collective.set_mesh(mesh)
+    with paddle_tpu.LazyGuard():
+        net = GPTForCausalLM(GPTConfig(
+            vocab_size=4096, hidden_size=1024, num_hidden_layers=2,
+            num_attention_heads=8, intermediate_size=4096,
+            max_position_embeddings=512, hidden_dropout_prob=0.0,
+            attention_probs_dropout_prob=0.0))
+        opt = optimizer.AdamW(1e-4, parameters=net.parameters(),
+                              multi_precision=True)
+        amp.decorate(net, opt, level="O2", dtype="bfloat16")
+        runner = DistributedRunner(net, opt, GPTPretrainingCriterion(),
+                                   mesh=mesh)
+    monkeypatch.setattr(runner, "_shard", lambda value, spec: value)
+    ids = np.zeros((4, 512), np.int64)
+    data = sum(runner._prep_step_args([ids], [ids]), [])
+    step_fn = runner._step_fn if options else jax.jit(
+        runner._step_fn._fun, donate_argnums=(0, 3))
+
+    def on(v, spec):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params, frozen, bufs = runner._sync_val_cache()
+    state = {n: {k: on(v, runner._state_spec(runner._pspecs[n], v, name=n))
+                 for k, v in st.items()}
+             for n, st in runner._opt_state.items()}
+    return step_fn.lower(
+        {n: on(v, runner._pspecs[n]) for n, v in params.items()},
+        {n: on(v, runner._pspecs[n]) for n, v in frozen.items()},
+        {n: on(v, P()) for n, v in bufs.items()}, state,
+        on(jnp.float32(0), P()), on(jnp.uint32(1), P()),
+        *[on(d, P("dp")) for d in data]).compile().as_text()
+
+
+def test_dp2_mp2_step_reduces_gradients_under_the_backward_pass(
+        topo, monkeypatch):
+    """On four TPU devices the runner asks for the collective schedule of
+    ``step_schedule.COMPILER_OPTIONS``: the step's all-reduces become
+    asynchronous, the weight gradients' among them run under compute,
+    and the all-gathers stay plain.  Without the options the same step's
+    all-reduces are all plain (at this size 15 of them; with the options
+    11 run under compute, 13 have nothing under them and 2 small ones
+    stay plain)."""
+    from paddle_tpu.distributed import step_schedule
+    parent = step_schedule.collective_modes(
+        _dp2_mp2_gpt_step(topo, monkeypatch, options=False))
+    change = step_schedule.collective_modes(
+        _dp2_mp2_gpt_step(topo, monkeypatch, options=True))
+    print("without the options", dict(parent), "with them", dict(change))
+    assert parent[("all-reduce", "sync")] > 0
+    assert parent[("all-reduce", "overlapped")] == 0
+    assert change[("all-reduce", "overlapped")] > 0
+    assert change[("all-reduce", "sync")] * 4 <= parent[("all-reduce", "sync")]
+    assert change[("all-gather", "sync")] == sum(
+        n for (kind, _), n in change.items() if kind == "all-gather") > 0
